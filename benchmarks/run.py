@@ -24,6 +24,9 @@ SUITES = {
 
 
 def main() -> None:
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     which = sys.argv[1:] or list(SUITES)
     print("name,us_per_call,derived")
     failed = []

@@ -32,21 +32,77 @@ DEFAULT_BL = 512
 DEFAULT_BN = 128
 
 
-def _conv_tile(xs, wp, wn, k: int, cw: int):
-    """Accumulate K shifted popcount GEMM taps -> (bl, bn) int32.
+# ---------------------------------------------------------------------------
+# Kernel-body helpers shared by every kernel module (values, not refs).
+# Mosaic has no int32 x int32 matmul and no boolean truncation, so GEMMs run
+# on int8 operands and the SA polarity flip is an integer xor.
+# ---------------------------------------------------------------------------
 
-    Single-stream view of the batched tile (one accumulation loop to
-    maintain, two kernel entry points)."""
-    return _batched_conv_tile(xs[None], wp, wn, k, cw)[0]
+def sa_bits(raw, thr, flip):
+    """SA binarization -> int32 {0, 1}, executor-exact (integer thresholds
+    keep the float32 compare knife-edge free); ``flip`` is int32 0/1."""
+    ge = (raw.astype(jnp.float32) >= thr).astype(jnp.int32)
+    return jnp.bitwise_xor(ge, flip)
+
+
+def dot_i8(x, w):
+    """(M, K) @ (K, N) on int8 operands, int32 accumulation.  Exact for
+    operands inside int8: binary activations, ternary weights, offset
+    codes of up to 8 bits."""
+    return jax.lax.dot_general(
+        x.astype(jnp.int8), w.astype(jnp.int8), (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.int32,
+    )
+
+
+def dot_u8(h, w):
+    """``dot_i8`` for activations in [0, 255] (saturated GAP counts): the
+    low 7 bits and the top bit go through the MXU as two int8 GEMMs."""
+    lo = dot_i8(jnp.bitwise_and(h, 127), w)
+    hi = dot_i8(jnp.right_shift(h, 7), w)
+    return lo + hi * 128
+
+
+def classifier_val(gap, fc_params, out_raw: tuple[bool, ...]):
+    """GAP counts (bb, C) -> raw logits through the fc cascade.
+    ``fc_params`` is ``(w, [thr, flip])`` per layer, SA params only for
+    the layers that binarize."""
+    # 8-bit PWB counter ceiling (executor: gap counts saturate at 255)
+    h = jnp.minimum(gap, 255)
+    idx = 0
+    for j, raw_out in enumerate(out_raw):
+        w = fc_params[idx]
+        idx += 1
+        # the first fc reads 8-bit counts, the later ones binary SA bits
+        raw = dot_u8(h, w) if j == 0 else dot_i8(h, w)
+        if raw_out:
+            h = raw
+        else:
+            h = sa_bits(raw, fc_params[idx], fc_params[idx + 1])
+            idx += 2
+    return h
+
+
+def _conv_tile(xs, wp, wn, k: int, cw: int):
+    """Accumulate K shifted popcount GEMM taps -> (bl, bn) int32."""
+    bl = xs.shape[1]
+    acc = jnp.zeros((bl, wp.shape[2]), jnp.int32)
+    for tap in range(k):
+        for c in range(cw):
+            xa = xs[tap, :, c : c + 1]  # (bl, 1)
+            p = jax.lax.population_count(
+                jnp.bitwise_and(xa, wp[tap, c : c + 1, :]))
+            n = jax.lax.population_count(
+                jnp.bitwise_and(xa, wn[tap, c : c + 1, :]))
+            acc = acc + p.astype(jnp.int32) - n.astype(jnp.int32)
+    return acc
 
 
 def _kernel(
     xs_ref, wp_ref, wn_ref, thr_ref, flip_ref, o_ref, *, k: int, cw: int, pool: int
 ):
     diff = _conv_tile(xs_ref[...], wp_ref[...], wn_ref[...], k, cw)
-    ge = diff.astype(jnp.float32) >= thr_ref[0, :][None, :]
-    flip = flip_ref[0, :][None, :] != 0
-    y = jnp.where(flip, ~ge, ge).astype(jnp.uint32)
+    y = sa_bits(diff, thr_ref[...], flip_ref[...]).astype(jnp.uint32)
     if pool > 1:
         bl, bn = y.shape
         # PWB: OR-reduce the window before write-back (binary max-pool).
@@ -72,7 +128,7 @@ def bnn_conv1d_packed(
     bl: int = DEFAULT_BL,
     bn: int = DEFAULT_BN,
     mode: str = "sa",
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Fused conv1d -> SA -> pool on pre-shifted packed views.
 
@@ -127,140 +183,123 @@ def bnn_conv1d_packed(
 # the silicon, so the batch dimension rides free through the Pallas grid
 # (one extra grid axis, zero extra weight traffic).
 #
+# Layout: the (stream, position) rows ride the LANE axis and output
+# channels the sublanes — xs (K, Cw, R) packed words, planes (K, Cout, Cw),
+# output (Cout, R) — so a narrow packed-channel axis (Cw = 1 for the
+# one-channel audio input) never pads a tile, and each word is a lane-dense
+# row broadcast against a column of weight words.  ``ops`` builds the rows
+# (R = B * L_out) and transposes the result back.
+#
 # Shard-safety contract: pallas_call is opaque to GSPMD, so these kernels
 # must never see a mesh-sharded operand directly.  Under the mesh-wide slot
 # pool each device invokes the kernel on its LOCAL block of batch rows via
 # the shard_map entry points (ops.bnn_conv1d_batched_sharded /
 # ops.classifier_tail_sharded); per-shard batches can be as small as one
-# row, which the ops-layer entry points absorb (batch-block clamp for the
-# conv step, pad-to-block for the classifier tail).
+# row, which the ops-layer entry points absorb by padding.
 # ---------------------------------------------------------------------------
 
-DEFAULT_BB = 8
+DEFAULT_BB = 8      # slot block of the classifier tail / tenant block
+DEFAULT_BR = 512    # lane block of (stream, position) rows
 
 
-def _batched_conv_tile(xs, wp, wn, k: int, cw: int):
-    """Accumulate K shifted popcount GEMM taps -> (bb, bl, bn) int32."""
-    bb, _, bl, _ = xs.shape
-    bn = wp.shape[2]
-    acc = jnp.zeros((bb, bl, bn), jnp.int32)
+def _popcount_taps(xs_ref, wp, wn, plane=()):
+    """Sum K taps x Cw words of popcount diffs -> (bn, br) int32.
+
+    xs_ref[(*plane, tap)] is (Cw, br) packed activation words (rows on
+    lanes); wp/wn are (K, bn, Cw) weight planes (channels on sublanes).
+    """
+    k, cw = xs_ref.shape[-3], xs_ref.shape[-2]
+    acc = None
     for tap in range(k):
+        x = xs_ref[(*plane, tap)]
         for c in range(cw):
-            xa = xs[:, tap, :, c][:, :, None]  # (bb, bl, 1)
+            xa = x[c : c + 1, :]  # (1, br)
             p = jax.lax.population_count(
-                jnp.bitwise_and(xa, wp[tap, c][None, None, :])
-            )
+                jnp.bitwise_and(xa, wp[tap][:, c : c + 1]))
             n = jax.lax.population_count(
-                jnp.bitwise_and(xa, wn[tap, c][None, None, :])
-            )
-            acc = acc + p.astype(jnp.int32) - n.astype(jnp.int32)
+                jnp.bitwise_and(xa, wn[tap][:, c : c + 1]))
+            d = p.astype(jnp.int32) - n.astype(jnp.int32)
+            acc = d if acc is None else acc + d
     return acc
 
 
-def _batched_kernel(
-    xs_ref, wp_ref, wn_ref, thr_ref, flip_ref, o_ref, *, k: int, cw: int, pool: int
-):
-    diff = _batched_conv_tile(xs_ref[...], wp_ref[...], wn_ref[...], k, cw)
-    ge = diff.astype(jnp.float32) >= thr_ref[0, :][None, None, :]
-    flip = flip_ref[0, :][None, None, :] != 0
-    y = jnp.where(flip, ~ge, ge).astype(jnp.uint32)
-    if pool > 1:
-        bb, bl, bn = y.shape
-        y = jnp.max(y.reshape(bb, bl // pool, pool, bn), axis=2)
-    o_ref[...] = y
-
-
-def _batched_kernel_raw(*refs, k: int, cw: int, pooled: bool = False):
-    """refs = xs, wp, wn, [model (pooled),] out.  ``pooled``: wp/wn carry a
-    leading tenant axis (M, K, Cw, Cout); the block's planes are gathered
-    once per grid cell (slot blocks are single-tenant by placement)."""
-    xs_ref, wp_ref, wn_ref, o_ref = refs[0], refs[1], refs[2], refs[-1]
-    wp, wn = wp_ref[...], wn_ref[...]
+def _planes(refs, pooled: bool):
+    """(wp, wn) of this grid cell; pooled stacks gather the block's tenant
+    row once per cell (slot blocks are single-tenant by placement)."""
+    wp, wn = refs[1][...], refs[2][...]
     if pooled:
         midx = refs[3][0, 0]
         wp = jax.lax.dynamic_index_in_dim(wp, midx, 0, keepdims=False)
         wn = jax.lax.dynamic_index_in_dim(wn, midx, 0, keepdims=False)
-    o_ref[...] = _batched_conv_tile(xs_ref[...], wp, wn, k, cw)
+    return wp, wn
 
 
-@functools.partial(
-    jax.jit, static_argnames=("pool", "bb", "bl", "bn", "mode", "interpret")
-)
+def _batched_kernel_raw(*refs, pooled: bool = False):
+    """refs = xs, wp, wn, [model (pooled),] out."""
+    wp, wn = _planes(refs, pooled)
+    refs[-1][...] = _popcount_taps(refs[0], wp, wn)
+
+
+def _batched_kernel_bitserial(*refs, pooled: bool = False):
+    """refs = xs (bits, K, Cw, br), wp, wn, [model (pooled),] out."""
+    wp, wn = _planes(refs, pooled)
+    acc = None
+    for b in range(refs[0].shape[0]):
+        d = _popcount_taps(refs[0], wp, wn, plane=(b,)) * (1 << b)
+        acc = d if acc is None else acc + d
+    refs[-1][...] = acc
+
+
+def _rows_call(kernel, xs, wp, wn, model_idx, br: int, bn: int,
+               interpret: bool):
+    """Grid (R / br, Cout / bn) over a rows-on-lanes popcount kernel."""
+    pooled = model_idx is not None
+    r = xs.shape[-1]
+    n = wp.shape[-2]
+    assert r % br == 0 and n % bn == 0, (r, br, n, bn)
+    lead = xs.ndim - 1
+    xs_spec = pl.BlockSpec(
+        xs.shape[:-1] + (br,), lambda i, j: (0,) * lead + (i,))
+    wl = wp.ndim - 2
+    w_spec = pl.BlockSpec(
+        wp.shape[:-2] + (bn, wp.shape[-1]), lambda i, j: (0,) * wl + (j, 0))
+    in_specs = [xs_spec, w_spec, w_spec]
+    args = [xs, wp, wn]
+    if pooled:
+        in_specs.append(pl.BlockSpec((1, 1), lambda i, j: (i, 0)))
+        args.append(model_idx.astype(jnp.int32))
+    return dispatch.pallas_call(
+        functools.partial(kernel, pooled=pooled),
+        grid=(r // br, n // bn),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((bn, br), lambda i, j: (j, i)),
+        out_shape=jax.ShapeDtypeStruct((n, r), jnp.int32),
+        interpret=interpret,
+    )(*args)
+
+
 def bnn_conv1d_step_packed(
     xs: jax.Array,
     wp: jax.Array,
     wn: jax.Array,
-    thr: jax.Array | None = None,
-    flip: jax.Array | None = None,
     model_idx: jax.Array | None = None,
     *,
-    pool: int = 1,
-    bb: int = DEFAULT_BB,
-    bl: int = DEFAULT_BL,
-    bn: int = DEFAULT_BN,
-    mode: str = "sa",
-    interpret: bool = True,
+    br: int,
+    bn: int,
+    interpret: bool,
 ) -> jax.Array:
-    """Batched fused conv1d step on pre-shifted packed views.
+    """Batched raw conv step on pre-shifted packed rows.
 
-    xs : (B, K, L_out, Cw) uint32 — per-stream tap-shifted packed views
-    wp/wn : (K, Cw, Cout) uint32  — shared across the batch axis; with
-        ``model_idx`` (``(B // bb, 1)`` int32, one tenant per slot block)
-        a pooled (M, K, Cw, Cout) stack, gathered per grid cell (raw mode
-        only — the SA affine runs outside the raw path)
-    Output: (B, L_out / pool, Cout) uint32 bits (or (B, L_out, Cout) int32).
+    xs : (K, Cw, R) uint32 — tap-shifted packed words, one lane per
+        (stream, position) row
+    wp/wn : (K, Cout, Cw) uint32 — shared across the rows; with
+        ``model_idx`` (``(R // br, 1)`` int32, one tenant per row block)
+        a pooled (M, K, Cout, Cw) stack, gathered per grid cell
+    Output: (Cout, R) int32 raw popcount diff.  Not jitted itself: each
+    call from a traced wrapper is one launch the dispatch counter sees.
     """
-    pooled = model_idx is not None
-    b, k, l_out, cw = xs.shape
-    if pooled:
-        assert mode == "raw", "weight pooling is a raw-conv path feature"
-        m, k2, cw2, n = wp.shape
-    else:
-        k2, cw2, n = wp.shape
-    assert k == k2 and cw == cw2 and wn.shape == wp.shape
-    bb = min(bb, b)
-    bl = min(bl, l_out)
-    bn = min(bn, n)
-    assert b % bb == 0 and l_out % bl == 0 and n % bn == 0, (b, bb, l_out, bl, n, bn)
-    assert bl % pool == 0, (bl, pool)
-    grid = (b // bb, l_out // bl, n // bn)
-
-    xs_spec = pl.BlockSpec((bb, k, bl, cw), lambda s, i, j: (s, 0, i, 0))
-    if pooled:
-        w_spec = pl.BlockSpec((m, k, cw, bn), lambda s, i, j: (0, 0, 0, j))
-    else:
-        w_spec = pl.BlockSpec((k, cw, bn), lambda s, i, j: (0, 0, j))
-    v_spec = pl.BlockSpec((1, bn), lambda s, i, j: (0, j))
-    mi_spec = pl.BlockSpec((1, 1), lambda s, i, j: (s, 0))
-
-    if mode == "sa":
-        assert thr is not None and flip is not None
-        o_spec = pl.BlockSpec((bb, bl // pool, bn), lambda s, i, j: (s, i, j))
-        return dispatch.pallas_call(
-            functools.partial(_batched_kernel, k=k, cw=cw, pool=pool),
-            grid=grid,
-            in_specs=[xs_spec, w_spec, w_spec, v_spec, v_spec],
-            out_specs=o_spec,
-            out_shape=jax.ShapeDtypeStruct((b, l_out // pool, n), jnp.uint32),
-            interpret=interpret,
-        )(xs, wp, wn, thr.reshape(1, n), flip.astype(jnp.int32).reshape(1, n))
-    elif mode == "raw":
-        assert pool == 1, "raw mode has no SA output to pool"
-        o_spec = pl.BlockSpec((bb, bl, bn), lambda s, i, j: (s, i, j))
-        in_specs = [xs_spec, w_spec, w_spec]
-        args = [xs, wp, wn]
-        if pooled:
-            in_specs.append(mi_spec)
-            args.append(model_idx.astype(jnp.int32))
-        return dispatch.pallas_call(
-            functools.partial(_batched_kernel_raw, k=k, cw=cw, pooled=pooled),
-            grid=grid,
-            in_specs=in_specs,
-            out_specs=o_spec,
-            out_shape=jax.ShapeDtypeStruct((b, l_out, n), jnp.int32),
-            interpret=interpret,
-        )(*args)
-    raise ValueError(f"mode {mode!r}")
+    return _rows_call(_batched_kernel_raw, xs, wp, wn, model_idx, br, bn,
+                      interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -275,8 +314,7 @@ def bnn_conv1d_step_packed(
 # ---------------------------------------------------------------------------
 
 
-def _tail_kernel(*refs, n_fc: int, out_raw: tuple[bool, ...],
-                 pooled: bool = False):
+def _tail_kernel(*refs, out_raw: tuple[bool, ...], pooled: bool = False):
     """refs = [gap, [model (pooled),] (w, [thr, flip])* , out].  One cell:
     bb streams.  ``pooled``: fc params carry a leading tenant axis,
     gathered once per cell."""
@@ -290,25 +328,7 @@ def _tail_kernel(*refs, n_fc: int, out_raw: tuple[bool, ...],
         ]
     else:
         params = [r[...] for r in params]
-    # 8-bit PWB counter ceiling (executor: gap counts saturate at 255)
-    h = jnp.minimum(gap_ref[...], 255)
-    idx = 0
-    for j in range(n_fc):
-        w = params[idx]
-        idx += 1
-        raw = jax.lax.dot_general(
-            h, w, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32,
-        )
-        if out_raw[j]:
-            h = raw
-        else:
-            thr = params[idx]
-            flip = params[idx + 1]
-            idx += 2
-            ge = raw.astype(jnp.float32) >= thr[0, :][None, :]
-            h = jnp.where(flip[0, :][None, :] != 0, ~ge, ge).astype(jnp.int32)
-    o_ref[...] = h
+    o_ref[...] = classifier_val(gap_ref[...], params, out_raw)
 
 
 @functools.partial(jax.jit, static_argnames=("out_raw", "bb", "interpret"))
@@ -321,12 +341,12 @@ def classifier_tail_packed(
     *,
     out_raw: tuple[bool, ...],
     bb: int = DEFAULT_BB,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Saturate GAP counts and run the whole fc cascade in one kernel.
 
     gap : (B, C) int32 GAP counts (possibly already clamped; idempotent)
-    fc_ws : per-fc (Cin, Cout) int32 ternary weights — or pooled
+    fc_ws : per-fc (Cin, Cout) int8 ternary weights — or pooled
         (M, Cin, Cout) stacks when ``model_idx`` (``(B // bb, 1)`` int32,
         one tenant per slot block) is given
     fc_thrs/fc_flips : per-fc (1, Cout) (pooled: (M, 1, Cout)) float32 /
@@ -359,9 +379,7 @@ def classifier_tail_packed(
             args.extend([fc_thrs[j], fc_flips[j]])
     n_out = fc_ws[-1].shape[-1]
     return dispatch.pallas_call(
-        functools.partial(
-            _tail_kernel, n_fc=n_fc, out_raw=out_raw, pooled=pooled
-        ),
+        functools.partial(_tail_kernel, out_raw=out_raw, pooled=pooled),
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bb, n_out), lambda s: (s, 0)),
@@ -383,101 +401,24 @@ def classifier_tail_packed(
 # ---------------------------------------------------------------------------
 
 
-def _batched_bitserial_tile(xs, wp, wn, k: int, cw: int, bits: int):
-    """Accumulate bits x K x Cw popcount partials -> (bb, bl, bn) int32.
-
-    xs: (bb, bits, K, bl, Cw) uint32 — per-plane tap-shifted packed views.
-    """
-    bb, _, _, bl, _ = xs.shape
-    bn = wp.shape[2]
-    acc = jnp.zeros((bb, bl, bn), jnp.int32)
-    for b in range(bits):
-        scale = jnp.int32(1 << b)
-        for tap in range(k):
-            for c in range(cw):
-                xa = xs[:, b, tap, :, c][:, :, None]  # (bb, bl, 1)
-                p = jax.lax.population_count(
-                    jnp.bitwise_and(xa, wp[tap, c][None, None, :])
-                )
-                n = jax.lax.population_count(
-                    jnp.bitwise_and(xa, wn[tap, c][None, None, :])
-                )
-                acc = acc + (p.astype(jnp.int32) - n.astype(jnp.int32)) * scale
-    return acc
-
-
-def _batched_kernel_bitserial(
-    *refs, k: int, cw: int, bits: int, pooled: bool = False
-):
-    """refs = xs, wp, wn, [model (pooled),] out."""
-    xs_ref, wp_ref, wn_ref, o_ref = refs[0], refs[1], refs[2], refs[-1]
-    wp, wn = wp_ref[...], wn_ref[...]
-    if pooled:
-        midx = refs[3][0, 0]
-        wp = jax.lax.dynamic_index_in_dim(wp, midx, 0, keepdims=False)
-        wn = jax.lax.dynamic_index_in_dim(wn, midx, 0, keepdims=False)
-    o_ref[...] = _batched_bitserial_tile(xs_ref[...], wp, wn, k, cw, bits)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("bits", "bb", "bl", "bn", "interpret")
-)
 def bnn_bitserial_step_packed(
     xs: jax.Array,
     wp: jax.Array,
     wn: jax.Array,
     model_idx: jax.Array | None = None,
     *,
-    bits: int,
-    bb: int = DEFAULT_BB,
-    bl: int = DEFAULT_BL,
-    bn: int = DEFAULT_BN,
-    interpret: bool = True,
+    br: int,
+    bn: int,
+    interpret: bool,
 ) -> jax.Array:
-    """Batched bit-serial raw conv on pre-shifted per-plane packed views.
+    """Batched bit-serial raw conv on pre-shifted per-plane packed rows.
 
-    xs : (B, bits, K, L_out, Cw) uint32; wp/wn : (K, Cw, Cout) uint32
-    shared across batch AND planes (the whole point: one weight fetch for
-    all ``bits`` passes) — or pooled (M, K, Cw, Cout) stacks when
-    ``model_idx`` (``(B // bb, 1)`` int32, one tenant per slot block) is
-    given.  Output: (B, L_out, Cout) int32 raw popcount diff already
-    accumulated over planes (offset NOT yet folded).
+    xs : (bits, K, Cw, R) uint32; wp/wn : (K, Cout, Cw) uint32 shared
+    across rows AND planes (the whole point: one weight fetch for all
+    ``bits`` passes) — or pooled (M, K, Cout, Cw) stacks when
+    ``model_idx`` (``(R // br, 1)`` int32, one tenant per row block) is
+    given.  Output: (Cout, R) int32 raw popcount diff already accumulated
+    over planes (offset NOT yet folded).
     """
-    pooled = model_idx is not None
-    b, nbits, k, l_out, cw = xs.shape
-    assert nbits == bits, (nbits, bits)
-    if pooled:
-        m, k2, cw2, n = wp.shape
-    else:
-        k2, cw2, n = wp.shape
-    assert k == k2 and cw == cw2 and wn.shape == wp.shape
-    bb = min(bb, b)
-    bl = min(bl, l_out)
-    bn = min(bn, n)
-    assert b % bb == 0 and l_out % bl == 0 and n % bn == 0, (
-        b, bb, l_out, bl, n, bn)
-    grid = (b // bb, l_out // bl, n // bn)
-
-    xs_spec = pl.BlockSpec(
-        (bb, bits, k, bl, cw), lambda s, i, j: (s, 0, 0, i, 0)
-    )
-    if pooled:
-        w_spec = pl.BlockSpec((m, k, cw, bn), lambda s, i, j: (0, 0, 0, j))
-    else:
-        w_spec = pl.BlockSpec((k, cw, bn), lambda s, i, j: (0, 0, j))
-    o_spec = pl.BlockSpec((bb, bl, bn), lambda s, i, j: (s, i, j))
-    in_specs = [xs_spec, w_spec, w_spec]
-    args = [xs, wp, wn]
-    if pooled:
-        in_specs.append(pl.BlockSpec((1, 1), lambda s, i, j: (s, 0)))
-        args.append(model_idx.astype(jnp.int32))
-    return dispatch.pallas_call(
-        functools.partial(
-            _batched_kernel_bitserial, k=k, cw=cw, bits=bits, pooled=pooled
-        ),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=o_spec,
-        out_shape=jax.ShapeDtypeStruct((b, l_out, n), jnp.int32),
-        interpret=interpret,
-    )(*args)
+    return _rows_call(_batched_kernel_bitserial, xs, wp, wn, model_idx, br,
+                      bn, interpret)

@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import contextlib
 
+import jax
 from jax.experimental import pallas as pl
 
 _dispatches = 0
@@ -37,7 +38,15 @@ def count() -> int:
 
 
 def pallas_call(*args, **kwargs):
-    """Drop-in ``pl.pallas_call`` that records the launch at trace time."""
+    """Drop-in ``pl.pallas_call`` that records the launch at trace time.
+
+    Refuses the Pallas interpreter on a TPU backend: an interpreted kernel
+    there would run, slowly and without a word, in place of the Mosaic
+    kernel the caller meant to measure."""
+    if kwargs.get("interpret") and jax.default_backend() == "tpu":
+        raise ValueError(
+            "pallas_call asked to interpret on a TPU backend; pass "
+            "interpret=False (or None, resolved by ops.default_interpret)")
     bump()
     return pl.pallas_call(*args, **kwargs)
 

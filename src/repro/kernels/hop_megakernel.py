@@ -1,4 +1,4 @@
-"""Pallas megakernel: one launch per streaming hop, ping-pong scratch.
+"""Pallas megakernel: one launch per streaming hop.
 
 The per-stage streaming path (scheduler ``backend="pallas"``) issues one
 ``pallas_call`` per conv stage per hop — plus the whole cascade again for
@@ -6,10 +6,9 @@ the ghost flush on emit hops, plus the classifier tail — bouncing every
 intermediate feature map through HBM between launches.  This module fuses
 the entire hop into ONE kernel:
 
-  * bit-serial first layer: the ``2^b`` input planes are extracted and
-    accumulated *inside* the kernel (the accumulation commutes with the
-    integer MAC, so one shared-tap GEMM replaces ``in_bits`` passes — see
-    ``_conv_raw_val``), instead of ``in_bits`` separate dispatches;
+  * the bit-serial first layer: its ``2^b`` code planes telescope back to
+    the offset code (``sum_b plane_b << b``), so one shared-tap GEMM on the
+    code replaces ``in_bits`` passes;
   * SA binarization, max-pool with the steady pool phase, receptive-field
     tail carry and pending-frame carry for every stage;
   * GAP accumulation saturated at the 8-bit PWB ceiling;
@@ -19,12 +18,26 @@ the entire hop into ONE kernel:
     tail run in the same launch on the merged state, so an emit hop is
     still a single dispatch.
 
-Intermediate feature maps ping-pong between two VMEM scratch buffers
-(``scratch_shapes``): stage *i* reads its input from one buffer and parks
-its pooled output in the other, so nothing but the hop's audio input and
-the updated slot state (tails / pendings / GAP, plus logits on emit) ever
-touches HBM.  This is the paper's flexible ping-pong feature SRAM (§II-C)
-made literal: layer-to-layer activations never leave the macro.
+Intermediate feature maps never leave the kernel: only the hop's input and
+the updated slot state (tails / pendings / GAP, plus logits on emit) touch
+HBM — the paper's ping-pong feature SRAM (§II-C), with Mosaic placing the
+stage-to-stage values in VMEM.
+
+Layout (what Mosaic needs, prepared by ``ops.hop_megakernel``):
+
+  * per-slot state is **time-major**: a stage's tail is ``(tail, B, cin)``
+    and its pending frames ``(phase, B, cout)``, so one frame is a 2-D
+    ``(bb, C)`` tile (slots on sublanes, channels on lanes) and every tap
+    slice, pool window and tail carry is a list slice of whole tiles;
+  * stage 0's window arrives as **stride phases** ``(Q, B, s * cin)``:
+    row ``q`` holds samples ``q*s .. q*s + s - 1``, and the taps are
+    regrouped into ``ceil(k / s)`` unit-stride groups, so the strided
+    first layer is ``ceil(k / s)`` dense GEMMs.  Its codes arrive already
+    offset (``x - in_offset``, masked to ``in_bits``); stage 0's own tail
+    is carried by the wrapper, which builds the window;
+  * every GEMM runs on int8 operands with int32 accumulation — exact, since
+    ternary weights, binary activations and offset 8-bit codes all fit
+    int8; the classifier's 0..255 GAP counts split into two int8 halves.
 
 Grid: ``(B / bb,)`` over slot blocks — weights/thresholds are replicated
 per grid cell (one weight fetch serves every stream, the shared-weight CIM
@@ -34,14 +47,6 @@ Shard-safety: ``pallas_call`` is GSPMD-opaque, so this kernel must never
 see a mesh-sharded operand — the mesh-wide slot pool enters through the
 shard_map wrappers ``ops.hop_megakernel_sharded`` /
 ``ops.finalize_megakernel_sharded``.
-
-Interpret-mode note: on this CPU container the kernel runs with
-``interpret=True`` (scratch residency is simulated), which preserves the
-dispatch-count and bit-exactness contracts; on TPU the same call site
-compiles to one Mosaic kernel where the scratch buffers are real VMEM.
-The conv taps use ``dot_general`` with ``preferred_element_type=int32``
-(MXU-friendly) rather than the packed popcount primitive — identical
-integer semantics, no packing round-trip between fused stages.
 """
 from __future__ import annotations
 
@@ -53,20 +58,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels import dispatch
-
-try:  # TPU memory-space annotation; interpret mode accepts plain structs
-    from jax.experimental.pallas import tpu as pltpu
-
-    def _vmem(shape, dtype):
-        return pltpu.VMEM(shape, dtype)
-except ImportError:  # pragma: no cover - depends on jax build
-    def _vmem(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype)
+from repro.kernels.bnn_conv1d import classifier_val, dot_i8, sa_bits
 
 # slot-block size: big blocks amortize the weight fetch and keep the grid
-# short (the whole local batch in one cell for every bench config); the
-# scratch footprint per cell is 2 * bb * SL * SC int32, tiny next to the
-# feature maps the per-stage path round-trips
+# short (a whole 256-slot shard is one grid cell; at width 64 that cell
+# fits Mosaic's default 16 MiB VMEM scope)
 DEFAULT_BB = 256
 
 
@@ -105,164 +101,78 @@ def stage_geom(st) -> StageGeom:
     )
 
 
-def scratch_dims(geoms: tuple[StageGeom, ...], emit: bool) -> tuple[int, int]:
-    """(length, channels) of each ping-pong buffer: the max inter-stage
-    feature-map footprint across the steady cascade (and the flush
-    cascade when it is fused in)."""
-    sl = sc = 1
-    for g in geoms:
-        sl = max(sl, g.n_out)
-        sc = max(sc, g.cout)
-        if emit:
-            sl = max(sl, g.flush_out)
-    return sl, sc
-
-
-class _PingPong:
-    """The two scratch buffers; ``park`` writes a stage's output into the
-    current buffer and flips sides, so consecutive stages alternate —
-    stage *i* reads buffer A while writing buffer B, exactly the paper's
-    double-buffered feature SRAM.  Zero-width maps pass through."""
-
-    def __init__(self, a_ref, b_ref):
-        self._bufs = (a_ref, b_ref)
-        self._side = 0
-
-    def park(self, val):
-        n, c = val.shape[1], val.shape[2]
-        if n == 0 or c == 0:
-            return val
-        buf = self._bufs[self._side]
-        self._side ^= 1
-        buf[:, :n, :c] = val
-        return buf[:, :n, :c]
-
-
 # ---------------------------------------------------------------------------
-# Kernel-body math (pure value helpers, shared by hop and finalize modes)
+# Kernel-body math (pure value helpers, shared by hop and finalize modes).
+# A "frame list" is a python list of (bb, C) int32 tiles, one per position.
 # ---------------------------------------------------------------------------
 
-def _conv_raw_val(g: StageGeom, w, window, n_pos: int):
-    """(bb, L, Cin) int32 window -> (bb, n_pos, Cout) raw popcount diff.
-
-    Bit-serial first layer (``in_bits > 1``): the ``2^b`` planes are
-    extracted and accumulated in VMEM, then one shared-tap GEMM runs on
-    the accumulated code — ``sum_b (plane_b << b)`` telescopes back to the
-    integer code, so the plane accumulation commutes with the MAC and is
-    bit-exact with the per-plane popcount path at 1/in_bits the GEMM
-    passes (and, vs the old per-stage path, 1/in_bits the dispatches).
-    """
-    if g.in_bits > 1:
-        x = jnp.zeros_like(window)
-        for b in range(g.in_bits):
-            x = x + (((window >> b) & 1) << b)
-        x = x - g.in_offset
-    else:
-        x = window
-    span = (n_pos - 1) * g.stride + 1
+def _conv_frames(w, thr, flip, win, n: int, bb: int):
+    """Conv + SA over a frame list: ``w`` holds the (A, C, Cout) tap
+    groups, group ``a`` reads frames ``a .. a + n - 1``.  Returns ``n``
+    binarized (bb, Cout) frames."""
     acc = None
-    for t in range(g.k):
-        tap = x[:, t : t + span : g.stride, :]
-        d = jax.lax.dot_general(
-            tap, w[t], (((2,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32,
-        )
+    for a in range(w.shape[0]):
+        lhs = win[a] if n == 1 else jnp.concatenate(win[a : a + n], axis=0)
+        d = dot_i8(lhs, w[a])
         acc = d if acc is None else acc + d
-    return acc
+    y = sa_bits(acc, thr, flip)
+    return [y[j * bb : (j + 1) * bb] for j in range(n)]
 
 
-def _sa_val(raw, thr, flip):
-    """SA binarization, executor-exact (integer thresholds keep the
-    float32 compare knife-edge free)."""
-    ge = raw.astype(jnp.float32) >= thr[0][None, None, :]
-    return jnp.where(flip[0][None, None, :] != 0, ~ge, ge).astype(jnp.int32)
+def _pool_frames(frames, n_out: int, pool: int):
+    """Max-pool a frame list (drop-remainder windows, ref_maxpool1d)."""
+    return [
+        functools.reduce(jnp.maximum, frames[j * pool : (j + 1) * pool])
+        for j in range(n_out)
+    ]
 
 
-def _steady_cascade(geoms, cur, tails, pends, ws, thrs, flips, pp):
-    """The per-hop conv cascade on one slot block; returns the final
-    stage's pooled frames plus the carried tails/pendings."""
-    new_tails, new_pends = [], []
+def _gap_add(gap, frames):
+    if not frames:
+        return gap
+    return jnp.minimum(gap + functools.reduce(jnp.add, frames), 255)
+
+
+def _steady_cascade(geoms, x0, tails, pends, ws, thrs, flips, bb):
+    """The per-hop conv cascade on one slot block.  ``x0`` is stage 0's
+    stride-phase window; returns the final stage's pooled frames plus the
+    carried tails/pendings of stages 1.. (stage 0's tail is the
+    wrapper's)."""
+    new_tails, new_pends = [[]], []
+    cur = None
     for i, g in enumerate(geoms):
-        window = (
-            jnp.concatenate([tails[i], cur], axis=1) if g.tail else cur
-        )
-        raw = _conv_raw_val(g, ws[i], window, g.n_conv)
-        new_tails.append(window[:, g.n_conv * g.stride :, :])
-        y = _sa_val(raw, thrs[i], flips[i])
+        win = x0 if i == 0 else tails[i] + cur
+        if i:
+            new_tails.append(win[g.n_conv :])
+        y = _conv_frames(ws[i], thrs[i], flips[i], win, g.n_conv, bb)
+        frames = pends[i] + y
         if g.pool > 1:
-            frames = (
-                jnp.concatenate([pends[i], y], axis=1) if g.phase else y
-            )
-            used = g.n_out * g.pool
-            pooled = jnp.max(
-                frames[:, :used].reshape(
-                    frames.shape[0], g.n_out, g.pool, g.cout
-                ),
-                axis=2,
-            )
-            new_pends.append(frames[:, used:, :])
-            cur = pp.park(pooled)
+            cur = _pool_frames(frames, g.n_out, g.pool)
+            new_pends.append(frames[g.n_out * g.pool :])
         else:
+            cur = y
             new_pends.append(pends[i])
-            cur = pp.park(y)
     return cur, new_tails, new_pends
 
 
-def _flush_cascade(geoms, tails, pends, gap, ws, thrs, flips, pp):
+def _flush_cascade(geoms, f0, tails, pends, gap, ws, thrs, flips, bb):
     """Ghost end-of-stream flush from (merged) steady state -> saturated
-    GAP counts, mirror of ``_BatchedModel._finalize``."""
-    bb = gap.shape[0]
-    cur = None
+    GAP counts, mirror of ``_BatchedModel._finalize``.  ``f0`` is stage
+    0's flush window (its tail + right pad) in stride phases."""
+    cur = []
     for i, g in enumerate(geoms):
-        pieces = []
-        if g.tail:
-            pieces.append(tails[i])
-        if cur is not None and g.flush_in:
-            pieces.append(cur)
-        if g.pad:
-            pad_val = g.in_offset if g.in_bits > 1 else 0
-            pieces.append(jnp.full((bb, g.pad, g.cin), pad_val, jnp.int32))
-        if g.flush_conv > 0:
-            window = (
-                pieces[0] if len(pieces) == 1
-                else jnp.concatenate(pieces, axis=1)
-            )
-            y = _sa_val(
-                _conv_raw_val(g, ws[i], window, g.flush_conv),
-                thrs[i], flips[i],
-            )
+        if i == 0:
+            win = f0
         else:
-            y = jnp.zeros((bb, 0, g.cout), jnp.int32)
-        frames = jnp.concatenate([pends[i], y], axis=1) if g.phase else y
-        used = g.flush_out * g.pool  # drop-remainder (ref_maxpool1d)
-        cur = pp.park(
-            jnp.max(
-                frames[:, :used].reshape(bb, g.flush_out, g.pool, g.cout),
-                axis=2,
-            )
+            win = tails[i] + (cur if g.flush_in else [])
+            if g.pad:
+                win = win + [jnp.zeros((bb, g.cin), jnp.int32)] * g.pad
+        y = (
+            _conv_frames(ws[i], thrs[i], flips[i], win, g.flush_conv, bb)
+            if g.flush_conv > 0 else []
         )
-    return jnp.minimum(gap + cur.sum(axis=1, dtype=jnp.int32), 255)
-
-
-def _classifier_val(gap_f, fc_params, fc_raw):
-    """Saturated GAP counts (bb, C) -> raw logits (fused fc cascade)."""
-    h = jnp.minimum(gap_f, 255)  # idempotent with the flush clamp
-    idx = 0
-    for j, raw_out in enumerate(fc_raw):
-        w = fc_params[idx]
-        idx += 1
-        raw = jax.lax.dot_general(
-            h, w, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32,
-        )
-        if raw_out:
-            h = raw
-        else:
-            thr, flip = fc_params[idx], fc_params[idx + 1]
-            idx += 2
-            ge = raw.astype(jnp.float32) >= thr[0][None, :]
-            h = jnp.where(flip[0][None, :] != 0, ~ge, ge).astype(jnp.int32)
-    return h
+        cur = _pool_frames(pends[i] + y, g.flush_out, g.pool)
+    return _gap_add(gap, cur)
 
 
 # ---------------------------------------------------------------------------
@@ -273,61 +183,53 @@ def _n_fc_params(fc_raw: tuple[bool, ...]) -> int:
     return sum(1 if r else 3 for r in fc_raw)
 
 
+def _frames(ref):
+    """Time-major (T, bb, C) block -> frame list."""
+    return [ref[t] for t in range(ref.shape[0])]
+
+
 def _megakernel(
     *refs, geoms: tuple[StageGeom, ...], emit: bool, finalize_only: bool,
     fc_raw: tuple[bool, ...], pooled: bool = False,
 ):
-    """refs = [audio, mask,] tails(tail>0)*, pends(phase>0)*, gap,
-    [model (pooled),] (w, thr, flip) per stage, fc params (emit/finalize)
-    | outputs | ping, pong.  Outputs: tails*, pends*, gap [, logits]
-    (finalize: logits only).
+    """refs = [x0, mask,] tails(stage>0, tail>0)*, pends(phase>0)*, gap,
+    [f0 (emit/finalize),] [model (pooled),] (w, thr, flip) per stage,
+    fc params (emit/finalize) | outputs.  Outputs: tails*, pends*, gap
+    [, logits] (finalize: logits only).
 
     ``pooled``: every weight/threshold operand carries a leading tenant
     axis ``(K, ...)`` and a per-block ``(1, 1)`` int32 model index follows
-    ``gap`` — the block's weight planes are gathered out of the pool ONCE
+    ``f0`` — the block's weight planes are gathered out of the pool ONCE
     per grid cell (each slot block is single-tenant by placement), so the
     pool costs one dynamic row index, not K-way compute.
     """
     ns = len(geoms)
-    n_tail = sum(1 for g in geoms if g.tail)
+    n_tail = sum(1 for i, g in enumerate(geoms) if i and g.tail)
     n_pend = sum(1 for g in geoms if g.phase)
     with_fc = emit or finalize_only
-    pos = 0
+    refs = list(refs)
     if not finalize_only:
-        audio_ref, mask_ref = refs[0], refs[1]
-        pos = 2
-    tail_refs = refs[pos : pos + n_tail]
-    pos += n_tail
-    pend_refs = refs[pos : pos + n_pend]
-    pos += n_pend
-    gap_ref = refs[pos]
-    pos += 1
-    if pooled:
-        model_ref = refs[pos]
-        pos += 1
-    stage_refs = refs[pos : pos + 3 * ns]
-    pos += 3 * ns
-    n_fcp = _n_fc_params(fc_raw) if with_fc else 0
-    fc_refs = refs[pos : pos + n_fcp]
-    pos += n_fcp
-    out_refs = refs[pos:-2]
-    ping_ref, pong_ref = refs[-2], refs[-1]
+        x0_ref, mask_ref = refs.pop(0), refs.pop(0)
+    tail_refs = [refs.pop(0) for _ in range(n_tail)]
+    pend_refs = [refs.pop(0) for _ in range(n_pend)]
+    gap_ref = refs.pop(0)
+    f0_ref = refs.pop(0) if with_fc else None
+    model_ref = refs.pop(0) if pooled else None
+    stage_refs = [refs.pop(0) for _ in range(3 * ns)]
+    fc_refs = [refs.pop(0) for _ in range(_n_fc_params(fc_raw) if with_fc
+                                          else 0)]
+    out_refs = refs
 
     bb = gap_ref.shape[0]
     gap = gap_ref[...]
+    tails, pends = [[]], []
     ti = pi = 0
-    tails, pends = [], []
-    for g in geoms:
-        if g.tail:
-            tails.append(tail_refs[ti][...])
-            ti += 1
-        else:
-            tails.append(jnp.zeros((bb, 0, g.cin), jnp.int32))
-        if g.phase:
-            pends.append(pend_refs[pi][...])
-            pi += 1
-        else:
-            pends.append(jnp.zeros((bb, 0, g.cout), jnp.int32))
+    for i, g in enumerate(geoms):
+        if i:
+            tails.append(_frames(tail_refs[ti]) if g.tail else [])
+            ti += bool(g.tail)
+        pends.append(_frames(pend_refs[pi]) if g.phase else [])
+        pi += bool(g.phase)
     ws = [stage_refs[3 * i][...] for i in range(ns)]
     thrs = [stage_refs[3 * i + 1][...] for i in range(ns)]
     flips = [stage_refs[3 * i + 2][...] for i in range(ns)]
@@ -342,82 +244,79 @@ def _megakernel(
         thrs = [sel(t) for t in thrs]
         flips = [sel(f) for f in flips]
         fc_params = [sel(p) for p in fc_params]
-    pp = _PingPong(ping_ref, pong_ref)
 
     if finalize_only:
-        gap_f = _flush_cascade(geoms, tails, pends, gap, ws, thrs, flips, pp)
-        out_refs[0][...] = _classifier_val(gap_f, fc_params, fc_raw)
+        gap_f = _flush_cascade(geoms, _frames(f0_ref), tails, pends, gap,
+                               ws, thrs, flips, bb)
+        out_refs[0][...] = classifier_val(gap_f, fc_params, fc_raw)
         return
 
     cur, new_tails, new_pends = _steady_cascade(
-        geoms, audio_ref[...], tails, pends, ws, thrs, flips, pp
+        geoms, _frames(x0_ref), tails, pends, ws, thrs, flips, bb
     )
-    gap2 = jnp.minimum(gap + cur.sum(axis=1, dtype=jnp.int32), 255)
+    gap2 = _gap_add(gap, cur)
 
     # masked-slot merge in-kernel: rows whose stream had no full hop keep
     # their previous state bit-for-bit; the flush below runs on the MERGED
     # state so every primed slot's logits stay valid (scheduler contract)
     m = mask_ref[...] != 0  # (bb, 1)
-    m3 = m[:, :, None]
-    merged_tails = [
-        jnp.where(m3, nt, t) if g.tail else t
-        for g, nt, t in zip(geoms, new_tails, tails)
-    ]
-    merged_pends = [
-        jnp.where(m3, np_, p) if g.phase else p
-        for g, np_, p in zip(geoms, new_pends, pends)
-    ]
+
+    def merge(new, old):
+        return [jnp.where(m, a, b) for a, b in zip(new, old)]
+
+    merged_tails = [merge(nt, t) for nt, t in zip(new_tails, tails)]
+    merged_pends = [merge(np_, p) for np_, p in zip(new_pends, pends)]
     merged_gap = jnp.where(m, gap2, gap)
 
     oi = 0
-    for g, t in zip(geoms, merged_tails):
-        if g.tail:
-            out_refs[oi][...] = t
+    for i, (g, t) in enumerate(zip(geoms, merged_tails)):
+        if i and g.tail:
+            for j, v in enumerate(t):
+                out_refs[oi][j] = v
             oi += 1
     for g, p in zip(geoms, merged_pends):
         if g.phase:
-            out_refs[oi][...] = p
+            for j, v in enumerate(p):
+                out_refs[oi][j] = v
             oi += 1
     out_refs[oi][...] = merged_gap
     oi += 1
     if emit:
         gap_f = _flush_cascade(
-            geoms, merged_tails, merged_pends, merged_gap,
-            ws, thrs, flips, pp,
+            geoms, _frames(f0_ref), merged_tails, merged_pends, merged_gap,
+            ws, thrs, flips, bb,
         )
-        out_refs[oi][...] = _classifier_val(gap_f, fc_params, fc_raw)
+        out_refs[oi][...] = classifier_val(gap_f, fc_params, fc_raw)
 
 
 # ---------------------------------------------------------------------------
-# Packed entry points (ops.py wraps these with padding + shard_map)
+# Packed entry points (ops.py prepares the layout, pads and shard_maps)
 # ---------------------------------------------------------------------------
 
-def _block_arg(specs, args, x, bb, replicated):
-    nd = x.ndim
-    if replicated:
-        specs.append(pl.BlockSpec(x.shape, lambda s, _n=nd: (0,) * _n))
-    else:
-        specs.append(
-            pl.BlockSpec(
-                (bb,) + x.shape[1:], lambda s, _n=nd: (s,) + (0,) * (_n - 1)
-            )
-        )
-    args.append(x)
+def _state_spec(shape, bb):
+    """Block over the slot axis: axis 1 of a time-major (T, B, C) state
+    array, axis 0 of a (B, C) one."""
+    if len(shape) == 3:
+        return pl.BlockSpec((shape[0], bb, shape[2]), lambda s: (0, s, 0))
+    return pl.BlockSpec((bb,) + tuple(shape[1:]),
+                        lambda s, _n=len(shape): (s,) + (0,) * (_n - 1))
 
 
-def _stage_params(specs, args, ws, thrs, flips, bb):
+def _rep_spec(shape):
+    return pl.BlockSpec(tuple(shape), lambda s, _n=len(shape): (0,) * _n)
+
+
+def _params(specs, args, ws, thrs, flips, fc_ws, fc_thrs, fc_flips, fc_raw):
     for w, t, f in zip(ws, thrs, flips):
-        _block_arg(specs, args, w, bb, True)
-        _block_arg(specs, args, t, bb, True)
-        _block_arg(specs, args, f, bb, True)
-
-
-def _fc_args(specs, args, fc_ws, fc_thrs, fc_flips, fc_raw, bb):
+        for x in (w, t, f):
+            specs.append(_rep_spec(x.shape))
+            args.append(x)
     for j, raw_out in enumerate(fc_raw):
-        _block_arg(specs, args, fc_ws[j], bb, True)
-        if not raw_out:
-            _block_arg(specs, args, fc_thrs[j], bb, True)
-            _block_arg(specs, args, fc_flips[j], bb, True)
+        group = (fc_ws[j],) if raw_out else (fc_ws[j], fc_thrs[j],
+                                             fc_flips[j])
+        for x in group:
+            specs.append(_rep_spec(x.shape))
+            args.append(x)
 
 
 def _n_logits(fc_ws, fc_raw, geoms):
@@ -425,10 +324,15 @@ def _n_logits(fc_ws, fc_raw, geoms):
     return fc_ws[-1].shape[-1] if fc_raw else geoms[-1].cout
 
 
-def _model_arg(specs, args, model_idx, bb):
-    """Per-block model index: (nblocks, 1) int32, one row per grid cell."""
-    specs.append(pl.BlockSpec((1, 1), lambda s: (s, 0)))
-    args.append(model_idx.astype(jnp.int32))
+def _call(kernel, grid, specs, args, out_shapes, bb, interpret):
+    return dispatch.pallas_call(
+        kernel,
+        grid=grid,
+        in_specs=specs,
+        out_specs=[_state_spec(s.shape, bb) for s in out_shapes],
+        out_shape=tuple(out_shapes),
+        interpret=interpret,
+    )(*args)
 
 
 @functools.partial(
@@ -436,11 +340,12 @@ def _model_arg(specs, args, model_idx, bb):
     static_argnames=("geoms", "emit", "fc_raw", "bb", "interpret"),
 )
 def hop_megakernel_packed(
-    audio: jax.Array,
+    x0: jax.Array,
     mask: jax.Array,
     tails: tuple[jax.Array, ...],
     pendings: tuple[jax.Array, ...],
     gap: jax.Array,
+    f0: jax.Array | None,
     ws: tuple[jax.Array, ...],
     thrs: tuple[jax.Array, ...],
     flips: tuple[jax.Array, ...],
@@ -452,73 +357,44 @@ def hop_megakernel_packed(
     geoms: tuple[StageGeom, ...],
     emit: bool,
     fc_raw: tuple[bool, ...],
-    bb: int = DEFAULT_BB,
-    interpret: bool = True,
+    bb: int,
+    interpret: bool,
 ):
-    """One fused hop over a slot-block grid.  ``tails``/``pendings`` carry
-    one entry per stage with ``tail > 0`` / ``phase > 0`` (zero-width state
-    never enters the kernel).  B must divide into ``bb`` blocks (the ops
-    wrapper pads).  ``model_idx`` (``(b // bb, 1)`` int32, one tenant per
-    slot block) switches every weight operand to a pooled ``(K, ...)``
-    stack — same grid, same single launch.  Returns
-    ``(tails, pendings, gap[, logits])``.
+    """One fused hop over a slot-block grid, in the kernel layout (module
+    docstring): ``x0`` (Q, B, s*cin0) stage-0 stride phases, ``mask``
+    (B, 1), ``tails`` one time-major entry per stage > 0 with ``tail > 0``,
+    ``pendings`` one per stage with ``phase > 0``, ``gap`` (B, C), ``f0``
+    stage 0's flush window (emit only), int8 tap groups ``ws``.  B must
+    divide into ``bb`` blocks (the ops wrapper pads).  ``model_idx``
+    (``(B // bb, 1)`` int32, one tenant per slot block) switches every
+    weight operand to a pooled ``(K, ...)`` stack — same grid, same single
+    launch.  Returns ``(tails, pendings, gap[, logits])``.
     """
     b = gap.shape[0]
-    bb = min(bb, b)
     assert b % bb == 0, (b, bb)
-    grid = (b // bb,)
     pooled = model_idx is not None
     specs: list = []
     args: list = []
-    _block_arg(specs, args, audio.astype(jnp.int32), bb, False)
-    _block_arg(specs, args, mask.astype(jnp.int32).reshape(b, 1), bb, False)
-    for t in tails:
-        _block_arg(specs, args, t, bb, False)
-    for p in pendings:
-        _block_arg(specs, args, p, bb, False)
-    _block_arg(specs, args, gap, bb, False)
+    for x in (x0, mask, *tails, *pendings, gap) + ((f0,) if emit else ()):
+        specs.append(_state_spec(x.shape, bb))
+        args.append(x)
     if pooled:
-        _model_arg(specs, args, model_idx, bb)
-    _stage_params(specs, args, ws, thrs, flips, bb)
+        specs.append(pl.BlockSpec((1, 1), lambda s: (s, 0)))
+        args.append(model_idx)
+    _params(specs, args, ws, thrs, flips, fc_ws, fc_thrs, fc_flips,
+            fc_raw if emit else ())
+    out_shapes = [jax.ShapeDtypeStruct(x.shape, jnp.int32)
+                  for x in (*tails, *pendings, gap)]
     if emit:
-        _fc_args(specs, args, fc_ws, fc_thrs, fc_flips, fc_raw, bb)
-
-    out_specs: list = []
-    out_shapes: list = []
-
-    def out3(shape):
-        nd = len(shape)
-        out_specs.append(
-            pl.BlockSpec(
-                (bb,) + shape[1:], lambda s, _n=nd: (s,) + (0,) * (_n - 1)
-            )
-        )
-        out_shapes.append(jax.ShapeDtypeStruct(shape, jnp.int32))
-
-    for t in tails:
-        out3(t.shape)
-    for p in pendings:
-        out3(p.shape)
-    out3(gap.shape)
-    if emit:
-        out3((b, _n_logits(fc_ws, fc_raw, geoms)))
-
-    sl, sc = scratch_dims(geoms, emit)
-    out = dispatch.pallas_call(
+        out_shapes.append(jax.ShapeDtypeStruct(
+            (b, _n_logits(fc_ws, fc_raw, geoms)), jnp.int32))
+    out = _call(
         functools.partial(
             _megakernel, geoms=geoms, emit=emit, finalize_only=False,
             fc_raw=fc_raw if emit else (), pooled=pooled,
         ),
-        grid=grid,
-        in_specs=specs,
-        out_specs=out_specs,
-        out_shape=tuple(out_shapes),
-        scratch_shapes=[
-            _vmem((bb, sl, sc), jnp.int32),
-            _vmem((bb, sl, sc), jnp.int32),
-        ],
-        interpret=interpret,
-    )(*args)
+        (b // bb,), specs, args, out_shapes, bb, interpret,
+    )
     nt, npend = len(tails), len(pendings)
     tails_out = out[:nt]
     pends_out = out[nt : nt + npend]
@@ -535,6 +411,7 @@ def finalize_megakernel_packed(
     tails: tuple[jax.Array, ...],
     pendings: tuple[jax.Array, ...],
     gap: jax.Array,
+    f0: jax.Array,
     ws: tuple[jax.Array, ...],
     thrs: tuple[jax.Array, ...],
     flips: tuple[jax.Array, ...],
@@ -545,41 +422,29 @@ def finalize_megakernel_packed(
     *,
     geoms: tuple[StageGeom, ...],
     fc_raw: tuple[bool, ...],
-    bb: int = DEFAULT_BB,
-    interpret: bool = True,
+    bb: int,
+    interpret: bool,
 ) -> jax.Array:
     """Ghost flush + classifier tail alone (hop-boundary peeks): one
-    launch from resident state to logits."""
+    launch from resident state to logits, same layout as the hop."""
     b = gap.shape[0]
-    bb = min(bb, b)
     assert b % bb == 0, (b, bb)
-    grid = (b // bb,)
     pooled = model_idx is not None
     specs: list = []
     args: list = []
-    for t in tails:
-        _block_arg(specs, args, t, bb, False)
-    for p in pendings:
-        _block_arg(specs, args, p, bb, False)
-    _block_arg(specs, args, gap, bb, False)
+    for x in (*tails, *pendings, gap, f0):
+        specs.append(_state_spec(x.shape, bb))
+        args.append(x)
     if pooled:
-        _model_arg(specs, args, model_idx, bb)
-    _stage_params(specs, args, ws, thrs, flips, bb)
-    _fc_args(specs, args, fc_ws, fc_thrs, fc_flips, fc_raw, bb)
-    n_out = _n_logits(fc_ws, fc_raw, geoms)
-    sl, sc = scratch_dims(geoms, True)
-    return dispatch.pallas_call(
+        specs.append(pl.BlockSpec((1, 1), lambda s: (s, 0)))
+        args.append(model_idx)
+    _params(specs, args, ws, thrs, flips, fc_ws, fc_thrs, fc_flips, fc_raw)
+    out_shape = jax.ShapeDtypeStruct(
+        (b, _n_logits(fc_ws, fc_raw, geoms)), jnp.int32)
+    return _call(
         functools.partial(
             _megakernel, geoms=geoms, emit=True, finalize_only=True,
             fc_raw=fc_raw, pooled=pooled,
         ),
-        grid=grid,
-        in_specs=specs,
-        out_specs=[pl.BlockSpec((bb, n_out), lambda s: (s, 0))],
-        out_shape=(jax.ShapeDtypeStruct((b, n_out), jnp.int32),),
-        scratch_shapes=[
-            _vmem((bb, sl, sc), jnp.int32),
-            _vmem((bb, sl, sc), jnp.int32),
-        ],
-        interpret=interpret,
-    )(*args)[0]
+        (b // bb,), specs, args, [out_shape], bb, interpret,
+    )[0]

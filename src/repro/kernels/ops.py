@@ -5,9 +5,11 @@ to block multiples (padding = inactive wordlines / unused bitline pairs, so
 it is numerically inert), tap-shift view construction, and the
 popcount-vs-MXU dispatch heuristic (DESIGN.md §2.4).
 
-On this CPU container every kernel runs with ``interpret=True``; on TPU the
-same call sites compile to real Mosaic kernels (``interpret=False`` via
-``default_interpret``).
+Off the TPU the kernels run in the Pallas interpreter (``interpret=None``
+resolves through ``default_interpret``).  On a TPU they compile to Mosaic
+kernels, whose layouts these wrappers prepare: slots or rows on the tiled
+axes, int8 GEMM operands, and the first layer's stride cut into phases.
+``tests/test_tpu_compile.py`` compiles them for a v5e ahead of time.
 """
 from __future__ import annotations
 
@@ -201,6 +203,42 @@ def bitserial_conv1d(
     )[0]
 
 
+def _conv_rows(xq, k: int, stride: int, l_out: int):
+    """(..., B, L, Cw) packed words -> (..., K, Cw, B * L_out) tap views
+    with one lane per (stream, position) row (the batched kernels'
+    layout)."""
+    span = (l_out - 1) * stride + 1
+    taps = jnp.stack([xq[..., t : t + span : stride, :] for t in range(k)],
+                     axis=-4)  # (..., K, B, L_out, Cw)
+    rows = taps.reshape(*taps.shape[:-3], -1, taps.shape[-1])
+    return jnp.swapaxes(rows, -1, -2)
+
+
+def _rows_blocks(b: int, l_out: int, cout: int, bb: int | None,
+                 pooled: bool) -> tuple[int, int, int]:
+    """(slot padding, row block, channel block) for the batched kernels.
+
+    Pooled: one grid cell is one ``bb``-slot tenant block (single-tenant
+    by placement).  Otherwise the row block is whole 128-lane tiles."""
+    bn = _round_up(cout, 8) if cout <= 512 else 256
+    if pooled:
+        return _round_up(b, bb) - b, bb * l_out, bn
+    br = min(_conv.DEFAULT_BR, _round_up(b * l_out, 128))
+    return 0, br, bn
+
+
+def _rows_out(out, b: int, l_out: int, cout: int):
+    """(Cout_pad, R_pad) kernel output -> (B, L_out, Cout)."""
+    return out[:cout, : b * l_out].T.reshape(b, l_out, cout)
+
+
+def _rows_planes(w_t, bn: int):
+    """Ternary ([M,] K, Cin, Cout) -> packed ([M,] K, Cout_pad, Cw)
+    planes, output channels on sublanes."""
+    wp, wn = pack_weight_planes(w_t)  # ([M,] K, Cw, Cout)
+    return tuple(_pad_axis(jnp.swapaxes(x, -1, -2), bn, -2) for x in (wp, wn))
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("bits", "offset", "stride", "pad", "bb", "interpret"),
@@ -231,39 +269,25 @@ def bitserial_conv1d_batched(
     interpret = default_interpret() if interpret is None else interpret
     pooled = model_idx is not None
     b, l, cin = x_u.shape
-    if pooled:
-        k, cin2, cout = w_t.shape[1:]
-    else:
-        k, cin2, cout = w_t.shape
+    k, cin2, cout = w_t.shape[-3:]
     assert cin == cin2, (cin, cin2)
-    x_u = x_u.astype(jnp.uint32)
-    if pad:
-        x_u = jnp.pad(
-            x_u, ((0, 0), (pad, pad), (0, 0)), constant_values=offset
-        )
     l_out = (l + 2 * pad - k) // stride + 1
+    bb = _conv.DEFAULT_BB if bb is None else bb
+    pad_b, br, bn = _rows_blocks(b, l_out, cout, bb, pooled)
+    # spatial padding carries the offset code; padded slots are sliced off
+    x_u = jnp.pad(x_u.astype(jnp.uint32), ((0, pad_b), (pad, pad), (0, 0)),
+                  constant_values=offset)
     planes = jnp.stack(
-        [(x_u >> bi) & 1 for bi in range(bits)], axis=1
-    )  # (B, bits, L_pad, Cin)
-    xq = pack_activations(planes)  # (B, bits, L_pad, Cw)
-    span = (l_out - 1) * stride + 1
-    taps = [xq[:, :, t : t + span : stride] for t in range(k)]
-    xs = jnp.stack(taps, axis=2)  # (B, bits, K, L_out, Cw)
-    wp, wn = pack_weight_planes(w_t)  # ([M,] K, Cw, Cout)
-
-    bb = _pick_block(b, _conv.DEFAULT_BB if bb is None else bb)
-    bn = _pick_block(cout, _conv.DEFAULT_BN)
-    bl = _pick_block(l_out, _conv.DEFAULT_BL)
-    xs = _pad_axis(xs, bb, 0)
-    xs = _pad_axis(xs, bl, 3)
-    wp = _pad_axis(wp, bn, -1)
-    wn = _pad_axis(wn, bn, -1)
-    mi = _block_model_idx(model_idx, b, bb, _round_up(b, bb) - b) \
-        if pooled else None
+        [(x_u >> bi) & 1 for bi in range(bits)], axis=0
+    )  # (bits, B, L_pad, Cin)
+    xs = _pad_axis(_conv_rows(pack_activations(planes), k, stride, l_out),
+                   br, -1)
+    wp, wn = _rows_planes(w_t, bn)
+    mi = _block_model_idx(model_idx, b, bb, pad_b) if pooled else None
     out = _conv.bnn_bitserial_step_packed(
-        xs, wp, wn, mi, bits=bits, bb=bb, bl=bl, bn=bn, interpret=interpret
+        xs, wp, wn, mi, br=br, bn=bn, interpret=interpret
     )
-    acc = out[:b, :l_out, :cout]
+    acc = _rows_out(out, b, l_out, cout)
     if offset:
         if pooled:
             wsum = jnp.sum(w_t.astype(jnp.int32), axis=(1, 2))  # (M, Cout)
@@ -281,75 +305,44 @@ def bitserial_conv1d_batched(
 # ---------------------------------------------------------------------------
 
 @functools.partial(
-    jax.jit,
-    static_argnames=("stride", "pad", "pool", "mode", "bb", "interpret"),
+    jax.jit, static_argnames=("stride", "pad", "bb", "interpret"),
 )
 def bnn_conv1d_batched(
     x_bits: jax.Array,
     w_t: jax.Array,
-    thr: jax.Array | None = None,
-    flip: jax.Array | None = None,
     model_idx: jax.Array | None = None,
     *,
     stride: int = 1,
     pad: int = 0,
-    pool: int = 1,
-    mode: str = "sa",
     bb: int | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
-    """Batched binary conv1d with weights shared across the batch axis.
+    """Batched binary raw conv1d with weights shared across the batch axis.
 
     x_bits (B, L, Cin) {0,1}; w_t (K, Cin, Cout) broadcast over B.  Output
-    (B, L_out//pool, Cout) uint32 bits ((B, L_out, Cout) int32 when raw).
-    The batch axis maps straight onto the kernel grid: one weight fetch
-    serves every stream, mirroring shared-weight CIM batching.  With
-    ``model_idx`` ((B,) int32 tenant ids, constant per ``bb`` slot block)
-    ``w_t`` is a pooled (M, K, Cin, Cout) stack (raw mode only).
+    (B, L_out, Cout) int32 raw popcount diff.  Every (stream, position)
+    row maps onto the kernel grid: one weight fetch serves every stream,
+    mirroring shared-weight CIM batching.  With ``model_idx`` ((B,) int32
+    tenant ids, constant per ``bb`` slot block) ``w_t`` is a pooled
+    (M, K, Cin, Cout) stack.
     """
     interpret = default_interpret() if interpret is None else interpret
     pooled = model_idx is not None
-    b = x_bits.shape[0]
-    if pooled:
-        k, cin, cout = w_t.shape[1:]
-    else:
-        k, cin, cout = w_t.shape
-    l = x_bits.shape[1]
+    b, l = x_bits.shape[:2]
+    k, cin, cout = w_t.shape[-3:]
     l_out = (l + 2 * pad - k) // stride + 1
+    bb = _conv.DEFAULT_BB if bb is None else bb
+    pad_b, br, bn = _rows_blocks(b, l_out, cout, bb, pooled)
 
     xq = pack_activations(x_bits)  # (B, L, Cw)
-    if pad:
-        xq = jnp.pad(xq, ((0, 0), (pad, pad), (0, 0)))
-    taps = [
-        xq[:, t : t + (l_out - 1) * stride + 1 : stride] for t in range(k)
-    ]
-    xs = jnp.stack(taps, axis=1)  # (B, K, L_out, Cw)
-    wp, wn = pack_weight_planes(w_t)  # ([M,] K, Cw, Cout)
-
-    bb = _pick_block(b, _conv.DEFAULT_BB if bb is None else bb)
-    bn = _pick_block(cout, _conv.DEFAULT_BN)
-    bl = _pick_block(l_out, _conv.DEFAULT_BL, step=pool)
-    xs = _pad_axis(xs, bb, 0)
-    xs = _pad_axis(xs, bl, 2)
-    wp = _pad_axis(wp, bn, -1)
-    wn = _pad_axis(wn, bn, -1)
-
-    if mode == "sa":
-        assert not pooled, "weight pooling is a raw-conv path feature"
-        thr_p = _pad_axis(thr.astype(jnp.float32), bn, 0)
-        flip_p = _pad_axis(flip.astype(jnp.int32), bn, 0)
-        out = _conv.bnn_conv1d_step_packed(
-            xs, wp, wn, thr_p, flip_p,
-            pool=pool, bb=bb, bl=bl, bn=bn, mode="sa", interpret=interpret,
-        )
-        return out[:b, : l_out // pool, :cout]
-    mi = _block_model_idx(model_idx, b, bb, _round_up(b, bb) - b) \
-        if pooled else None
+    xq = jnp.pad(xq, ((0, pad_b), (pad, pad), (0, 0)))
+    xs = _pad_axis(_conv_rows(xq, k, stride, l_out), br, -1)
+    wp, wn = _rows_planes(w_t, bn)
+    mi = _block_model_idx(model_idx, b, bb, pad_b) if pooled else None
     out = _conv.bnn_conv1d_step_packed(
-        xs, wp, wn, None, None, mi,
-        pool=1, bb=bb, bl=bl, bn=bn, mode="raw", interpret=interpret,
+        xs, wp, wn, mi, br=br, bn=bn, interpret=interpret,
     )
-    return out[:b, :l_out, :cout]
+    return _rows_out(out, b, l_out, cout)
 
 
 # ---------------------------------------------------------------------------
@@ -362,14 +355,6 @@ def bnn_conv1d_batched(
 # so each device runs the kernel on its *local* block of batch rows with
 # the (replicated) weights — zero collectives, exactly the semantics of
 # the slot pool where a stream's math never leaves its shard.
-
-def _shard_map():
-    try:  # moved out of experimental after 0.4.x
-        from jax import shard_map  # type: ignore[attr-defined]
-        return shard_map
-    except ImportError:  # pragma: no cover - depends on jax version
-        from jax.experimental.shard_map import shard_map
-        return shard_map
 
 
 def _batch_spec(mesh):
@@ -385,50 +370,43 @@ def _data_size(mesh) -> int:
     return dp_size(mesh)
 
 
+def _per_shard(fn, mesh, args, batched):
+    """Run ``fn(*args)`` on each shard's local rows: ``batched[i]`` says
+    whether ``args[i]`` is split over the data axes or replicated."""
+    bspec, rep = _batch_spec(mesh)
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=tuple(bspec if s else rep for s in batched),
+        out_specs=bspec, check_vma=False,
+    )(*args)
+
+
 def bnn_conv1d_batched_sharded(
     x_bits: jax.Array,
     w_t: jax.Array,
-    thr: jax.Array | None = None,
-    flip: jax.Array | None = None,
     model_idx: jax.Array | None = None,
     *,
     mesh=None,
     stride: int = 1,
     pad: int = 0,
-    pool: int = 1,
-    mode: str = "sa",
     bb: int | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
     """``bnn_conv1d_batched`` with the batch axis sharded over ``mesh``.
 
-    Each shard convolves its own rows; weights/thresholds are replicated
-    (pooled (M, ...) stacks replicate whole, like the single weight set).
-    With no mesh (or a 1-device mesh) this IS ``bnn_conv1d_batched`` —
-    the single-device path stays byte-identical.
+    Each shard convolves its own rows; weights are replicated (pooled
+    (M, ...) stacks replicate whole, like the single weight set).  With no
+    mesh (or a 1-device mesh) this IS ``bnn_conv1d_batched`` — the
+    single-device path stays byte-identical.
     """
-    kw = dict(stride=stride, pad=pad, pool=pool, mode=mode, bb=bb,
-              interpret=interpret)
+    kw = dict(stride=stride, pad=pad, bb=bb, interpret=interpret)
     if mesh is None or _data_size(mesh) == 1:
-        return bnn_conv1d_batched(x_bits, w_t, thr, flip, model_idx, **kw)
-    bspec, rep = _batch_spec(mesh)
-    if mode == "sa":
-        fn = lambda x, w, t, f: bnn_conv1d_batched(x, w, t, f, **kw)
-        return _shard_map()(
-            fn, mesh=mesh, in_specs=(bspec, rep, rep, rep),
-            out_specs=bspec, check_rep=False,
-        )(x_bits, w_t, thr, flip)
+        return bnn_conv1d_batched(x_bits, w_t, model_idx, **kw)
     if model_idx is not None:
-        fn = lambda x, w, mi: bnn_conv1d_batched(x, w, None, None, mi, **kw)
-        return _shard_map()(
-            fn, mesh=mesh, in_specs=(bspec, rep, bspec), out_specs=bspec,
-            check_rep=False,
-        )(x_bits, w_t, model_idx)
-    fn = lambda x, w: bnn_conv1d_batched(x, w, **kw)
-    return _shard_map()(
-        fn, mesh=mesh, in_specs=(bspec, rep), out_specs=bspec,
-        check_rep=False,
-    )(x_bits, w_t)
+        return _per_shard(
+            lambda x, w, mi: bnn_conv1d_batched(x, w, mi, **kw), mesh,
+            (x_bits, w_t, model_idx), (True, False, True))
+    return _per_shard(lambda x, w: bnn_conv1d_batched(x, w, **kw), mesh,
+                      (x_bits, w_t), (True, False))
 
 
 def bitserial_conv1d_batched_sharded(
@@ -450,26 +428,86 @@ def bitserial_conv1d_batched_sharded(
               interpret=interpret)
     if mesh is None or _data_size(mesh) == 1:
         return bitserial_conv1d_batched(x_u, w_t, model_idx, **kw)
-    bspec, rep = _batch_spec(mesh)
     if model_idx is not None:
-        fn = lambda x, w, mi: bitserial_conv1d_batched(x, w, mi, **kw)
-        return _shard_map()(
-            fn, mesh=mesh, in_specs=(bspec, rep, bspec), out_specs=bspec,
-            check_rep=False,
-        )(x_u, w_t, model_idx)
-    fn = lambda x, w: bitserial_conv1d_batched(x, w, **kw)
-    return _shard_map()(
-        fn, mesh=mesh, in_specs=(bspec, rep), out_specs=bspec,
-        check_rep=False,
-    )(x_u, w_t)
+        return _per_shard(
+            lambda x, w, mi: bitserial_conv1d_batched(x, w, mi, **kw), mesh,
+            (x_u, w_t, model_idx), (True, False, True))
+    return _per_shard(lambda x, w: bitserial_conv1d_batched(x, w, **kw),
+                      mesh, (x_u, w_t), (True, False))
 
 
 # ---------------------------------------------------------------------------
 # Hop megakernel entry points (repro.stream fused hop)
 # ---------------------------------------------------------------------------
 
-def _mega_prep(stages, thrs, flips, fc_thrs, fc_flips, pooled=False):
+def _mega_geoms(stages) -> tuple:
+    """Kernel geometry for a plan's conv stages, checked against what the
+    megakernel's layout takes: stride and multi-bit input in stage 0 only,
+    and stage-0 offset codes inside int8."""
     geoms = tuple(_mega.stage_geom(st) for st in stages)
+    for g in geoms[1:]:
+        if g.stride != 1 or g.in_bits != 1:
+            raise ValueError(
+                "hop megakernel: only stage 0 may be strided or take "
+                f"multi-bit input, got {g}")
+    g0 = geoms[0]
+    if g0.in_bits > 1:
+        lo, hi = -g0.in_offset, (1 << g0.in_bits) - 1 - g0.in_offset
+        if lo < -128 or hi > 127:
+            raise ValueError(
+                f"hop megakernel: stage-0 codes [{lo}, {hi}] exceed int8")
+    return geoms
+
+
+def _codes0(x, g):
+    """Stage-0 input codes -> the values its GEMM multiplies: the code
+    masked to ``in_bits`` (the bit planes telescope to it) minus the
+    offset; binary input passes through."""
+    x = jnp.asarray(x, jnp.int32)
+    if g.in_bits > 1:
+        x = jnp.bitwise_and(x, (1 << g.in_bits) - 1) - g.in_offset
+    return x
+
+
+def _phases(x, g, n: int):
+    """(B, L, cin) stage-0 window -> (Q, B, stride * cin) stride phases
+    for ``n`` outputs: row ``q`` holds samples ``q*s .. q*s + s - 1``, so
+    tap group ``a`` of output ``j`` is row ``j + a`` (see
+    ``_mega_weights``).  At least one row, so an empty flush still has a
+    block."""
+    a = -(-g.k // g.stride)
+    q = max(n + a - 1, 1)
+    need = q * g.stride
+    x = jnp.pad(x, ((0, 0), (0, max(need - x.shape[1], 0)), (0, 0)))
+    x = x[:, :need].reshape(x.shape[0], q, g.stride * g.cin)
+    return jnp.swapaxes(x, 0, 1)
+
+
+def _flush0(tail0, g):
+    """Stage 0's flush window (its tail + the right pad, whose offset code
+    is 0 after ``_codes0``) in stride phases."""
+    win = jnp.concatenate(
+        [_codes0(tail0, g),
+         jnp.zeros((tail0.shape[0], g.pad, g.cin), jnp.int32)], axis=1)
+    return _phases(win, g, g.flush_conv)
+
+
+def _mega_weights(geoms, ws, thrs, flips, fc_ws, fc_thrs, fc_flips,
+                  pooled: bool):
+    """Weights in the kernel's dtypes: int8 tap groups (stage 0's ``k``
+    taps regrouped into ``ceil(k / s)`` groups of ``s * cin`` rows, zero
+    where a group runs past ``k``), (1, C) float32 thresholds and int32
+    flips — each with a leading tenant axis when ``pooled``."""
+    g0 = geoms[0]
+    a0 = -(-g0.k // g0.stride)
+    w0 = jnp.asarray(ws[0])
+    lead = w0.shape[:-3]
+    w0 = jnp.pad(w0, ((0, 0),) * len(lead)
+                 + ((0, a0 * g0.stride - g0.k), (0, 0), (0, 0)))
+    w0 = w0.reshape(*lead, a0, g0.stride * g0.cin, g0.cout)
+
+    def _i8(w):
+        return jnp.asarray(w).astype(jnp.int8)
 
     def _sa(x, dtype):
         x = jnp.asarray(x).astype(dtype)
@@ -477,11 +515,22 @@ def _mega_prep(stages, thrs, flips, fc_thrs, fc_flips, pooled=False):
             return x.reshape(x.shape[0], 1, -1)
         return x.reshape(1, -1)
 
-    thr_p = tuple(_sa(t, jnp.float32) for t in thrs)
-    flip_p = tuple(_sa(f, jnp.int32) for f in flips)
-    fct_p = tuple(_sa(t, jnp.float32) for t in fc_thrs)
-    fcf_p = tuple(_sa(f, jnp.int32) for f in fc_flips)
-    return geoms, thr_p, flip_p, fct_p, fcf_p
+    return (
+        tuple(_i8(w) for w in (w0, *ws[1:])),
+        tuple(_sa(t, jnp.float32) for t in thrs),
+        tuple(_sa(f, jnp.int32) for f in flips),
+        tuple(_i8(w) for w in fc_ws),
+        tuple(_sa(t, jnp.float32) for t in fc_thrs),
+        tuple(_sa(f, jnp.int32) for f in fc_flips),
+    )
+
+
+def _mega_bb(b: int, bb: int | None, pooled: bool) -> int:
+    """Slot block: the tenant block when pooled (blocks must stay
+    single-tenant), else the default rounded to whole (8, 128) tiles."""
+    if pooled:
+        return min(bb, b)
+    return min(_mega.DEFAULT_BB if bb is None else bb, _round_up(b, 8))
 
 
 def _block_model_idx(model_idx, b, bb, pad_b):
@@ -495,6 +544,22 @@ def _block_model_idx(model_idx, b, bb, pad_b):
     if pad_b:
         mi = jnp.pad(mi, ((0, pad_b),))
     return mi.reshape(-1, bb)[:, :1]
+
+
+def _mega_state(tails, pendings, geoms, pad):
+    """Stage > 0 tails and every pending carry, time-major (T, B, C) —
+    zero-width state never enters the kernel."""
+    t_in = tuple(jnp.swapaxes(pad(jnp.asarray(tails[i], jnp.int32)), 0, 1)
+                 for i, g in enumerate(geoms) if i and g.tail)
+    p_in = tuple(jnp.swapaxes(pad(jnp.asarray(p, jnp.int32)), 0, 1)
+                 for p, g in zip(pendings, geoms) if g.phase)
+    return t_in, p_in
+
+
+def _slot_padder(pad_b: int):
+    if not pad_b:
+        return lambda x: x
+    return lambda x: jnp.pad(x, ((0, pad_b),) + ((0, 0),) * (x.ndim - 1))
 
 
 def hop_megakernel(
@@ -528,49 +593,49 @@ def hop_megakernel(
     ``(tails, pendings, gap)`` plus int32 logits when ``emit`` (the ghost
     flush + classifier ride in the SAME launch).  Bit-exact with the
     per-stage path — kernels/hop_megakernel.py is the contract.
+
+    Layout work stays here, in XLA around the launch: stage 0's window
+    (its tail + the hop) is built and cut into stride phases, its tail
+    carry and mask merge happen here, and the other stages' state is
+    transposed to the kernel's time-major layout and back.
     """
     interpret = default_interpret() if interpret is None else interpret
     pooled = model_idx is not None
-    geoms, thr_p, flip_p, fct_p, fcf_p = _mega_prep(
-        stages, thrs, flips, fc_thrs, fc_flips, pooled
-    )
+    geoms = _mega_geoms(stages)
+    g0 = geoms[0]
     b = gap.shape[0]
-    bb = _mega.DEFAULT_BB if bb is None else bb
-    bb = min(bb, b)
+    bb = _mega_bb(b, bb, pooled)
     pad_b = _round_up(b, bb) - b
-    nz_t = [i for i, g in enumerate(geoms) if g.tail]
-    nz_p = [i for i, g in enumerate(geoms) if g.phase]
-    t_in = [jnp.asarray(tails[i], jnp.int32) for i in nz_t]
-    p_in = [jnp.asarray(pendings[i], jnp.int32) for i in nz_p]
-    audio = jnp.asarray(audio, jnp.int32)
-    gap = jnp.asarray(gap, jnp.int32)
-    if pad_b:
-        padb = lambda x: jnp.pad(  # noqa: E731
-            x, ((0, pad_b),) + ((0, 0),) * (x.ndim - 1)
-        )
-        audio, gap = padb(audio), padb(gap)
-        mask = jnp.pad(mask.astype(jnp.int32), ((0, pad_b),))
-        t_in = [padb(t) for t in t_in]
-        p_in = [padb(p) for p in p_in]
-    mi = _block_model_idx(model_idx, b, bb, pad_b) if pooled else None
+    pad = _slot_padder(pad_b)
+    m = pad(jnp.asarray(mask, jnp.int32).reshape(b, 1))
+    tail0 = pad(jnp.asarray(tails[0], jnp.int32))
+    win0 = jnp.concatenate([tail0, pad(jnp.asarray(audio, jnp.int32))],
+                           axis=1)
+    tail0 = jnp.where(m[:, :, None] != 0,
+                      win0[:, g0.n_conv * g0.stride :], tail0)
+    t_in, p_in = _mega_state(tails, pendings, geoms, pad)
     out = _mega.hop_megakernel_packed(
-        audio, mask, tuple(t_in), tuple(p_in), gap,
-        tuple(jnp.asarray(w, jnp.int32) for w in ws), thr_p, flip_p,
-        tuple(jnp.asarray(w, jnp.int32) for w in fc_ws), fct_p, fcf_p, mi,
+        _phases(_codes0(win0, g0), g0, g0.n_conv), m, t_in, p_in,
+        pad(jnp.asarray(gap, jnp.int32)),
+        _flush0(tail0, g0) if emit else None,
+        *_mega_weights(geoms, ws, thrs, flips, fc_ws, fc_thrs, fc_flips,
+                       pooled),
+        _block_model_idx(model_idx, b, bb, pad_b) if pooled else None,
         geoms=geoms, emit=emit, fc_raw=tuple(fc_raw), bb=bb,
         interpret=interpret,
     )
-    unpad = (lambda x: x[:b]) if pad_b else (lambda x: x)
-    tails_out = list(tails)
-    for j, i in enumerate(nz_t):
-        tails_out[i] = unpad(out[0][j])
+    tails_out = [tail0[:b]] + list(tails[1:])
+    it = iter(out[0])
+    for i, g in enumerate(geoms):
+        if i and g.tail:
+            tails_out[i] = jnp.swapaxes(next(it), 0, 1)[:b]
     pends_out = list(pendings)
-    for j, i in enumerate(nz_p):
-        pends_out[i] = unpad(out[1][j])
-    gap_out = unpad(out[2])
-    if emit:
-        return tuple(tails_out), tuple(pends_out), gap_out, unpad(out[3])
-    return tuple(tails_out), tuple(pends_out), gap_out
+    it = iter(out[1])
+    for i, g in enumerate(geoms):
+        if g.phase:
+            pends_out[i] = jnp.swapaxes(next(it), 0, 1)[:b]
+    res = (tuple(tails_out), tuple(pends_out), out[2][:b])
+    return res + (out[3][:b],) if emit else res
 
 
 def hop_megakernel_sharded(
@@ -610,31 +675,18 @@ def hop_megakernel_sharded(
     out_specs = ((bspec,) * nt, (bspec,) * npd, bspec)
     if emit:
         out_specs = out_specs + (bspec,)
+    in_specs = (bspec, bspec, (bspec,) * nt, (bspec,) * npd, bspec,
+                (rep,) * ns, (rep,) * ns, (rep,) * ns,
+                (rep,) * nf, (rep,) * nf, (rep,) * nf)
+    args = (audio, mask, tuple(tails), tuple(pendings), gap, tuple(ws),
+            tuple(thrs), tuple(flips), tuple(fc_ws), tuple(fc_thrs),
+            tuple(fc_flips))
     if model_idx is not None:
-        fn = lambda a, m, t, p, g, w, th, fl, fw, ft, ff, mi: hop_megakernel(
-            a, m, t, p, g, w, th, fl, fw, ft, ff, mi, **kw
-        )
-        return _shard_map()(
-            fn, mesh=mesh,
-            in_specs=(bspec, bspec, (bspec,) * nt, (bspec,) * npd, bspec,
-                      (rep,) * ns, (rep,) * ns, (rep,) * ns,
-                      (rep,) * nf, (rep,) * nf, (rep,) * nf, bspec),
-            out_specs=out_specs, check_rep=False,
-        )(audio, mask, tuple(tails), tuple(pendings), gap, tuple(ws),
-          tuple(thrs), tuple(flips), tuple(fc_ws), tuple(fc_thrs),
-          tuple(fc_flips), model_idx)
-    fn = lambda a, m, t, p, g, w, th, fl, fw, ft, ff: hop_megakernel(
-        a, m, t, p, g, w, th, fl, fw, ft, ff, **kw
-    )
-    return _shard_map()(
-        fn, mesh=mesh,
-        in_specs=(bspec, bspec, (bspec,) * nt, (bspec,) * npd, bspec,
-                  (rep,) * ns, (rep,) * ns, (rep,) * ns,
-                  (rep,) * nf, (rep,) * nf, (rep,) * nf),
-        out_specs=out_specs, check_rep=False,
-    )(audio, mask, tuple(tails), tuple(pendings), gap, tuple(ws),
-      tuple(thrs), tuple(flips), tuple(fc_ws), tuple(fc_thrs),
-      tuple(fc_flips))
+        in_specs, args = in_specs + (bspec,), args + (model_idx,)
+    return jax.shard_map(
+        lambda *a: hop_megakernel(*a, **kw), mesh=mesh, in_specs=in_specs,
+        out_specs=out_specs, check_vma=False,
+    )(*args)
 
 
 def finalize_megakernel(
@@ -657,33 +709,21 @@ def finalize_megakernel(
     """Standalone ghost-flush + classifier launch (hop-boundary peeks)."""
     interpret = default_interpret() if interpret is None else interpret
     pooled = model_idx is not None
-    geoms, thr_p, flip_p, fct_p, fcf_p = _mega_prep(
-        stages, thrs, flips, fc_thrs, fc_flips, pooled
-    )
+    geoms = _mega_geoms(stages)
     b = gap.shape[0]
-    bb = _mega.DEFAULT_BB if bb is None else bb
-    bb = min(bb, b)
+    bb = _mega_bb(b, bb, pooled)
     pad_b = _round_up(b, bb) - b
-    t_in = [jnp.asarray(tails[i], jnp.int32)
-            for i, g in enumerate(geoms) if g.tail]
-    p_in = [jnp.asarray(pendings[i], jnp.int32)
-            for i, g in enumerate(geoms) if g.phase]
-    gap = jnp.asarray(gap, jnp.int32)
-    if pad_b:
-        padb = lambda x: jnp.pad(  # noqa: E731
-            x, ((0, pad_b),) + ((0, 0),) * (x.ndim - 1)
-        )
-        gap = padb(gap)
-        t_in = [padb(t) for t in t_in]
-        p_in = [padb(p) for p in p_in]
-    mi = _block_model_idx(model_idx, b, bb, pad_b) if pooled else None
+    pad = _slot_padder(pad_b)
+    t_in, p_in = _mega_state(tails, pendings, geoms, pad)
     out = _mega.finalize_megakernel_packed(
-        tuple(t_in), tuple(p_in), gap,
-        tuple(jnp.asarray(w, jnp.int32) for w in ws), thr_p, flip_p,
-        tuple(jnp.asarray(w, jnp.int32) for w in fc_ws), fct_p, fcf_p, mi,
+        t_in, p_in, pad(jnp.asarray(gap, jnp.int32)),
+        _flush0(pad(jnp.asarray(tails[0], jnp.int32)), geoms[0]),
+        *_mega_weights(geoms, ws, thrs, flips, fc_ws, fc_thrs, fc_flips,
+                       pooled),
+        _block_model_idx(model_idx, b, bb, pad_b) if pooled else None,
         geoms=geoms, fc_raw=tuple(fc_raw), bb=bb, interpret=interpret,
     )
-    return out[:b] if pad_b else out
+    return out[:b]
 
 
 def finalize_megakernel_sharded(
@@ -712,30 +752,17 @@ def finalize_megakernel_sharded(
                                    **kw)
     bspec, rep = _batch_spec(mesh)
     nt, npd, ns, nf = len(tails), len(pendings), len(ws), len(fc_ws)
+    in_specs = ((bspec,) * nt, (bspec,) * npd, bspec,
+                (rep,) * ns, (rep,) * ns, (rep,) * ns,
+                (rep,) * nf, (rep,) * nf, (rep,) * nf)
+    args = (tuple(tails), tuple(pendings), gap, tuple(ws), tuple(thrs),
+            tuple(flips), tuple(fc_ws), tuple(fc_thrs), tuple(fc_flips))
     if model_idx is not None:
-        fn = lambda t, p, g, w, th, fl, fw, ft, ff, mi: finalize_megakernel(
-            t, p, g, w, th, fl, fw, ft, ff, mi, **kw
-        )
-        return _shard_map()(
-            fn, mesh=mesh,
-            in_specs=((bspec,) * nt, (bspec,) * npd, bspec,
-                      (rep,) * ns, (rep,) * ns, (rep,) * ns,
-                      (rep,) * nf, (rep,) * nf, (rep,) * nf, bspec),
-            out_specs=bspec, check_rep=False,
-        )(tuple(tails), tuple(pendings), gap, tuple(ws), tuple(thrs),
-          tuple(flips), tuple(fc_ws), tuple(fc_thrs), tuple(fc_flips),
-          model_idx)
-    fn = lambda t, p, g, w, th, fl, fw, ft, ff: finalize_megakernel(
-        t, p, g, w, th, fl, fw, ft, ff, **kw
-    )
-    return _shard_map()(
-        fn, mesh=mesh,
-        in_specs=((bspec,) * nt, (bspec,) * npd, bspec,
-                  (rep,) * ns, (rep,) * ns, (rep,) * ns,
-                  (rep,) * nf, (rep,) * nf, (rep,) * nf),
-        out_specs=bspec, check_rep=False,
-    )(tuple(tails), tuple(pendings), gap, tuple(ws), tuple(thrs),
-      tuple(flips), tuple(fc_ws), tuple(fc_thrs), tuple(fc_flips))
+        in_specs, args = in_specs + (bspec,), args + (model_idx,)
+    return jax.shard_map(
+        lambda *a: finalize_megakernel(*a, **kw), mesh=mesh,
+        in_specs=in_specs, out_specs=bspec, check_vma=False,
+    )(*args)
 
 
 @jax.jit
@@ -794,23 +821,15 @@ def classifier_tail_sharded(
     if mesh is None or _data_size(mesh) == 1:
         return classifier_tail(gap, fc_ws, fc_thrs, fc_flips, model_idx,
                                **kw)
-    bspec, rep = _batch_spec(mesh)
-    n = len(fc_ws)
+    args = (gap, tuple(fc_ws), tuple(fc_thrs), tuple(fc_flips))
     if model_idx is not None:
-        fn = lambda g, ws, ts, fs, mi: classifier_tail(
-            g, ws, ts, fs, mi, **kw
-        )
-        return _shard_map()(
-            fn, mesh=mesh,
-            in_specs=(bspec, (rep,) * n, (rep,) * n, (rep,) * n, bspec),
-            out_specs=bspec, check_rep=False,
-        )(gap, tuple(fc_ws), tuple(fc_thrs), tuple(fc_flips), model_idx)
-    fn = lambda g, ws, ts, fs: classifier_tail(g, ws, ts, fs, **kw)
-    return _shard_map()(
-        fn, mesh=mesh,
-        in_specs=(bspec, (rep,) * n, (rep,) * n, (rep,) * n),
-        out_specs=bspec, check_rep=False,
-    )(gap, tuple(fc_ws), tuple(fc_thrs), tuple(fc_flips))
+        return _per_shard(
+            lambda g, ws, ts, fs, mi: classifier_tail(g, ws, ts, fs, mi,
+                                                      **kw),
+            mesh, args + (model_idx,), (True, False, False, False, True))
+    return _per_shard(
+        lambda g, ws, ts, fs: classifier_tail(g, ws, ts, fs, **kw), mesh,
+        args, (True, False, False, False))
 
 
 # ---------------------------------------------------------------------------
@@ -837,14 +856,14 @@ def classifier_tail(
     ids, constant per ``bb`` slot block) the fc params are pooled
     (M, ...) stacks.  Returns (B, n_classes) int32 raw logits — bit-exact
     with ``StreamState.logits`` (integer thresholds make the float32
-    compare exact; counts keep every product inside int32).
+    compare exact; the int8 GEMMs accumulate in int32).
     """
     interpret = default_interpret() if interpret is None else interpret
     pooled = model_idx is not None
     b = gap.shape[0]
     bb = _pick_block(b, _conv.DEFAULT_BB if bb is None else bb)
     gap_p = _pad_axis(gap.astype(jnp.int32), bb, 0)
-    ws = tuple(w.astype(jnp.int32) for w in fc_ws)
+    ws = tuple(w.astype(jnp.int8) for w in fc_ws)
     if pooled:
         thrs = tuple(
             t.astype(jnp.float32).reshape(t.shape[0], 1, -1)

@@ -31,6 +31,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels import dispatch
+from repro.kernels.bnn_conv1d import sa_bits
 
 DEFAULT_BM = 256
 DEFAULT_BN = 256
@@ -54,9 +55,7 @@ def _popdiff_tile(x, wp, wn, kw: int):
 
 def _kernel_sa(x_ref, wp_ref, wn_ref, thr_ref, flip_ref, o_ref, *, kw: int):
     diff = _popdiff_tile(x_ref[...], wp_ref[...], wn_ref[...], kw)
-    ge = diff.astype(jnp.float32) >= thr_ref[0, :][None, :]
-    flip = flip_ref[0, :][None, :] != 0
-    o_ref[...] = jnp.where(flip, ~ge, ge).astype(jnp.uint32)
+    o_ref[...] = sa_bits(diff, thr_ref[...], flip_ref[...]).astype(jnp.uint32)
 
 
 def _kernel_raw(x_ref, wp_ref, wn_ref, o_ref, *, kw: int):
@@ -76,7 +75,7 @@ def twm_matmul(
     bm: int = DEFAULT_BM,
     bn: int = DEFAULT_BN,
     mode: str = "sa",
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Packed popcount GEMM with optional fused SA epilogue.
 
@@ -131,9 +130,7 @@ def _kernel_mxu(x_ref, w_ref, thr_ref, flip_ref, o_ref):
         (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.int32,
     )
-    ge = acc.astype(jnp.float32) >= thr_ref[0, :][None, :]
-    flip = flip_ref[0, :][None, :] != 0
-    o_ref[...] = jnp.where(flip, ~ge, ge).astype(jnp.uint32)
+    o_ref[...] = sa_bits(acc, thr_ref[...], flip_ref[...]).astype(jnp.uint32)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "interpret"))
@@ -145,7 +142,7 @@ def twm_matmul_mxu(
     *,
     bm: int = 256,
     bn: int = 256,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """int8 MXU GEMM (x in {0,1}, w in {-1,0,1}) with the same SA epilogue.
 

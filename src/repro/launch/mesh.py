@@ -13,15 +13,10 @@ from __future__ import annotations
 
 import jax
 
-try:  # AxisType landed after jax 0.4.x; Auto is the old default behavior
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on jax version
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _axis_kw(n_axes: int) -> dict:
-    if AxisType is None:
-        return {}
     return {"axis_types": (AxisType.Auto,) * n_axes}
 
 

@@ -24,9 +24,11 @@ axis and advances them with ONE jitted step per hop:
     re-trace at the new static shape, so bursty arrivals are absorbed
     without provisioning for the peak and results stay bit-exact across
     the resize boundary;
-  * the batched step is built on the batched Pallas conv kernel
-    (kernels/bnn_conv1d.bnn_conv1d_step_packed) or an equivalent pure-jnp
-    einsum path (default on CPU, where Pallas runs interpreted).
+  * the batched step runs one of three hop backends: ``jnp`` (plain XLA
+    einsums, the default and the reference), ``pallas`` (the per-stage
+    popcount kernels of kernels/bnn_conv1d) or ``megakernel`` (the whole
+    hop in one kernel, kernels/hop_megakernel).  Off the TPU the Pallas
+    kernels run interpreted; on a TPU they compile to Mosaic kernels.
 
 **Mesh sharding (one pool, whole mesh).**  Pass ``mesh`` (see
 ``launch.mesh.make_stream_mesh``) and the batch axis of every piece of
@@ -515,8 +517,8 @@ class _BatchedModel:
             return jnp.einsum("bknc,kco->bno", xs, w)
         if self.backend == "pallas":
             return ops.bnn_conv1d_batched_sharded(
-                window.astype(jnp.uint32), w, None, None, model_idx,
-                mesh=self.mesh, stride=st.stride, pad=0, mode="raw",
+                window.astype(jnp.uint32), w, model_idx,
+                mesh=self.mesh, stride=st.stride, pad=0,
                 bb=self._bb(window.shape[0]), interpret=self.interpret,
             )
         taps = [
@@ -1320,7 +1322,7 @@ class StreamScheduler:
         else:
             tails, pendings, gap = self._model.step(*args, emit=False)
             logits = post = None
-        if n_entries is not None and self._jit_entries() != n_entries:
+        if self._jit_entries() != n_entries:
             # this hop traced a new (capacity, emit) shape — the compile
             # spike idle pre-warming exists to hide (the multi-tenant
             # suite pins the post-grow hop clean when prewarm=True)
@@ -1331,13 +1333,9 @@ class StreamScheduler:
         self._gap = gap
         return logits, post
 
-    def _jit_entries(self) -> int | None:
-        """Jit-cache entry count of the batched step (None when the jax
-        version exposes no cache introspection)."""
-        try:
-            return self._model.step._cache_size()
-        except AttributeError:  # pragma: no cover - jax-version dependent
-            return None
+    def _jit_entries(self) -> int:
+        """Jit-cache entry count of the batched step."""
+        return self._model.step._cache_size()
 
     def _fold_hop(self, ready_slots, shard_counts, logits_h, post_h,
                   t0, t_pack, t_dispatch, t_device,

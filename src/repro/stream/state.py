@@ -746,6 +746,9 @@ class StreamState:
             )
             slack = max(1, -(-slack // max(1, st.stride)))
         self.started = [False] * len(plan.convs)
+        # frames still to skip per stage: with k < stride the next window
+        # can start past the frames that have arrived so far
+        self.skip = [0] * len(plan.convs)
         self.gap = np.zeros(plan.gap_channels, np.int64)
         self.frames = 0          # final-conv pooled frames accumulated in GAP
         self.samples_seen = 0
@@ -784,10 +787,13 @@ class StreamState:
                 pad_val = st.in_offset if st.in_bits > 1 else 0
                 hist.push(np.full((st.pad, st.cin), pad_val, np.int32))
                 self.started[i] = True
-            hist.push(cur)
             if flush:
                 pad_val = st.in_offset if st.in_bits > 1 else 0
-                hist.push(np.full((st.pad, st.cin), pad_val, np.int32))
+                cur = np.concatenate(
+                    [cur, np.full((st.pad, st.cin), pad_val, np.int32)])
+            n_skip = min(self.skip[i], cur.shape[0])
+            self.skip[i] -= n_skip
+            hist.push(cur[n_skip:])
             avail = len(hist)
             n_conv = (avail - st.k) // st.stride + 1 if avail >= st.k else 0
             if n_conv > 0:
@@ -795,7 +801,9 @@ class StreamState:
                 raw = _conv_raw(window, wk, st, n_conv)
                 thr, flip = self.thresholds[st.layer_idx]
                 y = _threshold(raw, thr, flip)
-                hist.drop(n_conv * st.stride)
+                n_drop = min(n_conv * st.stride, avail)
+                hist.drop(n_drop)
+                self.skip[i] = n_conv * st.stride - n_drop
             else:
                 y = np.zeros((0, st.cout), np.uint8)
             # pool: OR over non-overlapping windows, absolute alignment
@@ -853,6 +861,7 @@ class StreamState:
         c.hists = [h.clone() for h in self.hists]
         c.pendings = [p.clone() for p in self.pendings]
         c.started = list(self.started)
+        c.skip = list(self.skip)
         c.gap = self.gap.copy()
         c.frames = self.frames
         c.samples_seen = self.samples_seen
@@ -887,6 +896,7 @@ class StreamState:
                 np.asarray(pendings[i][: st.phase], np.int32)
             )
             self.started[i] = True
+            self.skip[i] = 0  # hop boundaries are whole strides
         self.gap = np.asarray(gap, np.int64).copy()
         self.frames = frames
 

@@ -549,14 +549,18 @@ def test_async_sharded_rebalance_barrier(smoke):
         for sid in sids:
             sched.push_audio(sid, _audio(sid, 0, half))
         sched.drain()
-        # close the low half: shards 0..S/2 empty out -> skew -> migrate
-        out = {sid: _close_fp(sched.close_stream(sid))
-               for sid in sids[:n // 2]}
-        for sid in sids[n // 2:]:
+        # close every stream on shards 0..S/2 (least-loaded placement
+        # spreads consecutive sids over the shards) -> skew -> migrate
+        low = [sid for sid in sids
+               if sched._streams[sid].slot // sched.shard_capacity < S // 2]
+        assert len(low) == n // 2
+        out = {sid: _close_fp(sched.close_stream(sid)) for sid in low}
+        survivors = [sid for sid in sids if sid not in out]
+        for sid in survivors:
             sched.push_audio(sid, _audio(sid, half, 2 * plan.hop_samples))
         sched.drain()
         out.update({sid: _close_fp(sched.close_stream(sid))
-                    for sid in sids[n // 2:]})
+                    for sid in survivors})
         if isinstance(sched, AsyncStreamScheduler):
             sched.shutdown()
         return out, sched.metrics.rebalances

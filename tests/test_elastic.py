@@ -12,12 +12,8 @@ SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import tempfile
     import jax, jax.numpy as jnp, numpy as np
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    try:
-        from jax.sharding import AxisType
-        kw = {"axis_types": (AxisType.Auto,) * 2}
-    except ImportError:
-        kw = {}
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
+    kw = {"axis_types": (AxisType.Auto,) * 2}
     from repro.train import checkpoint as ckpt
 
     mesh_a = jax.make_mesh((4, 1), ("data", "model"), **kw)

@@ -102,3 +102,20 @@ def test_pick_path_heuristic():
     # tiny-batch (memory-bound) prefers popcount; big GEMM prefers MXU
     assert ops.pick_path(1, 1024, 512) == "popcount"
     assert ops.pick_path(65536, 1024, 4096) == "mxu"
+
+
+def test_interpret_refused_on_tpu_backend(monkeypatch):
+    """An interpreted kernel on a TPU would run slowly in place of the
+    Mosaic kernel: the dispatch layer refuses it."""
+    import jax
+
+    from repro.kernels import dispatch
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops.default_interpret() is False
+    x, w, thr, flip = _rand_dense(8, 32, 16)
+    with pytest.raises(ValueError, match="interpret"):
+        dispatch.pallas_call(lambda x_ref, o_ref: None, interpret=True,
+                             out_shape=jax.ShapeDtypeStruct((8,), jnp.int32))
+    with pytest.raises(ValueError, match="interpret"):
+        ops.twm_linear(x, w, thr, flip, interpret=True)

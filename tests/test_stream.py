@@ -2,6 +2,7 @@
 executor, ring-buffer wraparound, mid-batch join/leave, the in-jit
 finalization tail (per-hop logits == offline prefix), elastic slot-pool
 resize boundaries, detector hysteresis, and the batched Pallas kernels."""
+import dataclasses
 import itertools
 
 import jax
@@ -10,6 +11,7 @@ import numpy as np
 import pytest
 
 from repro.core import compiler, executor
+from repro.core.cnn_spec import CNN1DSpec, Conv1DSpec, FCSpec, GAPSpec
 from repro.kernels import ops, ref
 from repro.models import kws
 from repro.stream import (
@@ -153,6 +155,39 @@ def test_stream_matches_offline_full_clip(smoke):
     st.advance(x[i:] if i < spec.in_len else np.zeros((0,), np.int32),
                flush=True)
     np.testing.assert_array_equal(st.logits(), _offline(prog, x))
+
+
+@pytest.mark.parametrize("k", [3, 5])
+def test_stream_kernel_narrower_than_stride_matches_offline(k):
+    """With k < stride the next window can start past the frames that
+    have arrived: ragged chunks and the flush carry that skip instead of
+    underflowing the ring (every prefix peek == offline executor)."""
+    spec = CNN1DSpec(
+        in_len=700, in_channels=1, in_bits=4, name="narrow",
+        layers=(
+            Conv1DSpec(1, 8, k=k, stride=8, pad=1, in_bits=4, in_offset=8,
+                       name="l0"),
+            Conv1DSpec(8, 8, k=5, stride=1, pad=2, pool=2, name="b1"),
+            GAPSpec(8, name="gap"),
+            FCSpec(8, 8, in_bits=8, name="fc1"),
+            FCSpec(8, kws.N_CLASSES, out_raw=True, name="fc2"),
+        ),
+    )
+    params = kws.init_kws_params(jax.random.PRNGKey(k), spec)
+    weights, thresholds = kws.export_kws(params, spec)
+    x = np.random.default_rng(k).integers(0, 16, (spec.in_len,)).astype(
+        np.uint8)
+    st = StreamState(plan_stream(spec), weights, thresholds)
+    i = 0
+    for sz in itertools.cycle([37, 13, 64, 5, 90]):
+        st.advance(x[i : i + sz])
+        i = min(i + sz, spec.in_len)
+        prog_i = compiler.compile_model(
+            dataclasses.replace(spec, in_len=i), weights, thresholds)
+        np.testing.assert_array_equal(st.peek_logits(), _offline(prog_i,
+                                                                 x[:i]))
+        if i == spec.in_len:
+            break
 
 
 @pytest.mark.parametrize("prefix", [320, 520, 648])
@@ -404,30 +439,21 @@ def test_scheduler_grow_shrink_bitexact(smoke):
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize(
-    "b,l,cin,cout,k,stride,pad,pool",
+    "b,l,cin,cout,k,stride,pad",
     [
-        (3, 40, 8, 16, 3, 1, 1, 1),
-        (8, 32, 24, 40, 3, 1, 1, 2),
-        (5, 66, 16, 20, 5, 2, 2, 1),
+        (3, 40, 8, 16, 3, 1, 1),
+        (8, 32, 24, 40, 3, 1, 1),
+        (5, 66, 16, 20, 5, 2, 2),
     ],
 )
-def test_bnn_conv1d_batched_kernel(b, l, cin, cout, k, stride, pad, pool):
+def test_bnn_conv1d_batched_kernel(b, l, cin, cout, k, stride, pad):
     x = jnp.array(RNG.integers(0, 2, (b, l, cin)), jnp.uint32)
     w = jnp.array(RNG.integers(-1, 2, (k, cin, cout)), jnp.int32)
-    thr = jnp.array(RNG.normal(0, 2, (cout,)), jnp.float32)
-    flip = jnp.array(RNG.integers(0, 2, (cout,)), bool)
-    raw = ops.bnn_conv1d_batched(x, w, stride=stride, pad=pad, mode="raw")
+    raw = ops.bnn_conv1d_batched(x, w, stride=stride, pad=pad)
     np.testing.assert_array_equal(
         np.asarray(raw),
         np.asarray(ref.ref_bnn_conv1d_batched(x, w, stride, pad)),
     )
-    sa = ops.bnn_conv1d_batched(x, w, thr, flip, stride=stride, pad=pad,
-                                pool=pool)
-    want = jnp.stack([
-        ref.ref_bnn_conv1d_sa(x[i], w, thr, flip, stride, pad, pool)
-        for i in range(b)
-    ])
-    np.testing.assert_array_equal(np.asarray(sa), np.asarray(want))
 
 
 def test_classifier_tail_kernel_matches_oracle():
