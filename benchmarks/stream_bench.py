@@ -12,8 +12,8 @@ IS hop-to-logits latency.  Reported:
 
   * steady-state hop latency p50/p95, frames/sec and measured silicon-
     equivalent uJ/inference at B in {8, 64, 256} (every slot active,
-    per-hop logits on), with the hop split into host-pack vs device time
-    (``host_pack_ms_p50`` / ``device_ms_p50`` per config)
+    per-hop logits on), with the hop's host pack and its wait on the
+    device (``host_pack_ms_p50`` / ``fence_ms_p50`` per config)
   * before/after vs the previous committed BENCH_stream.json at B=8
   * the host-pack microbench at B=1024: the pre-arena per-slot ring walk
     (one python pop per stream per hop) vs ``RingArena.pack_hops``'s one
@@ -167,12 +167,13 @@ def _steady(spec, weights, thresholds, n_streams: int, mesh=None,
         "hop_ms_p99": m["step_ms_p99"],
         "hop_ms_p999": m["step_ms_p999"],
         "host_pack_ms_p50": m["host_pack_ms_p50"],
-        "device_ms_p50": m["device_ms_p50"],
-        "device_ms_p95": m["device_ms_p95"],
-        "device_ms_p99": m["device_ms_p99"],
+        "fence_ms_p50": phases["fence"]["ms_p50"],
+        "fence_ms_p95": phases["fence"]["ms_p95"],
+        "fence_ms_p99": phases["fence"]["ms_p99"],
+        "fetch_ms_p50": phases["fetch"]["ms_p50"],
         "latency_estimated": m["latency_estimated"],
-        # the fenced per-phase split of the hop (pack / dispatch / device
-        # / detector): quantiles + each phase's share of hop wall time
+        # the fenced per-phase split of the hop (pack / dispatch / fence /
+        # fetch / detector): quantiles + each phase's share of hop wall
         "phases": {
             p: {k: d[k] for k in ("ms_p50", "ms_p95", "ms_p99", "ms_p999",
                                   "share_of_wall")}
@@ -195,7 +196,7 @@ def _obs_overhead(spec, hop_ms_p50: float, n_streams: int = 256,
     bound.
 
     Replays exactly what one hop adds to the hot path — one ``on_step``
-    (reservoir records, ledger charge) plus the six ``trace.add`` ring
+    (reservoir records, ledger charge) plus the seven ``add_batch`` ring
     appends — with no device work, so the measured per-hop cost is pure
     observability overhead.  The timed region starts *after* the latency
     reservoirs have wrapped, so it measures the saturated regime (ring
@@ -209,12 +210,13 @@ def _obs_overhead(spec, hop_ms_p50: float, n_streams: int = 256,
 
     def hop() -> None:
         metrics.on_step(n_streams, plan.frames_per_hop, 4e-3,
-                        host_pack_s=4e-4, dispatch_s=6e-4, device_s=2.6e-3,
-                        detector_s=4e-4)
+                        host_pack_s=4e-4, dispatch_s=6e-4, fence_s=2.4e-3,
+                        fetch_s=2e-4, detector_s=4e-4)
         tr.add_batch((
             ("pack", 0.0, 4e-4, {"n": n_streams}),
             ("dispatch", 0.0, 6e-4, {}),
-            ("device", 0.0, 2.6e-3, {}),
+            ("fence", 0.0, 2.4e-3, {}),
+            ("fetch", 0.0, 2e-4, {}),
             ("detector", 0.0, 4e-4, {}),
             ("push_fold", 0.0, 1e-4, {}),
             ("hop", 0.0, 4e-3, {"n": n_streams}),
@@ -544,6 +546,7 @@ def _multi_tenant(spec, weights, thresholds) -> dict[str, object]:
         wall_f = drive([fused], [sids], TENANT_ROUNDS)
         hops_f = TENANT_ROUNDS * 4 * total
         mf = fused.metrics.summary()
+        fence_f = fused.metrics.phase_summary()["fence"]
         # the same load on K independent single-tenant schedulers
         scheds, sid_lists = [], []
         for k in range(K):
@@ -564,7 +567,7 @@ def _multi_tenant(spec, weights, thresholds) -> dict[str, object]:
         per_k[str(K)] = {
             "hop_ms_p50": mf["step_ms_p50"],
             "host_pack_ms_p50": mf["host_pack_ms_p50"],
-            "device_ms_p50": mf["device_ms_p50"],
+            "fence_ms_p50": fence_f["ms_p50"],
             "stream_hops_per_sec": hops_f / wall_f,
             "dispatches_per_emit_hop": mk._model.dispatches_per_hop(True),
             "dispatches_per_steady_hop": mk._model.dispatches_per_hop(False),
@@ -713,10 +716,10 @@ def _sharded_sweep(spec, weights, thresholds) -> dict[str, object] | None:
         # kernel launches vs the hop megakernel, same pool, same mesh
         "configs_per_stage": configs_per_stage,
         "configs_fused": configs_fused,
-        "fused_vs_per_stage_device_p50": (
-            configs_per_stage[top]["device_ms_p50"]
-            / configs_fused[top]["device_ms_p50"]
-            if configs_fused[top]["device_ms_p50"] else None
+        "fused_vs_per_stage_fence_p50": (
+            configs_per_stage[top]["fence_ms_p50"]
+            / configs_fused[top]["fence_ms_p50"]
+            if configs_fused[top]["fence_ms_p50"] else None
         ),
         "best_single_stream_hops_per_sec": single,
         "best_multi_stream_hops_per_sec": max(multi) if multi else None,
@@ -904,7 +907,7 @@ def run() -> list[str]:
         row("stream.uj_per_inference", f"{b0['uj_per_inference']:.4f}",
             "measured ledger: mac+sa+sram+ctrl"),
     ]
-    for p in ("pack", "dispatch", "device", "detector"):
+    for p in ("pack", "dispatch", "fence", "fetch", "detector"):
         ph = b0["phases"][p]
         out.append(row(f"stream.phase_{p}_ms_p50", f"{ph['ms_p50']:.3f}",
                        f"p99 {ph['ms_p99']:.3f}, "
@@ -960,17 +963,17 @@ def run() -> list[str]:
             ps = sharded["configs_per_stage"][s]
             out.append(row(
                 f"stream.fused_x{s}", f"{c['stream_hops_per_sec']:.1f}",
-                f"megakernel stream-hops/s; device p50 "
-                f"{c['device_ms_p50']:.1f} ms vs per-stage "
-                f"{ps['device_ms_p50']:.1f} ms, "
+                f"megakernel stream-hops/s; fence p50 "
+                f"{c['fence_ms_p50']:.1f} ms vs per-stage "
+                f"{ps['fence_ms_p50']:.1f} ms, "
                 f"{c['device_dispatches_per_hop']:.0f} vs "
                 f"{ps['device_dispatches_per_hop']:.0f} launches/hop",
             ))
-        fvp = sharded.get("fused_vs_per_stage_device_p50")
+        fvp = sharded.get("fused_vs_per_stage_fence_p50")
         if fvp is not None and not sharded_skipped:
             out.append(row(
                 "stream.fused_vs_per_stage", f"{fvp:.2f}",
-                f"{'PASS' if fvp > 1.0 else 'FAIL'} (fused hop device p50 "
+                f"{'PASS' if fvp > 1.0 else 'FAIL'} (fused hop fence p50 "
                 "faster than per-stage launches, same pool)",
             ))
         fms = sharded.get("fused_multi_vs_single")

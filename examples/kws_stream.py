@@ -93,11 +93,14 @@ def main() -> None:
         assert ok, "streaming/offline divergence"
 
     m = sched.metrics.summary()
+    ps = sched.metrics.phase_summary()
     e = sched.metrics.energy_summary()
     print(f"\nmetrics: {m['frames_total']:.0f} frames, "
           f"{m['frames_per_sec']:.0f} frames/s, "
           f"step p50 {m['step_ms_p50']:.1f} ms (hop -> on-device logits; "
-          f"host pack {m['host_pack_ms_p50']:.2f} ms of it), "
+          f"host pack {m['host_pack_ms_p50']:.2f} ms, wait on the device "
+          f"{ps['fence']['ms_p50']:.2f} ms, result copy "
+          f"{ps['fetch']['ms_p50']:.2f} ms of it), "
           f"silicon-equivalent {e['tops_per_w_equiv']:.0f} TOPS/W")
     print(f"elastic pool: {m['resizes']:.0f} resizes, "
           f"final capacity {sched.capacity} of max {sched.max_capacity}")

@@ -72,13 +72,14 @@ def _num(row: dict, key: str, fmt: str) -> str:
 def stream_lines(bench: dict) -> list[str]:
     """§Streaming table: the BENCH_stream.json steady-state sweep and the
     mesh-sharded 1k-stream sweep, one row per configuration, with each
-    hop's latency split into its host-pack and device halves."""
+    hop's host pack and its wait on the device (the fence) beside its
+    latency."""
     out = [
         "",
         "## Streaming (BENCH_stream.json)",
         "",
         "| config | streams | shards | hop p50 ms | hop p99 ms | "
-        "host-pack ms | device ms | stream-hops/s | uJ/inference |",
+        "host-pack ms | fence ms | stream-hops/s | uJ/inference |",
         "|---|---|---|---|---|---|---|---|---|",
     ]
 
@@ -91,7 +92,7 @@ def stream_lines(bench: dict) -> list[str]:
             f"| {_num(r, 'hop_ms_p50', '.3f')} "
             f"| {_num(r, 'hop_ms_p99', '.3f')} "
             f"| {_num(r, 'host_pack_ms_p50', '.3f')} "
-            f"| {_num(r, 'device_ms_p50', '.3f')} "
+            f"| {_num(r, 'fence_ms_p50', '.3f')} "
             f"| {_num(r, 'stream_hops_per_sec', '.0f')} "
             f"| {_num(r, 'uj_per_inference', '.4f')} |"
         )

@@ -14,8 +14,9 @@ Three planes, one bundle:
   log-linear ``Histogram``\\ s (p50..p999 with bounded relative error)
   and exact-while-short ``Reservoir``\\ s, with strict-JSON snapshots.
 * ``Tracer`` (trace.py) — lightweight spans over the hop pipeline,
-  exported as Chrome trace-event JSON (open in Perfetto), with an
-  opt-in ``jax.profiler`` bridge for kernel-level drill-down.
+  exported as Chrome trace-event JSON (open in Perfetto), and written
+  into the JAX profile as ``repro.<span>`` annotations while one is
+  being captured, on the device trace's clock.
 * ``EventLog`` (events.py) — JSONL lifecycle records (join / close /
   resize / rebalance / detection / mass-join) with monotonic
   timestamps, mirrored into ``utils.logging`` behind a per-kind rate
@@ -59,7 +60,6 @@ class Observability:
     @classmethod
     def create(cls, *, enabled: bool = True, trace_capacity: int = 65536,
                event_path=None, event_capacity: int = 4096,
-               jax_profiler: bool = False,
                mirror_events: bool = True) -> "Observability":
         """Build a bundle; ``enabled=False`` keeps the registry (metrics
         stay cheap and bounded) but turns spans into no-ops and stops
@@ -67,8 +67,7 @@ class Observability:
         against."""
         return cls(
             registry=MetricsRegistry(),
-            trace=Tracer(capacity=trace_capacity, enabled=enabled,
-                         jax_profiler=jax_profiler),
+            trace=Tracer(capacity=trace_capacity, enabled=enabled),
             events=EventLog(path=event_path, capacity=event_capacity,
                             mirror=enabled and mirror_events),
         )

@@ -1,30 +1,39 @@
-"""Per-hop trace spans with Chrome trace-event export.
+"""Per-hop trace spans: one bounded ring, bridged into the JAX profile.
 
-The streaming hop is a pipeline (pack -> dispatch -> device -> detector)
-and the ROADMAP's async-overlap work will be judged by *where inside the
-hop* the time goes, not by one aggregate number.  ``Tracer`` records
-lightweight spans into a bounded ring (O(1) memory over unbounded
-uptime, same discipline as the metrics registry) and exports them as
-Chrome trace-event JSON — load the file at ``ui.perfetto.dev`` (or
-``chrome://tracing``) to see every hop's phase breakdown on a timeline.
+The streaming hop is a pipeline (pack -> dispatch -> fence -> fetch ->
+detector -> push_fold) and the ROADMAP's async-overlap work will be
+judged by *where inside the hop* the time goes, not by one aggregate
+number.  ``Tracer`` records lightweight spans into a bounded ring (O(1)
+memory over unbounded uptime, same discipline as the metrics registry)
+and exports them as Chrome trace-event JSON — load the file at
+``ui.perfetto.dev`` (or ``chrome://tracing``) to see every hop's phase
+breakdown on a timeline.
 
-Two recording APIs:
+While a JAX profile is being captured (``jax.profiler.start_trace``),
+every span the ring records is also written into the profile as a
+``jax.profiler.TraceAnnotation`` named ``<process_name>.<span name>``
+(``repro.hop``, ``repro.ingest``, ...), opened and closed live at the
+span's own boundaries, so the program's spans share the device trace's
+clock and can name the device's idle gaps.  The gate is
+``TraceAnnotation.is_enabled()``, checked where a span opens: with no
+profile running a span costs that one check and builds no annotation.
 
-* ``with tracer.span("pack"):`` — the general context-manager form
-  (lifecycle work: resize, rebalance, prime_batch, LM prefill).
-* ``tracer.add("pack", t0, dur)`` — raw form for the hop hot path,
-  where the caller already holds ``time.perf_counter()`` stamps for the
-  metrics phases and a second clock read per phase would be waste.
+Recording APIs:
 
-Timestamps are monotonic (``perf_counter``) relative to the tracer's
-epoch, exported in microseconds as the trace-event spec requires.
-Consecutive phases share boundary stamps, so the exported spans tile
-their parent ``hop`` span exactly (the bench asserts >= 95% coverage).
+* ``with tracer.span("resize"):`` — the context-manager form (lifecycle
+  work: ingest, resize, rebalance, prime_batch, prewarm, LM prefill).
+  The body may add args to the dict it yields.
+* ``tracer.add`` / ``tracer.add_batch`` — record completed spans into
+  the ring from stamps the caller already holds.  They cannot reach a
+  profile after the fact, so a caller that records this way (the hop hot
+  path) brackets the same boundaries live with ``annotate`` and
+  ``handoff``.
 
-``jax_profiler=True`` additionally wraps each ``span`` in
-``jax.profiler.TraceAnnotation`` so the phase names show up inside a
-captured XLA device profile for kernel-level drill-down — opt-in, since
-it costs a TraceMe even when no profile is being captured.
+Timestamps are monotonic (``perf_counter``, or the clock the caller
+passes) relative to the tracer's epoch, exported in microseconds as the
+trace-event spec requires.  Consecutive phases share boundary stamps, so
+the exported spans tile their parent ``hop`` span exactly (the tests
+assert >= 95% coverage).
 """
 from __future__ import annotations
 
@@ -34,23 +43,20 @@ import json
 import threading
 import time
 
+from jax.profiler import TraceAnnotation as _Annotation
+
 
 class Tracer:
     """Bounded span recorder; disabled mode is a near-free no-op."""
 
     def __init__(self, capacity: int = 65536, enabled: bool = True,
-                 jax_profiler: bool = False,
                  process_name: str = "repro") -> None:
         self.enabled = enabled
         self.process_name = process_name
+        self._prefix = process_name + "."
         self._events: collections.deque = collections.deque(maxlen=capacity)
         self._epoch = time.perf_counter()
         self.dropped = 0  # spans evicted from the ring (uptime > capacity)
-        self._jax = None
-        if jax_profiler:
-            import jax.profiler  # deferred: opt-in only
-
-            self._jax = jax.profiler
 
     @property
     def capacity(self) -> int:
@@ -59,13 +65,30 @@ class Tracer:
     def __len__(self) -> int:
         return len(self._events)
 
+    # -- the profile bridge --------------------------------------------------
+
+    def annotate(self, name: str, **args):
+        """Open span ``name``'s annotation in the profile being captured
+        and return it; None (and no object built) when no profile is
+        being captured or the tracer is off."""
+        if not (self.enabled and _Annotation.is_enabled()):
+            return None
+        ann = _Annotation(self._prefix + name, **args)
+        ann.__enter__()
+        return ann
+
+    def handoff(self, ann, name: str, **args):
+        """One boundary between consecutive phases: close ``ann`` (None:
+        nothing open) and open ``name``'s annotation in its place."""
+        close(ann)
+        return self.annotate(name, **args)
+
     # -- recording -----------------------------------------------------------
 
     def add(self, name: str, t0: float, dur_s: float, **args) -> None:
-        """Record a completed span: ``t0`` is a ``time.perf_counter()``
+        """Record a completed span into the ring: ``t0`` is a clock
         stamp, ``dur_s`` its duration.  One deque append — cheap enough
-        for several calls per hop (the bench pins overhead <= 2% of hop
-        p50)."""
+        for several calls per hop."""
         if not self.enabled:
             return
         ev = self._events
@@ -74,12 +97,12 @@ class Tracer:
         ev.append((name, t0 - self._epoch, dur_s, threading.get_ident(), args))
 
     def add_batch(self, spans) -> None:
-        """Record several completed spans in one call.
+        """Record several completed spans into the ring in one call.
 
-        The hop hot path stamps every phase with consecutive
-        ``perf_counter`` reads and hands them all over at once — one
-        python call per hop instead of one per phase.  ``spans`` is an
-        iterable of ``(name, t0, dur_s, args_dict)`` tuples.
+        The hop hot path stamps every phase with consecutive clock reads
+        and hands them all over at once — one python call per hop instead
+        of one per phase.  ``spans`` is an iterable of ``(name, t0, dur_s,
+        args_dict)`` tuples.
         """
         if not self.enabled:
             return
@@ -93,28 +116,29 @@ class Tracer:
             ev.append((name, t0 - epoch, dur_s, tid, args))
 
     @contextlib.contextmanager
-    def span(self, name: str, **args):
-        """Context-managed span; body exceptions still close the span."""
+    def span(self, name: str, clock=time.perf_counter, **args):
+        """Context-managed span, stamped with ``clock``; yields its args
+        dict, which the body may extend.  Body exceptions still close the
+        span."""
         if not self.enabled:
-            yield
+            yield args
             return
-        if self._jax is not None:
-            with self._jax.TraceAnnotation(name):
-                t0 = time.perf_counter()
-                try:
-                    yield
-                finally:
-                    self.add(name, t0, time.perf_counter() - t0, **args)
-            return
-        t0 = time.perf_counter()
+        ann = self.annotate(name)
+        t0 = clock()
         try:
-            yield
+            yield args
         finally:
-            self.add(name, t0, time.perf_counter() - t0, **args)
+            t1 = clock()
+            if ann is not None:
+                if args:
+                    ann.set_metadata(**args)
+                close(ann)
+            self.add(name, t0, t1 - t0, **args)
 
-    def instant(self, name: str, **args) -> None:
-        """Zero-duration marker (joins, detections, ...)."""
-        self.add(name, time.perf_counter(), 0.0, **args)
+    def instant(self, name: str, clock=time.perf_counter, **args) -> None:
+        """Zero-duration marker (joins, detections, compiles, ...)."""
+        close(self.annotate(name, **args))
+        self.add(name, clock(), 0.0, **args)
 
     def reset(self) -> None:
         self._events.clear()
@@ -170,6 +194,12 @@ class Tracer:
         return len(out)
 
 
+def close(ann) -> None:
+    """Close an annotation from ``Tracer.annotate`` (None: nothing open)."""
+    if ann is not None:
+        ann.__exit__(None, None, None)
+
+
 def _dur(e: dict) -> float:
     return e["dur"] if "dur" in e else e["dur_s"]
 
@@ -220,8 +250,8 @@ def _intersect(xs: list[tuple[float, float]],
 
 
 def coverage(events: list[dict], parent: str = "hop",
-             phases: tuple[str, ...] = ("pack", "dispatch", "device",
-                                        "detector", "push_fold"),
+             phases: tuple[str, ...] = ("pack", "dispatch", "fence",
+                                        "fetch", "detector", "push_fold"),
              mode: str = "tile") -> float:
     """Fraction of ``parent`` span wall time covered by phase spans.
 
@@ -231,7 +261,7 @@ def coverage(events: list[dict], parent: str = "hop",
     anything under the 0.95 acceptance floor means a phase went missing
     from the instrumentation — but under the async plane it double
     counts, because hop N+1's pack/dispatch legitimately overlap hop N's
-    device span (the ratio can exceed 1.0).  ``mode="overlap"`` is the
+    fence span (the ratio can exceed 1.0).  ``mode="overlap"`` is the
     overlap-aware invariant: the measure of the *union* of phase
     intervals clipped to the union of parent intervals, over the parent
     union's measure — overlap never double counts and a missing phase
@@ -248,15 +278,17 @@ def coverage(events: list[dict], parent: str = "hop",
     return _intersect(phs, par) / tot if tot else 0.0
 
 
-def overlap_stats(events: list[dict], busy: tuple[str, ...] = ("device",),
+def overlap_stats(events: list[dict],
+                  busy: tuple[str, ...] = ("fence", "fetch"),
                   hidden_under: tuple[str, ...] = ("pack", "detector"),
                   ) -> dict[str, float]:
     """Union-interval account of how much host work hid under device
     compute — the async plane's acceptance measure.
 
-    ``busy`` spans (device execution, including queue wait at retire)
-    merge into one busy union; every ``hidden_under`` span's overlap
-    with that union counts as hidden.  Returns totals in the input's
+    ``busy`` spans (the wait on the device and the result copy,
+    including queue wait at retire) merge into one busy union; every
+    ``hidden_under`` span's overlap with that union counts as hidden.
+    Returns totals in the input's
     time unit (seconds for ``Tracer.spans()`` dicts, microseconds for
     exported Chrome events) plus the unit-free ``hidden_frac`` and
     ``utilization`` (busy fraction of the overall span extent).

@@ -42,10 +42,8 @@ overlap, since one hop's compute already hides the next hop's host work.
 """
 from __future__ import annotations
 
-import dataclasses
 import threading
 
-import jax
 import numpy as np
 
 # the double-buffered in-flight queue, epoch-barrier protocol, and the
@@ -53,24 +51,14 @@ import numpy as np
 # IngestPump is re-exported because this module is its historical home
 from repro.runtime.async_plane import InFlightQueue, IngestPump
 from repro.stream.detector import Detection
-from repro.stream.scheduler import HopBatch, StreamResult, StreamScheduler
+from repro.stream.scheduler import (
+    HopBatch,
+    StreamResult,
+    StreamScheduler,
+    _Hop,
+)
 
 __all__ = ["AsyncStreamScheduler", "IngestPump"]
-
-
-@dataclasses.dataclass
-class _InFlight:
-    """One dispatched-but-unretired hop: the host-side inputs its
-    deferred fold needs, plus the device-result futures to fence on."""
-
-    ready_slots: np.ndarray
-    shard_counts: np.ndarray
-    logits: object | None     # device future ((capacity, classes))
-    post: object | None
-    t0: float
-    t_pack: float
-    t_dispatch: float
-    hidden_s: float           # pack+dispatch wall already under device
 
 
 class AsyncStreamScheduler(StreamScheduler):
@@ -146,29 +134,21 @@ class AsyncStreamScheduler(StreamScheduler):
         """Dispatched hops whose fold has not retired yet."""
         return len(self._inflight)
 
-    def _retire_inflight(self, f: _InFlight, still_in_flight: bool
+    def _retire_inflight(self, h: _Hop, still_in_flight: bool
                          ) -> HopBatch:
-        """Retire function the ``InFlightQueue`` drives: fence on one hop
-        and run its deferred fold.  The fence blocks OUTSIDE the ingest
-        lock so pushes keep landing while the device finishes; the fold
-        itself (detector, metrics, events, emit cache) runs under the
-        lock, in FIFO dispatch order."""
-        if f.logits is not None:
-            jax.block_until_ready(f.logits)
-            logits_h = np.asarray(f.logits)  # one bulk transfer per hop
-            post_h = np.asarray(f.post)
-        else:
-            # emit off: no per-hop output future survives donation, so
-            # fence the resident state (syncs every queued hop <= now)
-            jax.block_until_ready((self._tails, self._pendings, self._gap))
-            logits_h = post_h = None
-        t_device = self._clock()
+        """Retire function the ``InFlightQueue`` drives: fence on one hop,
+        fetch its results and run its deferred fold.  The fence blocks
+        OUTSIDE the ingest lock so pushes keep landing while the device
+        finishes; the fold itself (detector, metrics, events, emit cache)
+        runs under the lock, in FIFO dispatch order."""
+        # emit off: no per-hop output future survives donation, so fence
+        # the resident state (syncs every queued hop <= now)
+        fence_on = h.logits if h.logits is not None else (
+            self._tails, self._pendings, self._gap)
+        logits_h, post_h = self._fence_fetch(h, fence_on)
         with self._lock:
-            return self._fold_hop(
-                f.ready_slots, f.shard_counts, logits_h, post_h,
-                f.t0, f.t_pack, f.t_dispatch, t_device,
-                hidden_s=f.hidden_s, fold_hidden=still_in_flight,
-            )
+            return self._fold_hop(h, logits_h, post_h,
+                                  fold_hidden=still_in_flight)
 
     def _retire_one(self) -> HopBatch:
         """Fence on the oldest in-flight hop and run its deferred fold."""
@@ -201,19 +181,14 @@ class AsyncStreamScheduler(StreamScheduler):
                 self._hop_barriers()
             packed = self._pack_ready()
             if packed is not None:
-                (ready_slots, ready_mask, audio, shard_counts,
-                 t0, t_pack) = packed
+                h, ready_mask, audio = packed
                 was_busy = bool(self._inflight)
-                logits, post = self._dispatch_hop(ready_mask, audio)
-                t_dispatch = self._clock()
-                self._inflight.push(_InFlight(
-                    ready_slots=ready_slots, shard_counts=shard_counts,
-                    logits=logits, post=post,
-                    t0=t0, t_pack=t_pack, t_dispatch=t_dispatch,
+                self._dispatch_hop(h, ready_mask, audio)
+                if was_busy:
                     # this hop's pack+dispatch ran while an earlier hop
                     # was executing: that host wall is hidden
-                    hidden_s=(t_dispatch - t0) if was_busy else 0.0,
-                ))
+                    h.hidden_s = h.t_dispatch - h.t0
+                self._inflight.push(h)
                 self._dispatched_total += 1
             else:
                 self._maybe_prewarm()  # starved turn: warm next capacity

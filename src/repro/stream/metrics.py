@@ -14,13 +14,12 @@ Step timing covers the whole per-hop pipeline *including* per-slot
 finalized logits: finalization runs inside the jitted step (the fused
 tail), so there is no separate host-side peek bucket to account for — the
 step latency percentile IS the hop-to-logits latency.  Each step records
-the split between *host packing* (building the batched audio/mask from
-the shared ``RingArena`` — the part the vectorized ingest plane exists to
-shrink) and everything else (device step + transfers + batched detector),
-so a regression in either half is visible on its own
-(``host_pack_ms_p50`` / ``device_ms_p50`` in ``summary``), plus the
-finer per-phase split (pack / dispatch / device / detector) the
-scheduler's fenced trace spans measure.
+the per-phase split the scheduler's trace spans measure (pack / dispatch
+/ fence / fetch / detector; ``phase_summary``), with host packing —
+building the batched audio/mask from the shared ``RingArena``, the part
+the vectorized ingest plane exists to shrink — also in ``summary`` as
+``host_pack_ms_*``.  Device time itself is the profiler's: the fence is
+the host's wait for the device, not the device's work.
 
 **Bounded over unbounded uptime.**  Nothing here grows with step count
 or stream count: latencies land in fixed-size ring ``Reservoir``\\ s
@@ -166,9 +165,9 @@ class StreamCounters:
 
 # the fenced per-phase split of one hop (scheduler.step_batch's span
 # stamps): host pack, dispatch (staging + jitted call returning its
-# futures), device (block_until_ready fence + result transfers), and the
-# batched detector + bookkeeping
-PHASES = ("pack", "dispatch", "device", "detector")
+# futures), fence (block_until_ready on the hop's results), fetch (their
+# device-to-host copy), and the batched detector + bookkeeping
+PHASES = ("pack", "dispatch", "fence", "fetch", "detector")
 
 
 class StreamMetrics:
@@ -209,10 +208,8 @@ class StreamMetrics:
         # latency series: exact ring reservoirs + all-sample histograms
         self._wall_res = Reservoir(reservoir)
         self._pack_res = Reservoir(reservoir)
-        self._dev_res = Reservoir(reservoir)   # wall - pack (legacy split)
         self._wall_hist = self._hist("stream.step_wall_s")
         self._pack_hist = self._hist("stream.step_pack_s")
-        self._dev_hist = self._hist("stream.step_device_s")
         # the fenced per-phase split (pack shares the series above)
         self._phase_res = {p: Reservoir(reservoir) for p in PHASES[1:]}
         self._phase_hist = {p: self._hist(f"stream.phase_{p}_s")
@@ -293,15 +290,17 @@ class StreamMetrics:
                 host_pack_s: float = 0.0,
                 shard_counts: list[int] | None = None,
                 finalized: bool = True,
-                dispatch_s: float = 0.0, device_s: float = 0.0,
-                detector_s: float = 0.0, hidden_s: float = 0.0,
+                dispatch_s: float = 0.0, fence_s: float = 0.0,
+                fetch_s: float = 0.0, detector_s: float = 0.0,
+                hidden_s: float = 0.0,
                 dispatches: int = 0,
                 model_counts: dict[str, int] | None = None) -> None:
         """Record one batched hop: ``n_ready`` streams advanced in
         ``wall_s`` seconds of which ``host_pack_s`` was host-side batch
-        packing; ``dispatch_s``/``device_s``/``detector_s`` are the
-        fenced phase durations from the scheduler's trace spans (device
-        time is real execution — the span boundary blocks until ready).
+        packing; ``dispatch_s``/``fence_s``/``fetch_s``/``detector_s``
+        are the phase durations from the scheduler's trace spans (the
+        fence blocks until the hop's results are ready, the fetch copies
+        them to the host).
         ``hidden_s`` is the portion of this hop's host work (pack /
         dispatch / deferred fold) that ran while an earlier or later hop
         was executing on the device — zero on the synchronous path,
@@ -321,11 +320,10 @@ class StreamMetrics:
         assert len(shard_counts) == self.n_shards, (shard_counts, self.n_shards)
         self._rec(self._wall_res, self._wall_hist, wall_s)
         self._rec(self._pack_res, self._pack_hist, host_pack_s)
-        self._rec(self._dev_res, self._dev_hist, wall_s - host_pack_s)
         pt = self._phase_total
         pt["pack"] += host_pack_s
-        for p, v in (("dispatch", dispatch_s), ("device", device_s),
-                     ("detector", detector_s)):
+        for p, v in (("dispatch", dispatch_s), ("fence", fence_s),
+                     ("fetch", fetch_s), ("detector", detector_s)):
             self._rec(self._phase_res[p], self._phase_hist[p], v)
             pt[p] += v
         self.hidden_total_s += hidden_s
@@ -406,10 +404,10 @@ class StreamMetrics:
         and the step/throughput aggregates (NOT lifecycle counters or the
         energy ledger, which stay cumulative).  Benches call this after
         warm-up so ``summary()`` reports steady-state quantiles."""
-        for r in (self._wall_res, self._pack_res, self._dev_res,
+        for r in (self._wall_res, self._pack_res,
                   *self._phase_res.values()):
             r.reset()
-        for h in (self._wall_hist, self._pack_hist, self._dev_hist,
+        for h in (self._wall_hist, self._pack_hist,
                   *self._phase_hist.values()):
             h.reset()
         self._phase_total = dict.fromkeys(PHASES, 0.0)
@@ -464,14 +462,10 @@ class StreamMetrics:
             "step_ms_p95": self._q(self._wall_res, self._wall_hist, 95),
             "step_ms_p99": self._q(self._wall_res, self._wall_hist, 99),
             "step_ms_p999": self._q(self._wall_res, self._wall_hist, 99.9),
-            # the hop's host/device split: pack = building the batched
-            # audio+mask from the arena; device = step + transfers +
-            # batched detector.  Regressions in either half show alone.
+            # building the batched audio+mask from the arena; the other
+            # phases are in phase_summary()
             "host_pack_ms_p50": self._q(self._pack_res, self._pack_hist, 50),
             "host_pack_ms_p95": self._q(self._pack_res, self._pack_hist, 95),
-            "device_ms_p50": self._q(self._dev_res, self._dev_hist, 50),
-            "device_ms_p95": self._q(self._dev_res, self._dev_hist, 95),
-            "device_ms_p99": self._q(self._dev_res, self._dev_hist, 99),
             "latency_estimated": float(self.latency_estimated),
             "mean_batch_occupancy": self.stream_hops_total / self.steps
             if self.steps else 0.0,
@@ -502,7 +496,8 @@ class StreamMetrics:
         }
 
     def phase_summary(self) -> dict[str, dict[str, float]]:
-        """Per-phase hop breakdown (pack / dispatch / device / detector):
+        """Per-phase hop breakdown (pack / dispatch / fence / fetch /
+        detector):
         quantiles in ms plus each phase's share of total hop wall time.
         The fenced spans tile the hop, so shares sum to ~1 when the
         scheduler recorded all phases (0 for phases never recorded)."""
@@ -538,7 +533,8 @@ class StreamMetrics:
             "hidden_ms": self.hidden_total_s * 1e3,
             "host_ms": host * 1e3,
             "hidden_frac": self.hidden_total_s / host if host else 0.0,
-            "device_busy_ms": pt["device"] * 1e3,
+            "fence_ms": pt["fence"] * 1e3,
+            "fetch_ms": pt["fetch"] * 1e3,
         }
 
     def shard_summary(self) -> dict[str, object]:
@@ -573,10 +569,8 @@ class StreamMetrics:
         plus an entry-count charge for the dict/deque containers.  The
         constant-memory-over-10k-steps test pins this value flat."""
         n = sum(r.nbytes for r in (self._wall_res, self._pack_res,
-                                   self._dev_res,
                                    *self._phase_res.values()))
         n += sum(h.nbytes for h in (self._wall_hist, self._pack_hist,
-                                    self._dev_hist,
                                     *self._phase_hist.values()))
         n += self._shard_hops.nbytes
         n += 64 * (len(self.streams) + len(self.retired)

@@ -72,6 +72,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import time
 
 import jax
@@ -83,6 +84,7 @@ from repro.core.cnn_spec import CNN1DSpec
 from repro.kernels import ops
 from repro.launch.mesh import dp_axes, dp_size
 from repro.obs import Observability
+from repro.obs.trace import close as close_annotation
 from repro.stream.detector import (
     BatchedDetector,
     Detection,
@@ -326,6 +328,30 @@ class _Stream:
     model: str = DEFAULT_MODEL  # tenant variant this stream computes with
 
 
+@dataclasses.dataclass(eq=False)
+class _Hop:
+    """One hop from pack to fold: the rows it advances, its device result
+    futures, the ring's phase stamps and, while a profile is being
+    captured, its open profile annotations.  The sync path carries it
+    through one ``step_batch``; the async plane queues it in flight
+    between dispatch and retirement."""
+
+    seq: int                     # joins the spans of one hop
+    ready_slots: np.ndarray
+    shard_counts: np.ndarray
+    t0: float
+    t_pack: float
+    hop_ann: object              # profile annotation of the hop, or None
+    ann: object                  # the open phase's annotation, or None
+    t_dispatch: float = 0.0
+    t_fence: float = 0.0
+    t_device: float = 0.0
+    logits: object = None        # device futures (None with emit off)
+    post: object = None
+    fetch_bytes: int = 0
+    hidden_s: float = 0.0        # host wall of this hop under device work
+
+
 def _mesh_data_axes(mesh):
     """The mesh's data-parallel axes as a PartitionSpec entry (a tuple of
     axis names is a valid single-dim entry)."""
@@ -402,8 +428,12 @@ class _BatchedModel:
         # input buffers, so a restep never copies the resident state.  The
         # caller must treat the passed-in state arrays as consumed (the
         # scheduler reassigns them from the step's results immediately).
+        # the device profile names the program after the function jitted
+        # (``jit_kws_hop_step`` in its XLA Modules line)
+        step = functools.partial(self._step)
+        step.__name__ = "kws_hop_step"
         self.step = jax.jit(
-            self._step, static_argnames=("emit",),
+            step, static_argnames=("emit",),
             donate_argnums=(2, 3, 4) if donate else (),
         )
         self.finalize = jax.jit(self._finalize)
@@ -585,29 +615,31 @@ class _BatchedModel:
         cur = audio.reshape(audio.shape[0], plan.hop_samples, stages[0].cin)
         new_tails, new_pendings = [], []
         for i, st in enumerate(stages):
-            window = jnp.concatenate([tails[i], cur], axis=1)
-            raw = self._conv_raw(i, window, st.n_conv, model_idx)
-            new_tails.append(window[:, st.n_conv * st.stride :])
-            y = self._sa(i, raw, model_idx)
-            if st.pool > 1:
-                frames = (
-                    jnp.concatenate([pendings[i], y], axis=1)
-                    if st.phase else y
-                )
-                used = st.n_out * st.pool
-                pooled = frames[:, :used].reshape(
-                    frames.shape[0], st.n_out, st.pool, st.cout
-                ).max(axis=2)
-                new_pendings.append(frames[:, used:])
-                cur = pooled
-            else:
-                new_pendings.append(pendings[i])
-                cur = y
+            with jax.named_scope(f"conv{i}"):
+                window = jnp.concatenate([tails[i], cur], axis=1)
+                raw = self._conv_raw(i, window, st.n_conv, model_idx)
+                new_tails.append(window[:, st.n_conv * st.stride :])
+                y = self._sa(i, raw, model_idx)
+                if st.pool > 1:
+                    frames = (
+                        jnp.concatenate([pendings[i], y], axis=1)
+                        if st.phase else y
+                    )
+                    used = st.n_out * st.pool
+                    pooled = frames[:, :used].reshape(
+                        frames.shape[0], st.n_out, st.pool, st.cout
+                    ).max(axis=2)
+                    new_pendings.append(frames[:, used:])
+                    cur = pooled
+                else:
+                    new_pendings.append(pendings[i])
+                    cur = y
         # saturate at the 8-bit PWB counter ceiling inside the step: the
         # accumulation is monotone non-negative, so incremental clamping
         # equals clamping the int64 total (pwb.gap_counts semantics) and
         # int32 can never wrap on always-on streams
-        gap2 = jnp.minimum(gap + cur.sum(axis=1, dtype=jnp.int32), 255)
+        with jax.named_scope("gap"):
+            gap2 = jnp.minimum(gap + cur.sum(axis=1, dtype=jnp.int32), 255)
 
         m3 = mask[:, None, None]
         new_tails = [
@@ -654,31 +686,33 @@ class _BatchedModel:
         stages = self.plan.convs
         B = gap.shape[0]
         cur = None  # frames flowing down from the layer above's flush
-        for i, st in enumerate(stages):
-            pieces = [tails[i]]
-            if cur is not None and st.flush_in:
-                pieces.append(cur)
-            if st.pad:
-                pad_val = st.in_offset if st.in_bits > 1 else 0
-                pieces.append(
-                    self._pin(
-                        jnp.full((B, st.pad, st.cin), pad_val, jnp.int32)
+        with jax.named_scope("ghost_flush"):
+            for i, st in enumerate(stages):
+                pieces = [tails[i]]
+                if cur is not None and st.flush_in:
+                    pieces.append(cur)
+                if st.pad:
+                    pad_val = st.in_offset if st.in_bits > 1 else 0
+                    pieces.append(
+                        self._pin(
+                            jnp.full((B, st.pad, st.cin), pad_val, jnp.int32)
+                        )
                     )
-                )
-            if st.flush_conv > 0:
-                window = jnp.concatenate(pieces, axis=1)
-                y = self._sa(i, self._conv_raw(i, window, st.flush_conv,
-                                               model_idx), model_idx)
-            else:
-                y = jnp.zeros((B, 0, st.cout), jnp.int32)
-            frames = jnp.concatenate([pendings[i], y], axis=1)
-            used = st.flush_out * st.pool  # drop-remainder (ref_maxpool1d)
-            cur = frames[:, :used].reshape(
-                B, st.flush_out, st.pool, st.cout
-            ).max(axis=2)
-        gap_f = jnp.minimum(gap + cur.sum(axis=1, dtype=jnp.int32), 255)
-        logits = self._pin(self._classifier(gap_f, model_idx))
-        post = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+                if st.flush_conv > 0:
+                    window = jnp.concatenate(pieces, axis=1)
+                    y = self._sa(i, self._conv_raw(i, window, st.flush_conv,
+                                                   model_idx), model_idx)
+                else:
+                    y = jnp.zeros((B, 0, st.cout), jnp.int32)
+                frames = jnp.concatenate([pendings[i], y], axis=1)
+                used = st.flush_out * st.pool  # drop-remainder (ref_maxpool1d)
+                cur = frames[:, :used].reshape(
+                    B, st.flush_out, st.pool, st.cout
+                ).max(axis=2)
+            gap_f = jnp.minimum(gap + cur.sum(axis=1, dtype=jnp.int32), 255)
+        with jax.named_scope("classifier"):
+            logits = self._pin(self._classifier(gap_f, model_idx))
+            post = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
         return logits, post
 
     def _classifier(self, gap_f: jax.Array,
@@ -887,6 +921,7 @@ class StreamScheduler:
         # state), so the row stays valid until the slot is rewritten on
         # the host (priming) or remapped (resize)
         self._emit_step = 0
+        self._hop_seq = 0  # the last hop packed; joins its phase spans
         self._emit_cache: np.ndarray | None = None
         self._emit_cache_step = -1
         # idle-time jit pre-warm of the next pow-2 capacity (satellite of
@@ -1119,19 +1154,32 @@ class StreamScheduler:
         exact math the arena would apply — so the single scatter stays
         bit-identical to sequential pushes).  Per-stream ``samples_in``
         counters are NOT walked here — the arena's vectorized counter is
-        the truth and folds into the stream's metrics at close."""
-        streams = [self._require(sid) for sid in sids]
-        slots = np.fromiter((s.slot for s in streams), np.int64, len(streams))
-        if np.unique(slots).size != slots.size:
-            slots, chunks, extra = self._coalesce_chunks(slots, chunks)
-        else:
-            extra = None
-        self._arena.push_batch(slots, chunks)
-        if extra is not None:
-            # credit the chunks the coalesce merged away (push_batch
-            # counted one per slot) so chunks_in stays arrival-accurate
-            self._arena.chunks_in[slots] += extra
-            self._arena.total_chunks_in += int(extra.sum())
+        the truth and folds into the stream's metrics at close.
+
+        The whole call is one ``ingest`` span (args ``chunks``,
+        ``samples``, ``coalesced``: chunks merged into an earlier one of
+        the same stream)."""
+        arena = self._arena
+        with self.obs.trace.span("ingest", clock=self._clock,
+                                 chunks=len(sids)) as args:
+            before = arena.total_samples_in
+            streams = [self._require(sid) for sid in sids]
+            slots = np.fromiter((s.slot for s in streams), np.int64,
+                                len(streams))
+            if np.unique(slots).size != slots.size:
+                slots, chunks, extra = self._coalesce_chunks(slots, chunks)
+            else:
+                extra = None
+            arena.push_batch(slots, chunks)
+            coalesced = 0
+            if extra is not None:
+                # credit the chunks the coalesce merged away (push_batch
+                # counted one per slot) so chunks_in stays arrival-accurate
+                coalesced = int(extra.sum())
+                arena.chunks_in[slots] += extra
+                arena.total_chunks_in += coalesced
+            args["samples"] = arena.total_samples_in - before
+            args["coalesced"] = coalesced
 
     def _coalesce_chunks(self, slots: np.ndarray, chunks: list[np.ndarray]
                          ) -> tuple[np.ndarray, list[np.ndarray], np.ndarray]:
@@ -1183,53 +1231,52 @@ class StreamScheduler:
         ready = (self._arena.wr[slots] - self._arena.rd[slots]) >= prime
         if not ready.any():
             return
-        t0 = self._clock()
         sids = [sid for sid, r in zip(sids, ready.tolist()) if r]
-        slots = slots[ready]
-        samples = self._arena.pop_batch(slots, prime)
-        # priming consumed a non-hop-multiple; realign the inboxes so
-        # every future hop window is one contiguous block
-        self._arena.rebase_batch(slots)
-        # one vectorized warm-up per tenant model (a single group without
-        # a pool): each group's rows land via the same batched scatters
-        if self._pool is None:
-            groups = [(self.weights, self.thresholds,
-                       np.arange(len(sids), dtype=np.int64))]
-        else:
-            by_model: dict[str, list[int]] = {}
-            for j, sid in enumerate(sids):
-                by_model.setdefault(self._streams[sid].model, []).append(j)
-            groups = [
-                (*self._stream_params(self._streams[sids[pos[0]]]),
-                 np.asarray(pos, np.int64))
-                for pos in by_model.values()
-            ]
-        for w, t, pos in groups:
-            steady = prime_batch(self.plan, w, t, samples[pos])
-            gslots = slots[pos]
-            jslots = jnp.asarray(gslots)
-            for i in range(len(self.plan.convs)):
-                self._tails[i] = self._tails[i].at[jslots].set(
-                    jnp.asarray(steady["tails"][i])
-                )
-                if self._pendings[i].shape[1]:
-                    self._pendings[i] = self._pendings[i].at[jslots].set(
-                        jnp.asarray(steady["pendings"][i])
+        with self.obs.trace.span("prime_batch", clock=self._clock,
+                                 n=len(sids)):
+            slots = slots[ready]
+            samples = self._arena.pop_batch(slots, prime)
+            # priming consumed a non-hop-multiple; realign the inboxes so
+            # every future hop window is one contiguous block
+            self._arena.rebase_batch(slots)
+            # one vectorized warm-up per tenant model (a single group without
+            # a pool): each group's rows land via the same batched scatters
+            if self._pool is None:
+                groups = [(self.weights, self.thresholds,
+                           np.arange(len(sids), dtype=np.int64))]
+            else:
+                by_model: dict[str, list[int]] = {}
+                for j, sid in enumerate(sids):
+                    by_model.setdefault(self._streams[sid].model, []).append(j)
+                groups = [
+                    (*self._stream_params(self._streams[sids[pos[0]]]),
+                     np.asarray(pos, np.int64))
+                    for pos in by_model.values()
+                ]
+            for w, t, pos in groups:
+                steady = prime_batch(self.plan, w, t, samples[pos])
+                gslots = slots[pos]
+                jslots = jnp.asarray(gslots)
+                for i in range(len(self.plan.convs)):
+                    self._tails[i] = self._tails[i].at[jslots].set(
+                        jnp.asarray(steady["tails"][i])
                     )
-            self._gap = self._gap.at[jslots].set(
-                jnp.asarray(steady["gap"].astype(np.int32))
-            )
-            self._frames_v[gslots] = steady["frames"]
-        self._primed_mask[slots] = True
-        for sid in sids:
-            s = self._streams[sid]
-            s.primed = True
-            self._unprimed.discard(sid)
-            # host wrote the slot: earlier cached logits don't cover it;
-            # the NEXT emit step (which includes this write) does
-            s.stamp = self._emit_step + 1
-        self.obs.trace.add("prime_batch", t0, self._clock() - t0,
-                           n=len(sids))
+                    if self._pendings[i].shape[1]:
+                        self._pendings[i] = self._pendings[i].at[jslots].set(
+                            jnp.asarray(steady["pendings"][i])
+                        )
+                self._gap = self._gap.at[jslots].set(
+                    jnp.asarray(steady["gap"].astype(np.int32))
+                )
+                self._frames_v[gslots] = steady["frames"]
+            self._primed_mask[slots] = True
+            for sid in sids:
+                s = self._streams[sid]
+                s.primed = True
+                self._unprimed.discard(sid)
+                # host wrote the slot: earlier cached logits don't cover it;
+                # the NEXT emit step (which includes this write) does
+                s.stamp = self._emit_step + 1
         self.obs.events.emit("mass_join", n=len(sids))
 
     def _clear_slot(self, slot: int) -> None:
@@ -1276,14 +1323,23 @@ class StreamScheduler:
 
     def _pack_ready(self):
         """Pack stage: consume one hop window from every ready slot.
-        Returns ``None`` when no stream is ready, else ``(ready_slots,
-        ready_mask, audio, shard_counts, t0, t_pack)``."""
+        Returns ``None`` when no stream is ready, else ``(hop, ready_mask,
+        audio)``.
+
+        The ring's ``pack`` and ``hop`` spans start at ``t0``, before the
+        readiness compare; their profile annotations open once the hop
+        has ready streams, so a starved turn leaves none in the profile."""
         hop = self.plan.hop_samples
         t0 = self._clock()
         ready_mask = self._primed_mask & self._arena.ready_mask(hop)
         ready_slots = np.nonzero(ready_mask)[0]
         if ready_slots.size == 0:
             return None
+        self._hop_seq += 1
+        seq = self._hop_seq
+        tr = self.obs.trace
+        hop_ann = tr.annotate("hop", hop=seq)
+        ann = None if hop_ann is None else tr.annotate("pack", hop=seq)
         audio = self._arena.pack_hops(ready_slots, hop)
         shard_counts = np.bincount(
             ready_slots // self._placement.shard_capacity,
@@ -1292,15 +1348,19 @@ class StreamScheduler:
         # pack phase ends here; staging (jnp.asarray/device_put) and the
         # jitted call itself are the dispatch phase
         t_pack = self._clock()
-        return ready_slots, ready_mask, audio, shard_counts, t0, t_pack
+        ann = tr.handoff(ann, "dispatch", hop=seq)
+        h = _Hop(seq=seq, ready_slots=ready_slots, shard_counts=shard_counts,
+                 t0=t0, t_pack=t_pack, hop_ann=hop_ann, ann=ann)
+        return h, ready_mask, audio
 
-    def _dispatch_hop(self, ready_mask, audio):
+    def _dispatch_hop(self, h: _Hop, ready_mask, audio) -> None:
         """Dispatch stage: stage operands, launch the jitted hop, and
         reassign the resident state from its (still unforced) result
         futures.  Nothing here blocks — JAX's async dispatch returns
         immediately — and with donated buffers the previous state arrays
         are consumed by the call, so they must not be read afterwards.
-        Returns the logits/posterior futures (None with emit off)."""
+        Leaves the logits/posterior futures (None with emit off) in ``h``
+        and opens its ``fence`` phase."""
         args = (
             self._shard(jnp.asarray(audio)),
             self._shard(jnp.asarray(ready_mask)),
@@ -1316,31 +1376,54 @@ class StreamScheduler:
             args = args + (self._model_idx_dev,)
         n_entries = self._jit_entries()
         if self.emit_logits:
-            tails, pendings, gap, logits, post = self._model.step(
+            tails, pendings, gap, h.logits, h.post = self._model.step(
                 *args, emit=True
             )
         else:
             tails, pendings, gap = self._model.step(*args, emit=False)
-            logits = post = None
         if self._jit_entries() != n_entries:
             # this hop traced a new (capacity, emit) shape — the compile
             # spike idle pre-warming exists to hide (the multi-tenant
             # suite pins the post-grow hop clean when prewarm=True)
-            self.obs.trace.add("compile", self._clock(), 0.0,
-                               capacity=self._capacity)
+            self.obs.trace.instant("compile", clock=self._clock,
+                                   capacity=self._capacity)
         self._tails = list(tails)
         self._pendings = list(pendings)
         self._gap = gap
-        return logits, post
+        # dispatch phase ends when the jitted call has returned its
+        # futures; the fence (and, under the async plane, the hop's wait
+        # in the pipeline) runs from here to the results being ready
+        h.t_dispatch = self._clock()
+        h.ann = self.obs.trace.handoff(h.ann, "fence", hop=h.seq)
 
     def _jit_entries(self) -> int:
         """Jit-cache entry count of the batched step."""
         return self._model.step._cache_size()
 
-    def _fold_hop(self, ready_slots, shard_counts, logits_h, post_h,
-                  t0, t_pack, t_dispatch, t_device,
-                  hidden_s: float = 0.0, fold_hidden: bool = False
-                  ) -> HopBatch:
+    def _fence_fetch(self, h: _Hop, fence_on):
+        """Fence and fetch stages: block until ``fence_on`` is ready, then
+        copy the hop's logits and posteriors to the host (one bulk
+        transfer each).  Returns them (None with emit off).
+
+        Without the fence, JAX's async dispatch would let wall time
+        measure *enqueue* rather than execution (egregiously so with
+        emit_logits off, where nothing else forces a sync)."""
+        tr = self.obs.trace
+        jax.block_until_ready(fence_on)
+        h.t_fence = self._clock()
+        h.ann = tr.handoff(h.ann, "fetch", hop=h.seq)
+        logits_h = post_h = None
+        if h.logits is not None:
+            logits_h = np.asarray(h.logits)
+            post_h = np.asarray(h.post)
+            h.fetch_bytes = logits_h.nbytes + post_h.nbytes
+            h.logits = h.post = None
+        h.t_device = self._clock()
+        h.ann = tr.handoff(h.ann, "detector", hop=h.seq)
+        return logits_h, post_h
+
+    def _fold_hop(self, h: _Hop, logits_h, post_h,
+                  fold_hidden: bool = False) -> HopBatch:
         """Fold stage: apply one resolved hop's results to the host-side
         planes — emit cache, frame counters, slot-vectorized detector,
         metrics, lifecycle events, trace spans.  The sync path runs it
@@ -1348,6 +1431,7 @@ class StreamScheduler:
         hop's retirement, strictly in FIFO dispatch order, which keeps
         every per-slot sequence (frames, detector state, events)
         bit-identical to the synchronous schedule."""
+        ready_slots = h.ready_slots
         if self.emit_logits:
             self._emit_step += 1
             self._emit_cache = logits_h
@@ -1374,10 +1458,13 @@ class StreamScheduler:
                                      score=det.score)
                 detections.append(det)
         t_detector = self._clock()
+        tr = self.obs.trace
+        h.ann = tr.handoff(h.ann, "push_fold", hop=h.seq)
+        hidden_s = h.hidden_s
         if fold_hidden:
             # a later hop is still executing while this fold runs, so the
             # detector phase is hidden under device compute
-            hidden_s += t_detector - t_device
+            hidden_s += t_detector - h.t_device
         n_disp = self._model.dispatches_per_hop(self.emit_logits)
         model_counts = None
         if self._pool is not None:
@@ -1387,13 +1474,16 @@ class StreamScheduler:
                 m: int(mc[row]) for m, row in self._pool.models()
                 if mc[row]
             }
+        t0, t_pack, t_dispatch = h.t0, h.t_pack, h.t_dispatch
+        t_fence, t_device = h.t_fence, h.t_device
         self.metrics.on_step(
             ready_slots.size, self.plan.frames_per_hop,
             t_detector - t0, host_pack_s=t_pack - t0,
-            shard_counts=shard_counts.tolist(), finalized=self.emit_logits,
-            dispatch_s=t_dispatch - t_pack, device_s=t_device - t_dispatch,
-            detector_s=t_detector - t_device, hidden_s=hidden_s,
-            dispatches=n_disp, model_counts=model_counts,
+            shard_counts=h.shard_counts.tolist(),
+            finalized=self.emit_logits,
+            dispatch_s=t_dispatch - t_pack, fence_s=t_fence - t_dispatch,
+            fetch_s=t_device - t_fence, detector_s=t_detector - t_device,
+            hidden_s=hidden_s, dispatches=n_disp, model_counts=model_counts,
         )
         # fold the arena's push-side counters into the metrics at the hop
         # boundary: two scalar reads, so neither the push path nor this
@@ -1401,21 +1491,26 @@ class StreamScheduler:
         self.metrics.on_push_fold(self._arena.total_samples_in,
                                   self._arena.total_chunks_in)
         t_end = self._clock()
+        close_annotation(h.ann)
+        close_annotation(h.hop_ann)
         # hop trace: on the sync path the stamps are consecutive, so the
-        # phase spans tile the hop span exactly (the bench asserts >= 95%
+        # phase spans tile the hop span exactly (the tests assert >= 95%
         # coverage).  Under the async plane, hop N+1's pack/dispatch
-        # spans legitimately overlap hop N's device span — union-interval
+        # spans legitimately overlap hop N's fence span — union-interval
         # coverage (``trace.coverage(mode="overlap")``) accounts for
-        # that.  One batched call, six deque appends — B-independent.
+        # that.  One batched call, seven deque appends — B-independent.
         n_ready = int(ready_slots.size)
-        self.obs.trace.add_batch((
-            ("pack", t0, t_pack - t0, {"n": n_ready}),
-            ("dispatch", t_pack, t_dispatch - t_pack, {}),
-            ("device", t_dispatch, t_device - t_dispatch,
-             {"dispatches": n_disp}),
-            ("detector", t_device, t_detector - t_device, {}),
-            ("push_fold", t_detector, t_end - t_detector, {}),
-            ("hop", t0, t_end - t0, {"n": n_ready}),
+        seq = h.seq
+        tr.add_batch((
+            ("pack", t0, t_pack - t0, {"n": n_ready, "hop": seq}),
+            ("dispatch", t_pack, t_dispatch - t_pack,
+             {"dispatches": n_disp, "hop": seq}),
+            ("fence", t_dispatch, t_fence - t_dispatch, {"hop": seq}),
+            ("fetch", t_fence, t_device - t_fence,
+             {"bytes": h.fetch_bytes, "hop": seq}),
+            ("detector", t_device, t_detector - t_device, {"hop": seq}),
+            ("push_fold", t_detector, t_end - t_detector, {"hop": seq}),
+            ("hop", t0, t_end - t0, {"n": n_ready, "hop": seq}),
         ))
         return HopBatch(sids=sids, frames=frames, logits=rows_logits,
                         posteriors=rows_post, detections=detections)
@@ -1433,32 +1528,22 @@ class StreamScheduler:
         (priming, teardown, fallback peeks) and for detections that
         actually fire.
 
-        The body is pack -> dispatch -> fence -> fold, each stage a
-        method the async plane (``AsyncStreamScheduler``) reuses with the
-        fence+fold deferred to the hop's retirement.
+        The body is pack -> dispatch -> fence -> fetch -> fold, each
+        stage a method the async plane (``AsyncStreamScheduler``) reuses
+        with the fence, fetch and fold deferred to the hop's retirement.
+        Each stage is a trace span of the same name (the fold is
+        ``detector`` then ``push_fold``), all inside one ``hop`` span.
         """
         self._hop_barriers()
         packed = self._pack_ready()
         if packed is None:
             self._maybe_prewarm()  # starved step = idle; warm the grow
             return None
-        ready_slots, ready_mask, audio, shard_counts, t0, t_pack = packed
-        logits, post = self._dispatch_hop(ready_mask, audio)
-        # dispatch phase ends when the jitted call has returned its
-        # futures; the device phase is the explicit fence + transfers.
-        # Without the fence, JAX's async dispatch would let wall time
-        # measure *enqueue* rather than execution (egregiously so with
-        # emit_logits off, where nothing else forces a sync), and
-        # device_ms percentiles would be fiction.
-        t_dispatch = self._clock()
-        jax.block_until_ready((self._tails, self._pendings, self._gap))
-        logits_h = post_h = None
-        if self.emit_logits:
-            logits_h = np.asarray(logits)  # one bulk transfer per hop
-            post_h = np.asarray(post)
-        t_device = self._clock()
-        return self._fold_hop(ready_slots, shard_counts, logits_h, post_h,
-                              t0, t_pack, t_dispatch, t_device)
+        h, ready_mask, audio = packed
+        self._dispatch_hop(h, ready_mask, audio)
+        logits_h, post_h = self._fence_fetch(
+            h, (self._tails, self._pendings, self._gap))
+        return self._fold_hop(h, logits_h, post_h)
 
     # -- idle-time jit pre-warm ----------------------------------------------
 
@@ -1478,23 +1563,22 @@ class StreamScheduler:
         if key in self._warmed:
             return
         self._warmed.add(key)
-        t0 = self._clock()
         plan = self.plan
         z = lambda shape, dt: self._shard(jnp.zeros(shape, dt))  # noqa: E731
-        args = (
-            z((cap, plan.hop_samples), jnp.int32),      # pack_hops dtype
-            z((cap,), bool),
-            tuple(z((cap, st.tail, st.cin), jnp.int32)
-                  for st in plan.convs),
-            tuple(z((cap, st.phase, st.cout), jnp.int32)
-                  for st in plan.convs),
-            z((cap, plan.gap_channels), jnp.int32),
-        )
-        if self._pool is not None:
-            args = args + (z((cap,), jnp.int32),)
-        out = self._model.step(*args, emit=self.emit_logits)
-        jax.block_until_ready(out)
-        self.obs.trace.add("prewarm", t0, self._clock() - t0, capacity=cap)
+        with self.obs.trace.span("prewarm", clock=self._clock, capacity=cap):
+            args = (
+                z((cap, plan.hop_samples), jnp.int32),      # pack_hops dtype
+                z((cap,), bool),
+                tuple(z((cap, st.tail, st.cin), jnp.int32)
+                      for st in plan.convs),
+                tuple(z((cap, st.phase, st.cout), jnp.int32)
+                      for st in plan.convs),
+                z((cap, plan.gap_channels), jnp.int32),
+            )
+            if self._pool is not None:
+                args = args + (z((cap,), jnp.int32),)
+            out = self._model.step(*args, emit=self.emit_logits)
+            jax.block_until_ready(out)
         self.obs.events.emit("prewarm", capacity=cap)
 
     def step(self) -> list[tuple[int, int, np.ndarray | None, Detection | None]]:

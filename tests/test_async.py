@@ -18,8 +18,9 @@ The async scheduler's whole claim is that it changes WHEN host work runs
     ghost end-of-stream flush (regression vs the offline executor);
   * trace invariants: under overlap the old "spans tile the hop" sum
     double counts wall time, so ``coverage(mode="overlap")`` uses
-    interval unions; the device ∩ pack(N+1) overlap is *reported*
-    (``overlap_stats``), not flagged.
+    interval unions; the fence ∩ pack(N+1) overlap is *reported*
+    (``overlap_stats``), not flagged, and each hop's interleaved phase
+    spans are joined by the ``hop`` sequence number they carry.
 
 Event-log note: with the ingest pump enabled, push *timing* (and hence
 ``mass_join`` batching granularity) is inherently racy, so the
@@ -454,31 +455,31 @@ def test_coverage_overlap_mode_synthetic():
     tile invariant but union-coverage stays exact, and ``overlap_stats``
     reports the host∩device overlap."""
     spans = [
-        # hop 1: pack 0-1, device 1-9 (retired late), fold 9-10
+        # hop 1: pack 0-1, fence 1-9 (retired late), fold 9-10
         {"name": "hop", "t0": 0.0, "dur_s": 10.0},
         {"name": "pack", "t0": 0.0, "dur_s": 1.0},
-        {"name": "device", "t0": 1.0, "dur_s": 8.0},
+        {"name": "fence", "t0": 1.0, "dur_s": 8.0},
         {"name": "detector", "t0": 9.0, "dur_s": 1.0},
-        # hop 2's pack+dispatch run INSIDE hop 1's device span
+        # hop 2's pack+dispatch run INSIDE hop 1's fence span
         {"name": "hop", "t0": 2.0, "dur_s": 12.0},
         {"name": "pack", "t0": 2.0, "dur_s": 1.0},
-        {"name": "device", "t0": 3.0, "dur_s": 10.0},
+        {"name": "fence", "t0": 3.0, "dur_s": 10.0},
         {"name": "detector", "t0": 13.0, "dur_s": 1.0},
     ]
-    tile = coverage(spans, phases=("pack", "device", "detector"))
+    tile = coverage(spans, phases=("pack", "fence", "detector"))
     assert tile == pytest.approx(22.0 / 22.0)
-    ov = coverage(spans, phases=("pack", "device", "detector"),
+    ov = coverage(spans, phases=("pack", "fence", "detector"),
                   mode="overlap")
     assert ov == pytest.approx(1.0)  # unions: no double count, no gap
     stats = overlap_stats(spans)
-    # hop2 pack [2,3] ⊂ device union [1,13]; hop1 detector [9,10] too
+    # hop2 pack [2,3] ⊂ fence union [1,13]; hop1 detector [9,10] too
     assert stats["hidden"] == pytest.approx(2.0)
     assert stats["host_total"] == pytest.approx(4.0)
     assert stats["hidden_frac"] == pytest.approx(0.5)
     assert stats["utilization"] == pytest.approx(12.0 / 14.0)
     # a missing phase still sinks union coverage below the floor
-    gappy = [s for s in spans if s["name"] != "device"]
-    assert coverage(gappy, phases=("pack", "device", "detector"),
+    gappy = [s for s in spans if s["name"] != "fence"]
+    assert coverage(gappy, phases=("pack", "fence", "detector"),
                     mode="overlap") < 0.5
 
 
@@ -521,6 +522,56 @@ def test_async_trace_overlap_invariants(smoke):
     sync.drain()
     assert sync.metrics.overlap_summary()["hidden_ms"] == 0.0
     assert coverage(obs2.trace.spans(), mode="overlap") >= 0.95
+
+
+def test_async_hop_spans_join_by_sequence(smoke):
+    """Under the pipeline, hop N+1's pack and dispatch fall inside hop N's
+    span; the ``hop`` arg still groups every phase with its own hop, and
+    each group's phases tile that hop's span (shared stamps)."""
+    spec, weights, thresholds, _prog = smoke
+    obs = Observability.create(mirror_events=False)
+    sched = AsyncStreamScheduler(
+        spec, weights, thresholds, capacity=4, initial_capacity=4,
+        min_capacity=4, obs=obs, clock=FakeClock(), use_pump=False,
+        inbox_samples=1 << 13,
+    )
+    plan = sched.plan
+    sids = [sched.add_stream() for _ in range(4)]
+    total = plan.prime_samples + 8 * plan.hop_samples
+    sched.push_audio_batch(sids, [_audio(s, 0, total) for s in sids])
+    assert sched.drain() == 8
+    spans = obs.trace.spans()
+    phases = ("pack", "dispatch", "fence", "fetch", "detector", "push_fold")
+    by_hop: dict[int, dict] = {}
+    for sp in spans:
+        if "hop" in sp["args"]:
+            by_hop.setdefault(sp["args"]["hop"], {})[sp["name"]] = sp
+    assert sorted(by_hop) == list(range(1, 9))
+    for seq, group in by_hop.items():
+        assert set(group) == {*phases, "hop"}, seq
+        assert sum(group[p]["dur_s"] for p in phases) == pytest.approx(
+            group["hop"]["dur_s"])
+        assert group["fetch"]["args"]["bytes"] > 0
+    # hop 2 was packed while hop 1 was in flight
+    hop1 = by_hop[1]["hop"]
+    assert by_hop[2]["pack"]["t0"] < hop1["t0"] + hop1["dur_s"]
+    assert coverage(spans, phases=phases, mode="overlap") >= 0.95
+    sched.shutdown()
+
+
+def test_ingest_span_runs_on_the_pump_thread(smoke):
+    spec, weights, thresholds, _prog = smoke
+    obs = Observability.create(mirror_events=False)
+    sched = AsyncStreamScheduler(spec, weights, thresholds, capacity=4,
+                                 obs=obs)
+    sids = [sched.add_stream() for _ in range(2)]
+    sched.push_audio_batch(sids, [_audio(s, 0, 300) for s in sids])
+    sched.flush_ingest()
+    ingest = obs.trace.spans("ingest")
+    assert [sp["args"] for sp in ingest] == [
+        {"chunks": 2, "samples": 600, "coalesced": 0}]
+    assert ingest[0]["tid"] != threading.get_ident()
+    sched.shutdown()
 
 
 # ---------------------------------------------------------------------------

@@ -272,7 +272,8 @@ def test_step_batch_columnar_matches_step_tuples(smoke):
     m = b.metrics.summary()
     assert m["host_pack_ms_p50"] >= 0.0
     assert m["step_ms_p50"] >= m["host_pack_ms_p50"]
-    assert m["device_ms_p50"] > 0.0
+    ps = b.metrics.phase_summary()
+    assert ps["fence"]["ms_p50"] + ps["fetch"]["ms_p50"] > 0.0
 
 
 def test_push_audio_batch_coalesces_duplicate_sids(smoke):

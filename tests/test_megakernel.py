@@ -341,7 +341,7 @@ def test_dispatches_per_hop_matches_trace(smoke, backend):
 
 def test_metrics_surface_dispatch_counts(smoke):
     """StreamMetrics carries the per-hop figure + running total into
-    summary(), and the device trace span is annotated with it."""
+    summary(), and the dispatch trace span is annotated with it."""
     spec, weights, thresholds, _ = smoke
     s = StreamScheduler(spec, weights, thresholds, capacity=2,
                         backend="megakernel")
@@ -352,9 +352,9 @@ def test_metrics_surface_dispatch_counts(smoke):
     summ = s.metrics.summary()
     assert summ["device_dispatches_per_hop"] == 1.0
     assert summ["device_dispatches_total"] == float(s.metrics.steps)
-    dev_spans = s.obs.trace.spans("device")
-    assert dev_spans and all(
-        sp["args"].get("dispatches") == 1 for sp in dev_spans
+    dispatch_spans = s.obs.trace.spans("dispatch")
+    assert dispatch_spans and all(
+        sp["args"].get("dispatches") == 1 for sp in dispatch_spans
     )
 
 

@@ -8,7 +8,10 @@ Pins the contracts the always-on deployment depends on:
 * metrics memory is constant over 10k hops of join/close/resize churn
   (the unbounded-list leak this plane replaced cannot come back);
 * device-phase timing is fenced — the jitted step's execution cost lands
-  in the ``device`` span, not wherever results happen to be forced;
+  in the ``fence`` span, not wherever results happen to be forced;
+* the spans reach a JAX profile only while one is being captured: with
+  none, the hop path checks the gate once per span boundary and builds
+  no annotation; with one, the hop's annotations nest in phase order;
 * empty summaries report NaN, never a fabricated 0.0, and the report
   renders them as "—";
 * sid reuse retires the first tenant's counters instead of clobbering;
@@ -40,6 +43,7 @@ from repro.obs import (
     Tracer,
     coverage,
 )
+from repro.obs import trace as trace_mod
 from repro.stream import StreamScheduler, plan_stream
 from repro.stream.metrics import StreamMetrics, _charge_scaled
 from repro.utils.logging import RateLimiter
@@ -221,7 +225,7 @@ def test_tracer_spans_and_chrome_export(tmp_path):
     t0 = 0.0
     tr.add_batch((
         ("pack", t0, 0.2, {"n": 4}),
-        ("device", 0.2, 0.7, {}),
+        ("fence", 0.2, 0.7, {}),
         ("hop", t0, 0.9, {"n": 4}),
     ))
     with tr.span("resize", old=2, new=4):
@@ -230,14 +234,14 @@ def test_tracer_spans_and_chrome_export(tmp_path):
     events = tr.export_chrome()
     names = [e["name"] for e in events]
     assert names[0] == "process_name"  # metadata record
-    assert {"pack", "device", "hop", "resize"} <= set(names)
+    assert {"pack", "fence", "hop", "resize"} <= set(names)
     path = tmp_path / "trace.json"
     n = tr.export_chrome(path=str(path))
     doc = json.loads(path.read_text())
     assert len(doc["traceEvents"]) == n + 1
     hop = next(e for e in doc["traceEvents"] if e["name"] == "hop")
     assert hop["ph"] == "X" and hop["dur"] == pytest.approx(0.9e6)
-    assert coverage(events, phases=("pack", "device")) == pytest.approx(1.0)
+    assert coverage(events, phases=("pack", "fence")) == pytest.approx(1.0)
 
 
 def test_tracer_bounded_and_disabled():
@@ -263,7 +267,8 @@ def test_metrics_constant_memory_over_10k_steps(plan):
 
     def hop(i):
         m.on_step(8, plan.frames_per_hop, 1e-3, host_pack_s=1e-4,
-                  dispatch_s=2e-4, device_s=6e-4, detector_s=1e-4)
+                  dispatch_s=2e-4, fence_s=5e-4, fetch_s=1e-4,
+                  detector_s=1e-4)
         if i % 7 == 0:
             m.on_resize(8 << (i % 3))
         sid = i % 1000
@@ -295,10 +300,12 @@ def test_metrics_empty_summary_nan_not_zero(plan):
     m = StreamMetrics(plan)
     s = m.summary()
     for key in ("step_ms_p50", "step_ms_p95", "step_ms_p99", "step_ms_p999",
-                "host_pack_ms_p50", "device_ms_p50", "device_ms_p99"):
+                "host_pack_ms_p50", "host_pack_ms_p95"):
         assert math.isnan(s[key]), key
     # non-latency aggregates legitimately start at zero
     assert s["samples_pushed"] == 0.0 and s["steps"] == 0.0
+    assert set(m.phase_summary()) == {"pack", "dispatch", "fence", "fetch",
+                                      "detector"}
     for p, d in m.phase_summary().items():
         assert math.isnan(d["ms_p50"]) and d["share_of_wall"] == 0.0, p
 
@@ -422,12 +429,14 @@ def test_device_phase_dominates_at_large_batch(smoke):
     ps = sched.metrics.phase_summary()
     m = sched.metrics.summary()
     assert m["steps"] >= 4
-    devside = ps["device"]["share_of_wall"] + ps["dispatch"]["share_of_wall"]
+    devside = (ps["fence"]["share_of_wall"] + ps["fetch"]["share_of_wall"]
+               + ps["dispatch"]["share_of_wall"])
     assert devside > ps["pack"]["share_of_wall"]
     assert devside > ps["detector"]["share_of_wall"]
     assert devside > 0.5, ps  # execution, not host work, owns the hop
-    # the legacy host/device split agrees: device strictly dominates
-    assert m["device_ms_p50"] > m["host_pack_ms_p50"]
+    # per hop too: launch plus the wait on the device dominate packing
+    assert (ps["dispatch"]["ms_p50"] + ps["fence"]["ms_p50"]
+            > m["host_pack_ms_p50"])
 
 
 def test_trace_spans_cover_hop_wall(smoke):
@@ -438,12 +447,100 @@ def test_trace_spans_cover_hop_wall(smoke):
     _stream_rounds(sched, 8, 6, np.random.default_rng(1))
     events = obs.trace.export_chrome()
     names = {e["name"] for e in events}
-    assert {"hop", "pack", "dispatch", "device", "detector",
-            "push_fold", "prime_batch"} <= names
+    assert {"hop", "pack", "dispatch", "fence", "fetch", "detector",
+            "push_fold", "prime_batch", "ingest"} <= names
     assert coverage(events) >= 0.95
     # phase stamps are consecutive: each hop is tiled exactly
     hops = [e for e in events if e["name"] == "hop"]
     assert all(e["ph"] == "X" and e["dur"] > 0 for e in hops)
+
+
+@pytest.fixture
+def fake_profiler(monkeypatch):
+    """Stands in for ``jax.profiler.TraceAnnotation``: counts the gate's
+    checks and the annotations built, and logs each one opened and
+    closed."""
+
+    class FakeAnnotation:
+        enabled = False
+        checks = 0
+        made = 0
+        log: list = []
+
+        @classmethod
+        def is_enabled(cls):
+            cls.checks += 1
+            return cls.enabled
+
+        def __init__(self, name, **args):
+            type(self).made += 1
+            self.name, self.args = name, args
+
+        def __enter__(self):
+            self.log.append(("open", self.name, dict(self.args)))
+            return self
+
+        def __exit__(self, *exc):
+            self.log.append(("close", self.name, dict(self.args)))
+
+        def set_metadata(self, **args):
+            self.args.update(args)
+
+    monkeypatch.setattr(trace_mod, "_Annotation", FakeAnnotation)
+    return FakeAnnotation
+
+
+def _primed(smoke, n_hops, seed):
+    """A 4-stream scheduler primed and past its first (compiling) hop,
+    with ``n_hops`` more hops buffered in every inbox."""
+    spec, weights, thresholds = smoke
+    sched = StreamScheduler(spec, weights, thresholds, capacity=4,
+                            initial_capacity=4, min_capacity=4)
+    plan = sched.plan
+    sids = [sched.add_stream() for _ in range(4)]
+    n = plan.prime_samples + (1 + n_hops) * plan.hop_samples
+    audio = np.random.default_rng(seed).integers(0, 256, (4, n))
+    sched.push_audio_batch(sids, list(audio.astype(np.uint8)))
+    assert sched.step_batch() is not None
+    return sched, sids
+
+
+def test_hop_path_without_a_profile_builds_no_annotation(smoke,
+                                                        fake_profiler):
+    sched, _ = _primed(smoke, 5, seed=4)
+    fake_profiler.checks = 0
+    assert sched.drain() == 5
+    assert fake_profiler.made == 0 and fake_profiler.log == []
+    # one check per boundary: hop+pack, dispatch, fence, fetch, detector,
+    # push_fold (the hop's close needs none)
+    assert fake_profiler.checks == 6 * 5
+    assert len(sched.obs.trace.spans("hop")) == 6  # the ring records all
+
+
+def test_hop_annotations_open_in_nesting_order(smoke, fake_profiler):
+    sched, sids = _primed(smoke, 0, seed=5)
+    fake_profiler.enabled = True
+    plan = sched.plan
+    chunk = np.full(plan.hop_samples, 7, np.uint8)
+    sched.push_audio_batch(sids + sids[:1], [chunk] * 5)
+    assert fake_profiler.log == [
+        ("open", "repro.ingest", {}),
+        ("close", "repro.ingest",
+         {"chunks": 5, "samples": 5 * plan.hop_samples, "coalesced": 1}),
+    ]
+    fake_profiler.log.clear()
+    assert sched.step_batch() is not None
+    seq = sched.obs.trace.spans("hop")[-1]["args"]["hop"]
+    phases = ("pack", "dispatch", "fence", "fetch", "detector", "push_fold")
+    want = [("open", "repro.hop")]
+    for p in phases:
+        want += [("open", "repro." + p), ("close", "repro." + p)]
+    want.append(("close", "repro.hop"))
+    assert [(k, n) for k, n, _ in fake_profiler.log] == want
+    assert {a["hop"] for _, _, a in fake_profiler.log} == {seq}
+    # the ring's spans of that hop carry the same sequence number
+    ring = [s for s in sched.obs.trace.spans() if s["args"].get("hop") == seq]
+    assert sorted(s["name"] for s in ring) == sorted(phases + ("hop",))
 
 
 def test_scheduler_event_log_lifecycle(smoke, tmp_path):
@@ -474,15 +571,17 @@ def test_metrics_summary_bit_compatible_with_reservoir(plan):
     rng = np.random.default_rng(3)
     walls = rng.uniform(5e-4, 5e-3, 200)
     packs = rng.uniform(1e-5, 1e-4, 200)
+    fences = walls - packs
     m = StreamMetrics(plan)
-    for w, p in zip(walls, packs):
-        m.on_step(4, plan.frames_per_hop, float(w), host_pack_s=float(p))
+    for w, p, f in zip(walls, packs, fences):
+        m.on_step(4, plan.frames_per_hop, float(w), host_pack_s=float(p),
+                  fence_s=float(f))
     s = m.summary()
     assert s["step_ms_p50"] == float(np.percentile(walls, 50) * 1e3)
     assert s["step_ms_p95"] == float(np.percentile(walls, 95) * 1e3)
     assert s["step_ms_p999"] == float(np.percentile(walls, 99.9) * 1e3)
     assert s["host_pack_ms_p50"] == float(np.percentile(packs, 50) * 1e3)
-    assert s["device_ms_p50"] == float(
-        np.percentile(walls - packs, 50) * 1e3
+    assert m.phase_summary()["fence"]["ms_p50"] == float(
+        np.percentile(fences, 50) * 1e3
     )
     assert s["latency_estimated"] == 0.0

@@ -219,18 +219,18 @@ def test_report_stream_table_renders_sweep_and_sharded():
     bench = {
         "sweep": {"8": {"hop_ms_p50": 1.5, "hop_ms_p99": 3.8,
                         "host_pack_ms_p50": 0.2,
-                        "device_ms_p50": 1.3,
+                        "fence_ms_p50": 1.3,
                         "stream_hops_per_sec": 4000.0,
                         "uj_per_inference": 0.0005}},
         "sharded": {
             "total_streams": 1024,
             "configs": {
                 "1": {"hop_ms_p50": 180.0, "host_pack_ms_p50": 4.0,
-                      "device_ms_p50": 176.0,
+                      "fence_ms_p50": 176.0,
                       "stream_hops_per_sec": 5000.0,
                       "uj_per_inference": 0.0005},
                 "8": {"hop_ms_p50": 150.0, "host_pack_ms_p50": 4.0,
-                      "device_ms_p50": 146.0,
+                      "fence_ms_p50": 146.0,
                       "stream_hops_per_sec": 6000.0,
                       "uj_per_inference": 0.0005},
             },
