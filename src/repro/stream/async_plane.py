@@ -12,8 +12,8 @@ machinery byte-for-byte identical and changes only *when* the host-side
 stages run:
 
   * **Ingest pump** — ``push_audio_batch`` enqueues to a daemon thread
-    that lands samples in the shared ``RingArena`` (one flat scatter,
-    PR 4) while the main thread packs and dispatches.  Arena mutations
+    that lands samples in the shared ``RingArena`` (one slice copy per
+    chunk) while the main thread packs and dispatches.  Arena mutations
     are serialized by the scheduler's ingest lock and marked by the
     arena's seqlock generation, so lock-free observers can detect (and
     retry past) a torn read instead of consuming one.

@@ -1145,20 +1145,22 @@ class StreamScheduler:
 
     def push_audio_batch(self, sids: list[int],
                          chunks: list[np.ndarray]) -> None:
-        """Bulk twin of ``push_audio``: one vectorized quantize + scatter
-        lands every stream's chunk in the shared arena
-        (``RingArena.push_batch``) — the ingest half of the zero-per-slot
-        hop path.  Float PCM and u8 chunks may be mixed, and a sid may
-        appear multiple times: duplicate-sid chunks coalesce in arrival
-        order (float chunks pre-quantized with the slot's gain — the
-        exact math the arena would apply — so the single scatter stays
-        bit-identical to sequential pushes).  Per-stream ``samples_in``
-        counters are NOT walked here — the arena's vectorized counter is
-        the truth and folds into the stream's metrics at close.
+        """Bulk twin of ``push_audio``: one vectorized quantize, then one
+        contiguous slice copy per chunk into its slot's arena row (two
+        where the chunk crosses the row's end), lands every stream's
+        chunk in the shared arena (``RingArena.push_batch``) — the ingest
+        half of the zero-per-slot hop path.  Float PCM and u8 chunks may
+        be mixed, and a sid may appear multiple times: duplicate-sid
+        chunks coalesce in arrival order (float chunks pre-quantized with
+        the slot's gain — the exact math the arena would apply — so the
+        arena's bytes stay bit-identical to sequential pushes).
+        Per-stream ``samples_in`` counters are NOT walked here — the
+        arena's vectorized counter is the truth and folds into the
+        stream's metrics at close.
 
         The whole call is one ``ingest`` span (args ``chunks``,
         ``samples``, ``coalesced``: chunks merged into an earlier one of
-        the same stream)."""
+        the same stream, ``wrapped``: chunks split at their row's end)."""
         arena = self._arena
         with self.obs.trace.span("ingest", clock=self._clock,
                                  chunks=len(sids)) as args:
@@ -1170,7 +1172,7 @@ class StreamScheduler:
                 slots, chunks, extra = self._coalesce_chunks(slots, chunks)
             else:
                 extra = None
-            arena.push_batch(slots, chunks)
+            wrapped = arena.push_batch(slots, chunks)
             coalesced = 0
             if extra is not None:
                 # credit the chunks the coalesce merged away (push_batch
@@ -1180,6 +1182,7 @@ class StreamScheduler:
                 arena.total_chunks_in += coalesced
             args["samples"] = arena.total_samples_in - before
             args["coalesced"] = coalesced
+            args["wrapped"] = wrapped
 
     def _coalesce_chunks(self, slots: np.ndarray, chunks: list[np.ndarray]
                          ) -> tuple[np.ndarray, list[np.ndarray], np.ndarray]:

@@ -149,9 +149,11 @@ class RingArena:
     monotonic read/write counters, the array-of-objects ->
     struct-of-arrays turn of the paper's §II-D ping-pong feature SRAM
     argument: one shared, layout-flexible buffer beats per-tenant buffers.
-    Every hot-path operation is one vectorized call:
+    Every hot-path operation is one call over the whole batch, with no
+    python loop over samples:
 
-      * ``push_batch``   quantize + scatter chunks for many streams at once
+      * ``push_batch``   quantize chunks for many streams at once, then
+                         land each with one contiguous row slice copy
       * ``ready_mask``   which slots hold >= n samples (one compare)
       * ``pack_hops``    gather every ready slot's hop window into the
                          batched ``(capacity_slots, hop)`` int32 step input
@@ -235,29 +237,32 @@ class RingArena:
     def set_gain(self, slot: int, gain: float) -> None:
         self.gain[slot] = gain
 
-    # -- ingest (quantize + scatter) -----------------------------------------
+    # -- ingest (quantize + slice copy) --------------------------------------
 
     def push(self, slot: int, audio: np.ndarray) -> None:
         """Append one stream's chunk (float PCM or u8 codes)."""
         self.push_batch(np.array([slot], np.int64), [audio])
 
-    def push_batch(self, slots: np.ndarray, chunks: list[np.ndarray]) -> None:
+    def push_batch(self, slots: np.ndarray, chunks: list[np.ndarray]) -> int:
         """Append one chunk per slot for many streams in one call.
 
         Float chunks are quantized in a single vectorized pass (each
-        stream's fixed gain repeated across its samples), integer chunks
-        are range-checked in a single pass, and everything lands in the
-        arena with ONE flat scatter — no python loop over samples.  Slots
-        must be unique within a call (chunk order per slot would otherwise
-        be ambiguous).
+        stream's fixed gain repeated across its samples) and integer
+        chunks are type- and range-checked, all before anything lands.
+        Each chunk then lands as one contiguous slice copy into its slot's
+        row, or two (row tail, then row head) when it crosses the row's
+        end — no per-sample index math.  Slots must be unique within a
+        call (chunk order per slot would otherwise be ambiguous).
+
+        Returns how many chunks were split at the row end.
         """
         slots = np.asarray(slots, np.int64)
         assert slots.size == len(chunks), (slots.size, len(chunks))
         if slots.size == 0:
-            return
+            return 0
         if np.unique(slots).size != slots.size:
             raise ValueError("push_batch slots must be unique per call")
-        chunks = [np.asarray(c).reshape(-1) for c in chunks]
+        chunks = [np.ravel(c) for c in chunks]
         lens = np.array([c.size for c in chunks], np.int64)
         free = self.capacity_samples - (self.wr[slots] - self.rd[slots])
         if (lens > free).any():
@@ -268,42 +273,52 @@ class RingArena:
                 f"{slots[worst]})"
             )
         is_f = np.array([c.dtype.kind == "f" for c in chunks], bool)
-        total = int(lens.sum())
-        flat = np.empty(total, np.uint8)
-        sample_is_f = np.repeat(is_f, lens)
         if is_f.any():
-            pcm = np.concatenate([c for c, f in zip(chunks, is_f) if f])
-            g = np.repeat(self.gain[slots[is_f]], lens[is_f])
-            flat[sample_is_f] = quantize_pcm(pcm, g)
+            fi = np.flatnonzero(is_f)
+            pcm = np.concatenate([chunks[i] for i in fi.tolist()])
+            g = np.repeat(self.gain[slots[fi]], lens[fi])
+            # views of one quantized buffer, one per float chunk
+            codes = np.split(quantize_pcm(pcm, g), np.cumsum(lens[fi])[:-1])
+            for i, c in zip(fi.tolist(), codes):
+                chunks[i] = c
         if not is_f.all():
-            ints = [c for c, f in zip(chunks, is_f) if not f]
-            for c in ints:
-                if c.dtype.kind not in "iu":
+            ints = np.flatnonzero(~is_f).tolist()
+            for i in ints:
+                if chunks[i].dtype.kind not in "iu":
                     raise TypeError(
                         f"audio must be float PCM or integer u8 codes, "
-                        f"got dtype {c.dtype}"
+                        f"got dtype {chunks[i].dtype}"
                     )
-            codes = np.concatenate(ints)
-            if codes.dtype != np.uint8 and codes.size and (
-                codes.min() < 0 or codes.max() > 255
-            ):
-                raise ValueError(
-                    f"integer sample codes out of u8 range [0, 255]: "
-                    f"min {codes.min()}, max {codes.max()}"
-                )
-            flat[~sample_is_f] = codes.astype(np.uint8, copy=False)
-        # flat scatter: (slot row, wrapped column) per sample
-        starts = np.cumsum(lens) - lens
-        rows = np.repeat(slots, lens)
-        offs = np.arange(total) - np.repeat(starts, lens)
-        cols = (np.repeat(self.wr[slots], lens) + offs) % self.capacity_samples
+            wide = [i for i in ints
+                    if chunks[i].dtype != np.uint8 and chunks[i].size]
+            if wide:
+                lo = min(chunks[i].min() for i in wide)
+                hi = max(chunks[i].max() for i in wide)
+                if lo < 0 or hi > 255:
+                    raise ValueError(
+                        f"integer sample codes out of u8 range [0, 255]: "
+                        f"min {lo}, max {hi}"
+                    )
+            for i in wide:
+                chunks[i] = chunks[i].astype(np.uint8)
+        cap = self.capacity_samples
+        starts = self.wr[slots] % cap
+        wrapped = starts + lens > cap
+        data = self.data
         with self._write():
-            self.data[rows, cols] = flat
+            for row, s, c in zip(slots.tolist(), starts.tolist(), chunks):
+                e = s + c.size
+                if e <= cap:
+                    data[row, s:e] = c
+                else:  # crosses the row's end: tail of the row, then head
+                    data[row, s:] = c[:cap - s]
+                    data[row, :e - cap] = c[cap - s:]
             self.wr[slots] += lens
             self.samples_in[slots] += lens
             self.chunks_in[slots] += 1
-            self.total_samples_in += total
+            self.total_samples_in += int(lens.sum())
             self.total_chunks_in += slots.size
+        return int(wrapped.sum())
 
     # -- drain ---------------------------------------------------------------
 

@@ -569,7 +569,7 @@ def test_ingest_span_runs_on_the_pump_thread(smoke):
     sched.flush_ingest()
     ingest = obs.trace.spans("ingest")
     assert [sp["args"] for sp in ingest] == [
-        {"chunks": 2, "samples": 600, "coalesced": 0}]
+        {"chunks": 2, "samples": 600, "coalesced": 0, "wrapped": 0}]
     assert ingest[0]["tid"] != threading.get_ident()
     sched.shutdown()
 

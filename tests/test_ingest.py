@@ -17,6 +17,7 @@ from repro.stream import (
     PosteriorDetector,
     RingArena,
     StreamScheduler,
+    plan_stream,
     quantize_pcm,
 )
 from repro.stream.detector import _softmax
@@ -142,6 +143,141 @@ def test_arena_pack_hops_gathers_and_consumes():
     arena.push(2, np.array([5, 6, 7], np.uint8))
     np.testing.assert_array_equal(arena.pack_hops(np.array([2]), 4)[2],
                                   [4, 5, 6, 7])
+
+
+def _ref_code(x, gain: float) -> int:
+    """One sample's u8 code, by hand: float PCM through the offset-binary
+    quantizer in float64 (round half to even), integers as they are."""
+    if isinstance(x, float):
+        v = min(max(x * gain, -1.0), 1.0) * 127.0
+        return min(max(round(v) + 128, 0), 255)
+    return int(x)
+
+
+class _RefArena:
+    """Per-sample python twin of RingArena's push side: sample i of a
+    chunk lands at column ``(wr + i) % cap`` of its slot's row."""
+
+    def __init__(self, slots: int, cap: int) -> None:
+        self.cap = cap
+        self.data = [[0] * cap for _ in range(slots)]
+        self.rd, self.wr = [0] * slots, [0] * slots
+        self.samples_in, self.chunks_in = [0] * slots, [0] * slots
+        self.total_samples_in = self.total_chunks_in = 0
+
+    def push(self, slot: int, chunk: np.ndarray, gain: float) -> bool:
+        wraps = self.wr[slot] % self.cap + chunk.size > self.cap
+        row, wr = self.data[slot], self.wr[slot]
+        for i, x in enumerate(chunk.tolist()):
+            row[(wr + i) % self.cap] = _ref_code(x, gain)
+        self.wr[slot] += chunk.size
+        self.samples_in[slot] += chunk.size
+        self.chunks_in[slot] += 1
+        self.total_samples_in += chunk.size
+        self.total_chunks_in += 1
+        return wraps
+
+
+_PUSH_DTYPES = (np.uint8, np.int64, np.float32, np.float64)
+
+
+@pytest.mark.parametrize("cap,seed", [(1, 0), (2, 1), (3, 2), (5, 3),
+                                      (8, 4), (13, 5)])
+def test_arena_push_batch_matches_per_sample_reference(cap, seed):
+    """Property: push_batch lands every sample exactly where a per-sample
+    ring would — u8, int64 and float32/float64 chunks mixed in one call
+    with per-slot gains, chunks that cross the row end, fill exactly the
+    free space, equal the whole capacity, or are empty — and keeps the
+    pointers, counters and totals the reference keeps, leaving the
+    seqlock generation even after every call."""
+    n_slots = 6
+    rng = np.random.default_rng(seed)
+    arena, ref = RingArena(n_slots, cap), _RefArena(n_slots, cap)
+    gains = rng.choice([1.0, 0.5, 2.0, 0.3], n_slots)
+    for slot, g in enumerate(gains.tolist()):
+        arena.set_gain(slot, g)
+    seen = set()
+    for _ in range(80):
+        k = int(rng.integers(1, n_slots + 1))
+        slots = rng.choice(n_slots, k, replace=False)
+        chunks, want_wrapped = [], 0
+        for slot in slots.tolist():
+            free = cap - (ref.wr[slot] - ref.rd[slot])
+            kind = int(rng.integers(4))
+            n = (0 if kind == 0 or free == 0 else free if kind == 1
+                 else int(rng.integers(1, free + 1)))
+            dtype = _PUSH_DTYPES[int(rng.integers(len(_PUSH_DTYPES)))]
+            if np.dtype(dtype).kind == "f":
+                c = rng.uniform(-1.3, 1.3, n).astype(dtype)
+            else:
+                c = rng.integers(0, 256, n).astype(dtype)
+            chunks.append(c)
+            wraps = ref.push(slot, c, float(gains[slot]))
+            want_wrapped += wraps
+            seen.add(np.dtype(dtype).name)
+            for case, hit in (("empty", n == 0), ("wrap", wraps),
+                              ("exact_free", 0 < n == free),
+                              ("whole_cap", n == cap),
+                              ("whole_cap_wraps", n == cap and wraps)):
+                if hit:
+                    seen.add(case)
+        assert arena.push_batch(slots, chunks) == want_wrapped
+        assert arena.generation % 2 == 0
+        np.testing.assert_array_equal(arena.data, np.array(ref.data))
+        assert arena.wr.tolist() == ref.wr
+        assert arena.samples_in.tolist() == ref.samples_in
+        assert arena.chunks_in.tolist() == ref.chunks_in
+        assert arena.total_samples_in == ref.total_samples_in
+        assert arena.total_chunks_in == ref.total_chunks_in
+        # consume a random amount so later pushes start mid-row
+        for slot in range(n_slots):
+            m = int(rng.integers(0, ref.wr[slot] - ref.rd[slot] + 1))
+            want = [ref.data[slot][(ref.rd[slot] + i) % cap]
+                    for i in range(m)]
+            assert arena.pop(slot, m).tolist() == want
+            ref.rd[slot] += m
+    want = {"empty", "exact_free", "whole_cap", "uint8", "int64", "float32",
+            "float64"}
+    if cap > 1:  # a one-sample row has no end to cross
+        want |= {"wrap", "whole_cap_wraps"}
+    assert seen >= want
+
+
+@pytest.mark.parametrize("bad", ["duplicate", "overflow", "dtype", "range"])
+def test_arena_rejected_push_leaves_arena_untouched(bad):
+    """A push rejected at the boundary — even one whose float chunks
+    were already quantized — lands nothing, bumps no counter and leaves
+    the seqlock generation where it was."""
+    arena = RingArena(4, 8)
+    arena.push_batch(np.array([0, 1]), [np.arange(6, dtype=np.uint8),
+                                        np.arange(3, dtype=np.uint8)])
+    arena.pop(0, 4)
+    before = (arena.data.copy(), arena.rd.copy(), arena.wr.copy(),
+              arena.samples_in.copy(), arena.chunks_in.copy(),
+              arena.total_samples_in, arena.total_chunks_in,
+              arena.generation)
+    slots = np.array([0, 1, 2])
+    chunks = [np.full(5, 9, np.uint8),                  # wraps slot 0's row
+              np.linspace(-1, 1, 4),                    # float, quantized
+              np.array([1, 2], np.int64)]
+    err = {"duplicate": ValueError, "overflow": MemoryError,
+           "dtype": TypeError, "range": ValueError}[bad]
+    if bad == "duplicate":
+        slots = np.array([0, 1, 0])
+    elif bad == "overflow":
+        chunks[1] = np.zeros(6)                         # 5 free in slot 1
+    elif bad == "dtype":
+        chunks[2] = np.array([True, False])
+    else:
+        chunks[2] = np.array([7, 256], np.int64)
+    with pytest.raises(err):
+        arena.push_batch(slots, chunks)
+    after = (arena.data, arena.rd, arena.wr, arena.samples_in,
+             arena.chunks_in, arena.total_samples_in, arena.total_chunks_in,
+             arena.generation)
+    for a, b in zip(before, after):
+        np.testing.assert_array_equal(a, b)
+    assert arena.push_batch(np.array([0]), [chunks[0]]) == 1  # then lands
 
 
 def test_frontend_facade_over_shared_arena():
@@ -308,6 +444,43 @@ def test_push_audio_batch_coalesces_duplicate_sids(smoke):
     # malformed dtypes are still rejected on the coalesce path
     with pytest.raises(TypeError, match=r"float PCM or integer u8"):
         a.push_audio_batch([s0, s0], [np.array([True]), np.array([False])])
+
+
+def test_ingest_span_counts_chunks_wrapped_at_row_end(smoke):
+    """The ``ingest`` span's ``wrapped`` arg is the number of chunks that
+    crossed their arena row's end: 0 for a push that fits, exactly the
+    crossing chunks after the streams' read pointers moved mid-row."""
+    spec, weights, thresholds, _ = smoke
+    plan = plan_stream(spec)
+    sched = StreamScheduler(spec, weights, thresholds, capacity=4,
+                            inbox_samples=plan.prime_samples
+                            + 2 * plan.hop_samples)
+    arena = sched._arena
+    cap = arena.capacity_samples
+    rng = np.random.default_rng(21)
+    sids = [sched.add_stream() for _ in range(3)]
+    fill = [cap, plan.prime_samples + plan.hop_samples, 7]
+    sched.push_audio_batch(
+        sids, [rng.integers(0, 256, n).astype(np.uint8) for n in fill])
+    assert sched.obs.trace.spans("ingest")[-1]["args"]["wrapped"] == 0
+    sched.drain()  # primes and consumes whole hops: rd moves mid-row
+    slots = np.array([sched._streams[s].slot for s in sids])
+    start = arena.wr[slots] % cap
+    free = cap - arena.fill()[slots]
+    want = int((start + free > cap).sum())
+    assert want == 2  # the two primed streams; the third never moved
+    chunks = [rng.uniform(-1, 1, free[0]),              # float PCM
+              rng.integers(0, 256, free[1]).astype(np.uint8),
+              rng.integers(0, 256, free[2]).astype(np.int64)]
+    sched.push_audio_batch(sids, chunks)
+    span = sched.obs.trace.spans("ingest")[-1]
+    assert span["args"] == {"chunks": 3, "samples": int(free.sum()),
+                            "coalesced": 0, "wrapped": want}
+    for slot, c in zip(slots.tolist(), chunks):
+        got = arena.peek(slot)[-c.size:]
+        if c.dtype.kind == "f":
+            c = quantize_pcm(c)
+        np.testing.assert_array_equal(got, c)
 
 
 def test_push_counters_fold_without_per_sid_python(smoke):

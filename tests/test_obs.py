@@ -526,7 +526,8 @@ def test_hop_annotations_open_in_nesting_order(smoke, fake_profiler):
     assert fake_profiler.log == [
         ("open", "repro.ingest", {}),
         ("close", "repro.ingest",
-         {"chunks": 5, "samples": 5 * plan.hop_samples, "coalesced": 1}),
+         {"chunks": 5, "samples": 5 * plan.hop_samples, "coalesced": 1,
+          "wrapped": 0}),
     ]
     fake_profiler.log.clear()
     assert sched.step_batch() is not None
