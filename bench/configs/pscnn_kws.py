@@ -73,10 +73,10 @@ def make_weights(cfg: dict, seed: int):
 
 # -- one layer --------------------------------------------------------------
 
-def _centered(codes: np.ndarray, ly: dict, offset: int) -> np.ndarray:
+def _centered(codes: np.ndarray, ly: dict) -> np.ndarray:
     """Input frames as signed integers; the pads then read 0."""
     x = np.asarray(codes, np.int64)
-    return x - offset if ly.get("in_bits", 1) > 1 else x
+    return x - ly.get("in_offset", 0) if ly.get("in_bits", 1) > 1 else x
 
 
 def _conv_sa(x: np.ndarray, w: np.ndarray, thr: np.ndarray,
@@ -101,7 +101,8 @@ def _pool(y: np.ndarray, p: int) -> np.ndarray:
 
 
 def _classifier(cfg, weights, thresholds, gap: np.ndarray) -> np.ndarray:
-    sat = next(ly for ly in cfg["layers"] if ly["kind"] == "gap")["saturate"]
+    sat = next(ly for ly in cfg["layers"]
+               if ly["kind"] == "gap")["not_in_spec"]["saturate"]
     h = np.minimum(gap, sat).astype(np.int64)
     for li, ly in enumerate(cfg["layers"]):
         if ly["kind"] != "fc":
@@ -132,7 +133,7 @@ def offline_logits(cfg: dict, weights, thresholds, codes: np.ndarray,
     for li, ly in enumerate(cfg["layers"]):
         if ly["kind"] != "conv":
             continue
-        xc = _centered(x, ly, cfg["in_offset"])
+        xc = _centered(x, ly)
         z = np.zeros((ly["pad"], ly["cin"]), np.int64)
         thr, flip = thresholds[li]
         y = _conv_sa(np.concatenate([z, xc, z]), weights[li], thr, flip,
@@ -152,7 +153,7 @@ class Reference:
         self.layers = [(li, ly) for li, ly in enumerate(cfg["layers"])
                        if ly["kind"] == "conv"]
         x = _requantize(np.asarray(codes).reshape(-1, 1), input_bits)
-        self.x0 = _centered(x, self.layers[0][1], cfg["in_offset"])
+        self.x0 = _centered(x, self.layers[0][1])
         # per layer: its input frames (left-pad causal) and its SA outputs
         self.inputs, self.sa = [], []
         cur = self.x0

@@ -4,7 +4,9 @@ Everything that belongs to one configuration, traffic mix or per-layer
 metric is found by its name in ``BENCHMARK.json``:
 
 * ``bench/configs/<config>.json``: the network as it is run, naming its
-  plain reference ``bench/configs/<reference>.py``;
+  plain reference ``bench/configs/<reference>.py``, the program's builder
+  of the network (``program``), the module that counts its work
+  (``work``) and, where several models share the pool, its tenants;
 * ``bench/traffic/<traffic>.json``: the mix, naming the generator
   ``bench/generators/<generator>.py`` that reads it (``generator.py``);
 * ``bench/layer_metrics/<metric>.py``: a ``read(ctx)`` that returns the
@@ -14,16 +16,20 @@ metric is found by its name in ``BENCHMARK.json``:
 
 The system under test is ``repro.stream.StreamScheduler`` with its
 default backend: the window drives ``push_audio_batch`` and
-``step_batch``.
+``step_batch``, and ``add_stream`` and ``close_stream`` where sessions
+come and go.  A cell on several chips spreads the slot pool over
+``make_stream_mesh(chips)``.
 """
 from __future__ import annotations
 
 import contextlib
 import dataclasses
 import gc
+import importlib
 import importlib.util
 import json
 import pathlib
+import re
 import shutil
 import sys
 import tempfile
@@ -37,11 +43,11 @@ sys.path[:0] = [str(BENCH), str(BENCH / "configs")]
 
 import audio  # noqa: E402
 import generator  # noqa: E402
-import work  # noqa: E402
 
 BANK_SECONDS = 64
 TRACE_S = 2.0          # length of a traced run's window, all profiled
 SLACK_S = 2.0          # schedule beyond the window's planned close
+WARM_JOINS = 16        # most joins primed together, where sessions join
 
 
 # -- discovery ---------------------------------------------------------------
@@ -54,6 +60,8 @@ def load_module(path: pathlib.Path):
     spec = importlib.util.spec_from_file_location(
         "bench_" + path.stem.replace(".", "_"), path)
     mod = importlib.util.module_from_spec(spec)
+    # a dataclass looks its module up by name while it is defined
+    sys.modules[spec.name] = mod
     spec.loader.exec_module(mod)
     return mod
 
@@ -93,6 +101,13 @@ def reference(cfg: dict):
     return load_module(BENCH / "configs" / f"{cfg['reference']}.py")
 
 
+def work_count(cfg: dict):
+    """The module that counts the network's work (``cfg["work"]``, a path
+    from the checkout's root): ``geometry``, ``ops_per_hop``,
+    ``bytes_per_hop``, ``weight_bytes`` and ``least_step_s``."""
+    return load_module(ROOT / cfg["work"])
+
+
 def open_chips(chips: int, who: str) -> list:
     """The TPU devices of this machine, with the compile cache on; a
     message and ``SystemExit(2)`` where there is no TPU or too few."""
@@ -122,42 +137,78 @@ def open_chips(chips: int, who: str) -> list:
 
 # -- the system under test ---------------------------------------------------
 
-def program_spec(cfg: dict):
-    """The program's network for ``cfg``; refuses a configuration file
-    that does not describe it layer for layer."""
-    from repro.core.cnn_spec import Conv1DSpec, FCSpec, GAPSpec
-    from repro.models import kws
+# keys of a configuration's layer that are not the program's to state
+UNCOMPARED = ("kind", "name", "not_in_spec")
 
-    spec = kws.build_kws_spec(in_len=cfg["in_len"], width=cfg["width"],
-                              n_classes=cfg["n_classes"])
-    for ly, ps in zip(cfg["layers"], spec.layers, strict=True):
-        if ly["kind"] == "conv":
-            ok = isinstance(ps, Conv1DSpec) and (
-                ps.cin, ps.cout, ps.k, ps.stride, ps.pad, ps.pool,
-                ps.in_bits) == (ly["cin"], ly["cout"], ly["k"], ly["stride"],
-                                ly["pad"], ly["pool"], ly.get("in_bits", 1))
-        elif ly["kind"] == "gap":
-            ok = isinstance(ps, GAPSpec) and ps.channels == ly["channels"]
-        else:
-            ok = isinstance(ps, FCSpec) and (ps.cin, ps.cout) == (
-                ly["cin"], ly["cout"]) and bool(ps.out_raw) == bool(
-                    ly.get("out_raw"))
-        if not ok:
+
+def layer_kind(layer) -> str:
+    """A program layer's kind: its ``kind`` attribute where it has one,
+    else its class name less ``Spec`` and a trailing ``<n>D``, in lower
+    case (``Conv1DSpec``: ``conv``, ``GAPSpec``: ``gap``)."""
+    kind = getattr(layer, "kind", None)
+    if kind is not None:
+        return kind
+    name = type(layer).__name__.removesuffix("Spec")
+    return re.sub(r"\d+D$", "", name).lower()
+
+
+def program_spec(cfg: dict):
+    """The program's network for ``cfg``, from the builder the file names
+    (``program``: ``{"builder": "<module>:<function>", "args": {...}}``);
+    refuses a file that does not describe it layer for layer.  Each key of
+    a file's layer, but for ``UNCOMPARED``, equals the program layer's
+    field of that name, and a field the file leaves out holds its
+    default."""
+    mod, _, fn = cfg["program"]["builder"].partition(":")
+    spec = getattr(importlib.import_module(mod), fn)(**cfg["program"]["args"])
+    if len(spec.layers) != len(cfg["layers"]):
+        raise ValueError(f"configuration has {len(cfg['layers'])} layers, "
+                         f"the program's network {len(spec.layers)}")
+    for ly, ps in zip(cfg["layers"], spec.layers):
+        fields = {f.name: f.default for f in dataclasses.fields(ps)}
+        bad = [] if layer_kind(ps) == ly["kind"] else ["kind"]
+        bad += [k for k, v in ly.items() if k not in UNCOMPARED
+                and (k not in fields or getattr(ps, k) != v)]
+        bad += [k for k, d in fields.items() if k not in UNCOMPARED
+                and k not in ly and getattr(ps, k) != d]
+        if bad:
             raise ValueError(f"configuration layer {ly['name']} differs "
-                             f"from the program's {ps}")
+                             f"from the program's {ps} in {bad}")
     return spec
 
 
-def build_system(cfg: dict, n: int, model, obs, inbox: int | None = None):
-    """The scheduler with its slot pool pinned at the ``n`` streams that
-    every run keeps open, each inbox ``inbox`` samples deep (None: the
-    program's default)."""
+def model_ids(cfg: dict) -> list[str]:
+    """The id the scheduler knows each tenant model by; the first is the
+    scheduler's construction model."""
+    from repro.stream import DEFAULT_MODEL
+
+    n = cfg.get("tenants", {}).get("count", 1)
+    return [DEFAULT_MODEL] + [f"tenant{i}" for i in range(1, n)]
+
+
+def build_system(cfg: dict, slots: int, models: list, obs,
+                 inbox: int | None = None, chips: int = 1):
+    """The scheduler with its slot pool pinned at ``slots``, each inbox
+    ``inbox`` samples deep (None: the program's default), every tenant
+    model registered, and the pool spread over ``chips`` chips."""
     from repro.stream import StreamScheduler
 
-    return StreamScheduler(program_spec(cfg), *model, capacity=n,
-                           initial_capacity=n, min_capacity=n,
-                           hop_frames=cfg["hop_frames"], obs=obs,
-                           inbox_samples=inbox)
+    kw = {}
+    if "tenants" in cfg:
+        kw = {"max_models": cfg["tenants"]["count"],
+              "tenant_block": cfg["tenants"]["tenant_block"]}
+    if chips > 1:
+        from repro.launch.mesh import make_stream_mesh
+
+        kw["mesh"] = make_stream_mesh(chips)
+    sched = StreamScheduler(program_spec(cfg), *models[0], capacity=slots,
+                            initial_capacity=slots, min_capacity=slots,
+                            hop_frames=cfg["hop_frames"], obs=obs,
+                            inbox_samples=inbox, **kw)
+    if "tenants" in cfg:
+        for mid, (w, t) in zip(model_ids(cfg), models):
+            sched.register_model(mid, w, t)
+    return sched
 
 
 # -- recording ---------------------------------------------------------------
@@ -198,10 +249,12 @@ class Record:
     closes: dict = dataclasses.field(default_factory=dict)  # sid -> logits
     lag: list = dataclasses.field(default_factory=list)
     recording: bool = False
+    closed: np.ndarray = None                # (n,) bool: its session closed
 
     def __post_init__(self) -> None:
         self.hops_done = np.zeros(self.n, np.int64)
         self.pushed = np.zeros(self.n, np.int64)
+        self.closed = np.zeros(self.n, bool)
 
     def step(self, hb, t: float) -> None:
         sids = hb.sids
@@ -251,7 +304,9 @@ class Run:
         self.c, self.seed, self.seconds, self.trace = c, seed, seconds, trace
         self.cfg, self.mix = c["config"], c["mix"]
         self.clock, self.sleep = clock, sleep
-        self.g = work.geometry(self.cfg)
+        self.work = work_count(self.cfg)
+        self.g = self.work.geometry(self.cfg)
+        self.chips = int(c["workload"]["chips"])
         self.ann = _null
         self.opened = False
         self.deepest = 0           # most samples one inbox held in the window
@@ -275,8 +330,12 @@ class Run:
         self.counter.on = True
         # the weights are the configuration's own, whatever the seed: a
         # checkpoint belongs to the configuration, and the hop step is
-        # compiled for its weights, so new weights would compile anew
-        self.model = reference(cfg).make_weights(cfg, cfg["weights"]["seed"])
+        # compiled for its weights, so new weights would compile anew.
+        # Tenant i's come from the configuration's seed + i.
+        seed0 = cfg["weights"]["seed"]
+        self.models = [reference(cfg).make_weights(cfg, seed0 + i)
+                       for i in range(cfg.get("tenants", {}).get("count", 1))]
+        self.ids = model_ids(cfg)
         lead = float(mix["lead_in_s"])
         # the window closes `seconds` after it opens; the schedule runs
         # on past that, so a late opening never starves it
@@ -286,15 +345,19 @@ class Run:
         # once: the first hop on freshly primed state then
         # compiles whatever it needs before the window
         g = self.g
-        self.sch = generator.build(mix, self.seed, self.t_hi + SLACK_S,
-                                   cfg["sample_rate"],
-                                   g.prime_samples + g.hop_samples, bank.size)
+        extra = {"tenants": len(self.models)} if "tenants" in cfg else {}
+        self.sch = sch = generator.build(
+            mix, self.seed, self.t_hi + SLACK_S, cfg["sample_rate"],
+            g.prime_samples + g.hop_samples, bank.size, **extra)
+        n = sch.n_streams
+        self.join = np.zeros(n) if sch.join is None else sch.join
+        self.close = np.full(n, np.inf) if sch.close is None else sch.close
+        self.tenant = np.zeros(n, np.int64) if sch.tenant is None \
+            else sch.tenant
         hi = int(mix["chunk_ms"][1] * cfg["sample_rate"] // 1000)
-        self.feed = Feed(bank, self.sch.offset,
+        self.feed = Feed(bank, sch.offset,
                          max(hi, g.prime_samples + g.hop_samples))
-        rng = np.random.default_rng([self.seed, 0xC4EC])
-        self.rec = Record(self.sch.n_streams, self._sample(rng))
-        self.ptr = np.zeros(self.sch.n_streams, np.int64)  # closed loop
+        self.ptr = np.zeros(n, np.int64)  # closed loop
         lap("inputs")
         self.obs = Observability.create(trace_capacity=1 << 20,
                                         mirror_events=False)
@@ -303,21 +366,33 @@ class Run:
         # never overflows an inbox (the program's default holds 4 s)
         inbox = None if mix["loop"] == "closed" else int(
             self.sch.c_end.max(initial=0))
-        self.sched = build_system(cfg, self.sch.n_streams, self.model,
-                                  self.obs, inbox)
+        self.sched = build_system(cfg, int(mix.get("slots", n)), self.models,
+                                  self.obs, inbox, self.chips)
         lap("build")
-        # the schedule never drives the pool past its initial capacity
+        # the pool is pinned: the run never leaves its one capacity
         self.caps = [self.sched.capacity]
         self.sched.warm(self.sched.capacity)
+        every = np.flatnonzero(self.join <= 0)
+        batch = every.size
+        if (self.join > 0).any():
+            # each new size of a batch of joins primed together compiles
+            # the program's scatters: set-up warms sizes up to WARM_JOINS
+            # and primes its own sessions in batches no larger, whatever
+            # the seed's tenants
+            batch = WARM_JOINS
+            self._warm_joins(batch)
         lap("warm")
-        every = np.arange(self.sch.n_streams)
         for s in every.tolist():
-            self.sched.add_stream(sid=s)
+            self._join(s)
         lap("join")
-        self._push(every, np.zeros(every.size, np.int64), self.sch.prefill)
-        hb = self.sched.step_batch()    # primes them and runs one hop
-        if hb is not None:
-            self.rec.step(hb, 0.0)
+        rng = np.random.default_rng([self.seed, 0xC4EC])
+        self.rec = Record(n, self._sample(rng))
+        for part in np.array_split(every, -(-every.size // batch)):
+            self._push(part, np.zeros(part.size, np.int64),
+                       sch.prefill[part])
+            hb = self.sched.step_batch()    # primes them and runs one hop
+            if hb is not None:
+                self.rec.step(hb, 0.0)
         lap("prime_and_first_hop")
         self.counter.on = False
         # set-up's objects never die: keep the collector from walking them
@@ -327,12 +402,65 @@ class Run:
         split["compiles"] = self.counter.compiles
         split["cache_loads"] = self.counter.cache_loads
 
+    def _model(self, tenant: int) -> str | None:
+        """The model id a stream of ``tenant`` binds to (None: the one
+        model of a scheduler without tenants)."""
+        return self.ids[tenant] if "tenants" in self.cfg else None
+
+    def _join(self, sid: int) -> None:
+        self.sched.add_stream(sid=sid, model=self._model(self.tenant[sid]))
+
+    def _warm_joins(self, most: int) -> None:
+        """Join, prime and close 1 to ``most`` streams at once, so that
+        every batch of joins the window can prime has its programs
+        compiled in set-up.  Their stream ids lie past the schedule's."""
+        sch, g = self.sch, self.g
+        sid = sch.n_streams
+        audio = self.feed.chunk(0, 0, g.prime_samples + g.hop_samples)
+        for k in range(1, most + 1):
+            sids = list(range(sid, sid + k))
+            sid += k
+            for s in sids:
+                self.sched.add_stream(sid=s, model=self._model(0))
+            self.sched.push_audio_batch(sids, [audio] * k)
+            self.sched.step_batch()
+            for s in sids:
+                self.sched.close_stream(s)
+
     def _sample(self, rng) -> np.ndarray:
-        """Streams whose hops the check compares, drawn from the seed."""
+        """Streams whose hops the check compares, drawn from the seed.
+        Streams fall in groups by tenant and by whether they join and
+        close inside the window; the draw's count goes round the groups
+        (each tenant's churning sessions, then its others), one stream a
+        group a pass, and each group's streams are drawn from the seed.
+        With one group this is a plain draw of the count."""
         n = self.sch.n_streams
         m = np.zeros(n, bool)
-        m[rng.choice(n, min(self.mix["check"]["streams"], n),
-                     replace=False)] = True
+        window = min(self.seconds, TRACE_S) if self.trace else self.seconds
+        churn = (self.join >= self.t_lo) & (self.close <= self.t_lo + window)
+        # a stream is eligible where it is open from set-up, or where its
+        # first chunk is due a second (a quarter of a shorter window) or
+        # more before the window closes
+        first = np.full(n, np.inf)
+        np.minimum.at(first, self.sch.c_sid, self.sch.c_due)
+        live = (self.join <= 0) | (
+            first < self.t_lo + window - min(1.0, window / 4))
+        groups = {}
+        for s in np.flatnonzero(live).tolist():
+            key = (int(self.tenant[s]), not churn[s])
+            groups.setdefault(key, []).append(s)
+        order = sorted(groups)
+        quota = dict.fromkeys(order, 0)
+        left = min(self.mix["check"]["streams"], int(live.sum()))
+        while left:
+            for g in order:
+                if left and quota[g] < len(groups[g]):
+                    quota[g] += 1
+                    left -= 1
+        for g in order:
+            members = np.asarray(groups[g])
+            pick = rng.choice(members.size, quota[g], replace=False)
+            m[members[pick]] = True
         return m
 
     def _push(self, sids: np.ndarray, a: np.ndarray, b: np.ndarray) -> None:
@@ -369,6 +497,8 @@ class Run:
             if now >= self.t_hi:
                 break
             tick(now)
+        if self.mix["loop"] == "open":
+            self._settle(self.t_hi)
         self.t_close = clock() - origin
         self.counter.on = False
         if self.trace:
@@ -411,32 +541,95 @@ class Run:
         self._jax_profiler.stop_trace()
 
     def _open_tick(self):
-        """One turn of the open loop: push what is due, step once, and
-        sleep to the next due event when no stream is ready."""
+        """One turn of the open loop: join, push and close what is due,
+        step once, and sleep to the next due event when no stream is
+        ready."""
         sch, rec = self.sch, self.rec
         due = sch.c_due
         pos = [0]
+        joins = np.flatnonzero(self.join > 0)
+        joins = joins[np.argsort(self.join[joins], kind="stable")]
+        j_due = self.join[joins]
+        closes = np.flatnonzero(np.isfinite(self.close))
+        closes = closes[np.argsort(self.close[closes], kind="stable")]
+        x_due = self.close[closes]
+        jpos, xpos = [0], [0]
+
+        def join_due(h: float) -> None:
+            a = jpos[0]
+            b = int(np.searchsorted(j_due, h, side="right"))
+            for s in joins[a:b].tolist():
+                self._join(s)
+            jpos[0] = b
+
+        def push_due(h: float, now: float) -> None:
+            i = pos[0]
+            j = int(np.searchsorted(due, h, side="right"))
+            if j == i:
+                return
+            with self.ann("bench.push"):
+                self._push(sch.c_sid[i:j], sch.c_start[i:j],
+                           sch.c_end[i:j])
+                if self.opened:
+                    rec.lag.append(now - due[i:j])
+                    sids = sch.c_sid[i:j]
+                    self.deepest = max(self.deepest, int(
+                        (rec.pushed[sids] - self.g.prime_samples
+                         - rec.hops_done[sids] * self.g.hop_samples).max()))
+            pos[0] = j
+
+        def close_due(h: float) -> None:
+            a = xpos[0]
+            b = int(np.searchsorted(x_due, h, side="right"))
+            for s in closes[a:b].tolist():
+                res = self.sched.close_stream(s)
+                rec.closed[s] = True
+                if rec.sampled[s]:
+                    rec.closes[s] = (res.logits, int(rec.pushed[s]))
+            xpos[0] = b
+
+        def catch_up(h: float, now: float) -> None:
+            """Join, push and close what is due by ``h``, in time order,
+            with ``now`` the time of the push: a turn that ran late takes
+            the closes due in the order of their times, each after the
+            joins and audio due before it, so the pool never holds more
+            sessions than the schedule has open."""
+            while True:
+                upto = h
+                if xpos[0] < x_due.size and x_due[xpos[0]] < h:
+                    upto = x_due[xpos[0]]
+                if joins.size:
+                    with self.ann("bench.join"):
+                        join_due(upto)
+                push_due(upto, now)
+                if closes.size:
+                    with self.ann("bench.close"):
+                        close_due(upto)
+                if upto == h:
+                    return
+                now = self.clock() - self.origin
+
+        def settle(h: float) -> None:
+            """Where sessions come and go: the sessions due to join or
+            close before the window closed, and their audio due, are
+            offered however late the loop ran."""
+            if joins.size or closes.size:
+                catch_up(h, self.clock() - self.origin)
+
+        self._settle = settle
 
         def tick(now: float) -> None:
-            i = pos[0]
-            j = int(np.searchsorted(due, now, side="right"))
-            if j > i:
-                with self.ann("bench.push"):
-                    self._push(sch.c_sid[i:j], sch.c_start[i:j],
-                               sch.c_end[i:j])
-                    if self.opened:
-                        rec.lag.append(now - due[i:j])
-                        sids = sch.c_sid[i:j]
-                        self.deepest = max(self.deepest, int(
-                            (rec.pushed[sids] - self.g.prime_samples
-                             - rec.hops_done[sids] * self.g.hop_samples
-                             ).max()))
-                pos[0] = i = j
+            catch_up(now, now)
             with self.ann("bench.step_batch"):
                 hb = self.sched.step_batch()
             t = self.clock() - self.origin
             if hb is None:
-                nxt = due[i] if i < due.size else self.t_hi
+                i = pos[0]
+                nxt = min(due[i] if i < due.size else self.t_hi,
+                          j_due[jpos[0]] if jpos[0] < j_due.size
+                          else self.t_hi,
+                          x_due[xpos[0]] if xpos[0] < x_due.size
+                          else self.t_hi)
                 wait = min(nxt, self.t_hi) - t
                 if wait > 0:
                     self.sleep(wait)
@@ -467,7 +660,7 @@ class Run:
 
     def _backlog(self) -> int:
         """Whole hops of audio buffered and not yet consumed."""
-        live = self.rec.pushed >= self.g.prime_samples
+        live = (self.rec.pushed >= self.g.prime_samples) & ~self.rec.closed
         left = (self.rec.pushed - self.g.prime_samples
                 - self.rec.hops_done * self.g.hop_samples)
         return int((left[live] // self.g.hop_samples).sum())
@@ -494,6 +687,10 @@ class Run:
         due = sch.c_due[order][pos]
         return done - due
 
+    def in_window(self, times: np.ndarray) -> int:
+        """How many of the schedule's ``times`` fell inside the window."""
+        return int(((times > self.t_open) & (times <= self.t_close)).sum())
+
     def memory_peak(self, devices) -> int:
         peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0)
                  for d in devices]
@@ -501,13 +698,17 @@ class Run:
 
     def failed(self) -> int:
         """Hops missing, or too many, after the post-window drain, over
-        every stream: each has all its whole hops out."""
+        every stream: each open stream has all its whole hops out, and a
+        closed one no more than it was sent (its close scored the rest)."""
         want = np.maximum(0, self.rec.pushed - self.g.prime_samples
                           ) // self.g.hop_samples
-        return int(np.abs(want - self.rec.hops_done).sum())
+        gap = want - self.rec.hops_done
+        return int(np.where(self.rec.closed, np.maximum(-gap, 0),
+                            np.abs(gap)).sum())
 
     def close_sampled(self) -> None:
-        """Close the sampled streams, keeping their flushed logits."""
+        """Close the sampled streams still open, keeping their flushed
+        logits."""
         for s in np.flatnonzero(self.rec.sampled).tolist():
             if s not in self.rec.closes:
                 res = self.sched.close_stream(s)
@@ -539,7 +740,7 @@ def check(run: Run, control: bool = False) -> dict:
                        + max(rows) * g.hop_samples > n_pushed):
             count_bad += 1
         codes = run.feed.stream(s, n_pushed)
-        w, t = run.model
+        w, t = run.models[run.tenant[s]]
         r = ref.Reference(cfg, w, t, codes)
         if control:
             low = ref.Reference(cfg, w, t, codes, input_bits=4)
@@ -579,6 +780,7 @@ class Context:
     spans: list                      # the program's spans in the window
     lag_s: np.ndarray                # generator lag of each chunk pushed
     geometry: object
+    work: object                     # the configuration's work count
     peak: dict | None
     chips: int
     trace: dict | None               # reduced profile of the window
@@ -611,7 +813,7 @@ def per_layer(run: Run, metrics: list[dict], peak: dict | None,
         window_s=run.t_close - run.t_open, step_hops=step_hops,
         spans=run.spans,
         lag_s=np.concatenate(run.rec.lag) if run.rec.lag else np.zeros(0),
-        geometry=run.g, peak=peak, chips=chips, trace=trace)
+        geometry=run.g, work=run.work, peak=peak, chips=chips, trace=trace)
     out = {}
     for m in metrics:
         v = layer_reader(m["name"]).read(ctx)
@@ -677,7 +879,9 @@ def execute(c: dict, seed: int, seconds: float, trace: bool, devices,
             f"events={window_compiles}, xla compiles={run.counter.compiles},"
             f" cache loads={run.counter.cache_loads}, backlog hops at open="
             f"{run.backlog_open} at close={run.backlog_end}, deepest inbox="
-            f"{run.deepest} samples, longest turn={run.longest_turn:.3f} s")
+            f"{run.deepest} samples, longest turn={run.longest_turn:.3f} s, "
+            f"joins={run.in_window(run.join)} closes="
+            f"{run.in_window(run.close)}")
     mem = run.memory_peak(devices)
     device = {"platform": platform, "kind": kind, "count": len(devices),
               "memory_peak_bytes": mem}

@@ -4,8 +4,8 @@
     python bench/sweep.py --workload kws_rt --seed 11 --seconds 6 \\
         --streams 2048 2560 3072
 
-Each point is one run of the cell with ``streams`` replaced, in one
-process.  A point sustains its load when the buffered audio at the
+Each point is one run of the cell with ``streams`` replaced (and the
+pinned pool's ``slots``, with ``--slots``), in one process.  A point sustains its load when the buffered audio at the
 window's close is no larger than at its open (``backlog hops`` on the
 ``window:`` line).  The cell's mix then takes 4/5 of the highest
 sustained count; the sweep itself is recorded in ``PERF.md``.
@@ -27,6 +27,7 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--streams", type=int, nargs="+", required=True)
+    ap.add_argument("--slots", type=int, default=None)
     args = ap.parse_args(argv)
     sys.path.insert(0, str(ROOT / "src"))
     import harness
@@ -36,11 +37,13 @@ def main(argv=None) -> int:
     for n in args.streams:
         c = copy.deepcopy(base)
         c["mix"]["streams"] = n
+        if args.slots is not None:
+            c["mix"]["slots"] = args.slots
         print(f"sweep point streams={n}", flush=True)
         try:
             line = harness.execute(c, args.seed, args.seconds, False,
                                    devices, time.perf_counter())
-        except MemoryError as e:   # an inbox overflowed: far past the knee
+        except MemoryError as e:   # an inbox or the pool overflowed
             line = f"overloaded: {e}"
         print(f"sweep result streams={n} {line}", flush=True)
     return 0

@@ -32,7 +32,7 @@ def small_config(cfg: dict, w: int = 8) -> dict:
             ly["cin"], ly["cout"] = int(5.5 * w), 8 * w
         else:
             ly["cin"] = 8 * w
-    cfg["width"] = w
+    cfg["program"]["args"]["width"] = w
     return cfg
 
 

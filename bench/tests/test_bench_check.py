@@ -36,7 +36,8 @@ def test_reference_matches_the_offline_executor(tiny):
 
     cfg, w, t, bank = tiny
     clip = bank[:4000]
-    spec = kws.build_kws_spec(in_len=clip.size, width=cfg["width"])
+    spec = kws.build_kws_spec(in_len=clip.size,
+                              width=cfg["program"]["args"]["width"])
     prog = compiler.compile_model(spec, w, t, rotate_hints=(),
                                   rowsplit_hints={})
     want = executor.Executor(prog).run(clip[:, None]).output.ravel()

@@ -109,7 +109,7 @@ def test_weights_are_the_configurations_and_inputs_the_seeds():
         run = harness.Run(tiny_cell("kws_rt"), seed, 0.2, False)
         run.setup()
         runs.append(run)
-    (wa, ta), (wb, tb) = (r.model for r in runs)
+    (wa, ta), (wb, tb) = (r.models[0] for r in runs)
     assert wa.keys() == wb.keys()
     assert all(np.array_equal(wa[k], wb[k]) for k in wa)
     assert all(np.array_equal(ta[k][0], tb[k][0]) for k in ta)

@@ -10,6 +10,12 @@ operation and byte counts read the same whatever backend runs the hop.
 Bytes are counted at the algorithm's own widths: 8-bit audio and GAP
 counters, 1-bit activations and tails, 16-bit raw logits, and ternary
 weights (2 bits) read once per batched step.
+
+A configuration names the module that counts its work (``"work"``); this
+one serves the conv -> GAP -> FC networks.  A network of another shape
+brings its own module with the same five functions: ``geometry``,
+``ops_per_hop``, ``bytes_per_hop``, ``weight_bytes`` and
+``least_step_s``; the harness and the readers use no others.
 """
 from __future__ import annotations
 
@@ -119,7 +125,7 @@ def geometry(cfg: dict) -> Geometry:
     gap = next(ly for ly in cfg["layers"] if ly["kind"] == "gap")
     fcs = tuple((ly["cin"], ly["cout"]) for ly in fc_layers(cfg))
     return Geometry(hop, prime, tuple(stages), fcs, gap["channels"],
-                    gap["bits"])
+                    gap["not_in_spec"]["bits"])
 
 
 def macs_per_hop(g: Geometry) -> dict[str, int]:
