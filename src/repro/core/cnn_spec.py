@@ -5,6 +5,9 @@ A model is a sequence of layers over a (length, channels) feature map:
   Conv1D : ternary weights (K, Cin, Cout), stride/pad, optional fused pool,
            SA binary output or raw counts; multi-bit input via bit-serial.
   Pool   : standalone max-pool (PWB bypass).
+  Res    : residual block of two same-length convs whose raw counts join
+           the block input (identity, or a 1x1 projection where the
+           channels change) before the second SA.
   GAP    : global average pool -> 8-bit counts.
   FC     : dense (Cin, Cout) = Conv1D with K=1 on a length-1 map, but kept
            explicit because its input may be multi-bit GAP counts.
@@ -15,6 +18,7 @@ ISA compiler, (c) the latency/energy analysis.
 from __future__ import annotations
 
 import dataclasses
+from typing import ClassVar
 
 import numpy as np
 
@@ -63,6 +67,49 @@ class PoolSpec:
 
 
 @dataclasses.dataclass(frozen=True)
+class ResBlock1DSpec:
+    """Binary residual block over a binary input ``x``::
+
+        h = SA1(conv_k(x; W1))              # binary, cout channels
+        s = conv_k(h; W2)                   # raw counts
+        r = x            (proj False: cin == cout)
+            x @ Wp       (proj True: 1x1 ternary projection, raw counts)
+        y = SA2(s + g * r)                  # g: per-channel integer gain
+
+    Both convs are stride 1 with ``2 * pad == k - 1``, so ``y`` has the
+    length of ``x`` and the join lines up position for position.
+    """
+
+    cin: int
+    cout: int
+    k: int = 3
+    pad: int = 1
+    proj: bool = False
+    name: str = "res"
+    kind: ClassVar[str] = "res"
+
+    def __post_init__(self) -> None:
+        if 2 * self.pad != self.k - 1:
+            raise ValueError(f"{self.name}: a residual conv must keep the "
+                             f"length (2 * pad == k - 1), got k {self.k} "
+                             f"pad {self.pad}")
+        if self.cin != self.cout and not self.proj:
+            raise ValueError(f"{self.name}: cin {self.cin} != cout "
+                             f"{self.cout} needs a projection shortcut")
+
+    def out_len(self, in_len: int) -> int:
+        return in_len
+
+    @property
+    def weights(self) -> int:
+        w = self.k * self.cin * self.cout + self.k * self.cout * self.cout
+        return w + (self.cin * self.cout if self.proj else 0)
+
+    def macs(self, in_len: int) -> int:
+        return self.weights * in_len
+
+
+@dataclasses.dataclass(frozen=True)
 class GAPSpec:
     channels: int
     name: str = "gap"
@@ -94,7 +141,7 @@ class FCSpec:
         return self.cin
 
 
-LayerSpec = Conv1DSpec | PoolSpec | GAPSpec | FCSpec
+LayerSpec = Conv1DSpec | PoolSpec | ResBlock1DSpec | GAPSpec | FCSpec
 
 
 @dataclasses.dataclass(frozen=True)
@@ -118,6 +165,9 @@ class CNN1DSpec:
             elif isinstance(spec, PoolSpec):
                 assert spec.channels == c
                 l = spec.out_len(l)
+            elif isinstance(spec, ResBlock1DSpec):
+                assert spec.cin == c, f"{spec.name}: cin {spec.cin} != {c}"
+                c = spec.cout
             elif isinstance(spec, GAPSpec):
                 assert spec.channels == c
                 l = 1
@@ -130,14 +180,15 @@ class CNN1DSpec:
     @property
     def total_weights(self) -> int:
         return sum(
-            s.weights for s in self.layers if isinstance(s, (Conv1DSpec, FCSpec))
+            s.weights for s in self.layers
+            if isinstance(s, (Conv1DSpec, ResBlock1DSpec, FCSpec))
         )
 
     @property
     def total_macs(self) -> int:
         macs, l = 0, self.in_len
         for spec in self.layers:
-            if isinstance(spec, Conv1DSpec):
+            if isinstance(spec, (Conv1DSpec, ResBlock1DSpec)):
                 macs += spec.macs(l)
                 l = spec.out_len(l)
             elif isinstance(spec, PoolSpec):

@@ -28,7 +28,14 @@ import dataclasses
 import numpy as np
 
 from repro.core import isa, macro
-from repro.core.cnn_spec import CNN1DSpec, Conv1DSpec, FCSpec, GAPSpec, PoolSpec
+from repro.core.cnn_spec import (
+    CNN1DSpec,
+    Conv1DSpec,
+    FCSpec,
+    GAPSpec,
+    PoolSpec,
+    ResBlock1DSpec,
+)
 
 
 @dataclasses.dataclass
@@ -134,6 +141,18 @@ class _Grid:
         return None
 
 
+def refuse_residual(spec: CNN1DSpec, who: str) -> None:
+    """Raise a ValueError naming the first residual block of ``spec``: the
+    ISA has no join of two branches (``repro.stream`` runs such networks
+    on the jnp hop backend)."""
+    for lspec in spec.layers:
+        if isinstance(lspec, ResBlock1DSpec):
+            raise ValueError(
+                f"{who} does not take residual blocks ({lspec.name}); "
+                "stream the network with StreamScheduler(backend='jnp')"
+            )
+
+
 def compile_model(
     spec: CNN1DSpec,
     weights: dict[int, np.ndarray],
@@ -147,6 +166,7 @@ def compile_model(
     thresholds[layer_idx]: (thr, flip) arrays of length Cout (SA offsets).
     """
     rowsplit_hints = rowsplit_hints or {}
+    refuse_residual(spec, "the ISA compiler")
     shapes = spec.trace_shapes()
 
     # ---- 1. chunk ----------------------------------------------------------

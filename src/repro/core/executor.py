@@ -28,7 +28,12 @@ import numpy as np
 
 from repro.core import isa, macro, pwb
 from repro.core.cnn_spec import Conv1DSpec, FCSpec
-from repro.core.compiler import Chunk, CompiledProgram, LayerBinding
+from repro.core.compiler import (
+    Chunk,
+    CompiledProgram,
+    LayerBinding,
+    refuse_residual,
+)
 from repro.core.energy import EnergyLedger, EnergyParams
 from repro.core.pingpong import FmapRef, PingPongSRAM
 from repro.kernels import ref
@@ -54,6 +59,7 @@ class Executor:
         params: EnergyParams | None = None,
         fuse_pool: bool = True,
     ) -> None:
+        refuse_residual(prog.spec, "the ISA executor")
         self.prog = prog
         self.params = params or EnergyParams()
         self.fuse_pool = fuse_pool

@@ -98,6 +98,9 @@ from repro.runtime.pool import SlotPool
 # historical name is re-exported because benches/tests import it from here
 from repro.runtime.pool import next_pow2 as _next_pow2  # noqa: F401
 from repro.stream.state import (
+    ConvStage,
+    JoinStage,
+    PoolStage,
     RingArena,
     StreamPlan,
     StreamState,
@@ -105,6 +108,9 @@ from repro.stream.state import (
     prime_batch,
     quantize_pcm,
     remap_rows,
+    stage_gain,
+    stage_threshold,
+    stage_weight,
 )
 from repro.utils.logging import get_logger
 
@@ -136,14 +142,17 @@ def prepared_model_params(plan: StreamPlan, weights, thresholds) -> dict:
     """Device-ready per-stage params for one model variant, memoized by
     ``(id(weights), id(thresholds), plan geometry)``.
 
-    Returns ``{"w", "thr", "flip", "fc_w", "fc_thr", "fc_flip"}`` —
-    exactly the arrays ``_BatchedModel`` loads — so pool admission,
-    scheduler reconstruction, and grow/shrink cycles over an unchanged
-    variant never re-run the conversion (or the wp/wn packing derived
-    from it downstream).
+    Returns ``{"w", "thr", "flip", "gain", "fc_w", "fc_thr", "fc_flip"}``
+    — exactly the arrays ``_BatchedModel`` loads; ``w``/``thr``/``flip``/
+    ``gain`` hold one entry per plan stage, None where the stage has no
+    such parameter (a pool; a block's raw second conv has no SA; a join's
+    ``w`` is its 1x1 projection, None for an identity shortcut).  Pool
+    admission, scheduler reconstruction, and grow/shrink cycles over an
+    unchanged variant never re-run the conversion (or the wp/wn packing
+    derived from it downstream).
     """
     global _param_cache_hits, _param_cache_misses
-    key = (id(weights), id(thresholds), plan.convs, plan.fcs)
+    key = (id(weights), id(thresholds), plan.stages, plan.fcs)
     hit = _PARAM_CACHE.get(key)
     if (hit is not None and hit["weights"] is weights
             and hit["thresholds"] is thresholds):
@@ -151,19 +160,27 @@ def prepared_model_params(plan: StreamPlan, weights, thresholds) -> dict:
         _PARAM_CACHE.move_to_end(key)
         return hit
     _param_cache_misses += 1
-    stages = plan.convs
+
+    def weight(st):
+        w = stage_weight(weights, st)
+        if w is None:
+            return None
+        if isinstance(st, ConvStage):
+            w = w.reshape(st.k, st.cin, st.cout)
+        return jnp.asarray(w, jnp.int32)
+
+    sa = [stage_threshold(thresholds, st) for st in plan.stages]
     prep = {
         # strong refs pin the keyed ids for the cache's lifetime
         "weights": weights,
         "thresholds": thresholds,
-        "w": [
-            jnp.asarray(weights[st.layer_idx].reshape(st.k, st.cin, st.cout),
-                        jnp.int32) for st in stages
-        ],
-        "thr": [jnp.asarray(thresholds[st.layer_idx][0], jnp.float32)
-                for st in stages],
-        "flip": [jnp.asarray(thresholds[st.layer_idx][1], bool)
-                 for st in stages],
+        "w": [weight(st) for st in plan.stages],
+        "thr": [None if t is None else jnp.asarray(t[0], jnp.float32)
+                for t in sa],
+        "flip": [None if t is None else jnp.asarray(t[1], bool) for t in sa],
+        "gain": [jnp.asarray(stage_gain(thresholds, st), jnp.int32)
+                 if isinstance(st, JoinStage) else None
+                 for st in plan.stages],
         "fc_w": tuple(jnp.asarray(weights[st.layer_idx], jnp.int32)
                       for st in plan.fcs),
         "fc_thr": tuple(jnp.asarray(thresholds[st.layer_idx][0],
@@ -377,6 +394,12 @@ class _BatchedModel:
                  donate: bool = False, pool_size: int | None = None,
                  tenant_block: int | None = None,
                  params: dict | None = None) -> None:
+        if backend != "jnp" and not plan.plain:
+            raise ValueError(
+                f"the {backend!r} hop backend takes convs with fused pools "
+                "only; a network with residual blocks or standalone pools "
+                "runs on backend='jnp'"
+            )
         self.plan = plan
         self.backend = backend
         self.interpret = interpret
@@ -389,6 +412,7 @@ class _BatchedModel:
         self._w = list(prep["w"])
         self._thr = list(prep["thr"])
         self._flip = list(prep["flip"])
+        self._gain = list(prep["gain"])
         self._fc_w = tuple(prep["fc_w"])
         self._fc_thr = tuple(prep["fc_thr"])
         self._fc_flip = tuple(prep["fc_flip"])
@@ -399,29 +423,12 @@ class _BatchedModel:
             # at max_models from construction — admitting a variant is a
             # row write (set_model_row), never a retrace.
             stack = lambda t: jnp.stack([t] * pool_size)  # noqa: E731
-            self._w = [stack(w) for w in self._w]
-            self._thr = [stack(t) for t in self._thr]
-            self._flip = [stack(f) for f in self._flip]
-            self._fc_w = tuple(stack(w) for w in self._fc_w)
-            self._fc_thr = tuple(stack(t) for t in self._fc_thr)
-            self._fc_flip = tuple(stack(f) for f in self._fc_flip)
-        # offset fold (per tenant row when pooled)
-        self._wsum = [
-            jnp.sum(w, axis=(1, 2) if pool_size is not None else (0, 1))
-            for w in self._w
-        ]
+            self._map_params(stack)
         if mesh is not None:
             # one macro, many shards: weights live replicated on every
             # device (the whole (K, ...) pool replicates exactly like
             # the single weight set); only per-stream state is sharded
-            put = self._rep_put
-            self._w = [put(w) for w in self._w]
-            self._thr = [put(t) for t in self._thr]
-            self._flip = [put(f) for f in self._flip]
-            self._wsum = [put(w) for w in self._wsum]
-            self._fc_w = tuple(put(w) for w in self._fc_w)
-            self._fc_thr = tuple(put(t) for t in self._fc_thr)
-            self._fc_flip = tuple(put(f) for f in self._fc_flip)
+            self._map_params(self._rep_put)
             self._baxes = _mesh_data_axes(mesh)
         # with donate=True the slot-state operands (tails, pendings, gap)
         # are donated to each hop: XLA aliases the output state onto the
@@ -437,6 +444,18 @@ class _BatchedModel:
             donate_argnums=(2, 3, 4) if donate else (),
         )
         self.finalize = jax.jit(self._finalize)
+
+    def _map_params(self, fn) -> None:
+        """Apply ``fn`` to every parameter array (the stage lists' None
+        entries stay None)."""
+        opt = lambda t: None if t is None else fn(t)  # noqa: E731
+        self._w = [opt(w) for w in self._w]
+        self._thr = [opt(t) for t in self._thr]
+        self._flip = [opt(f) for f in self._flip]
+        self._gain = [opt(g) for g in self._gain]
+        self._fc_w = tuple(fn(w) for w in self._fc_w)
+        self._fc_thr = tuple(fn(t) for t in self._fc_thr)
+        self._fc_flip = tuple(fn(f) for f in self._fc_flip)
 
     def _rep_put(self, t: jax.Array) -> jax.Array:
         """Replicate a weight array across the mesh (identity without)."""
@@ -468,11 +487,13 @@ class _BatchedModel:
         assert 0 <= idx < self.pool_size, (idx, self.pool_size)
         prep = prepared_model_params(self.plan, weights, thresholds)
         put = self._rep_put
-        for i in range(len(self.plan.convs)):
-            self._w[i] = put(self._w[i].at[idx].set(prep["w"][i]))
-            self._thr[i] = put(self._thr[i].at[idx].set(prep["thr"][i]))
-            self._flip[i] = put(self._flip[i].at[idx].set(prep["flip"][i]))
-            self._wsum[i] = put(jnp.sum(self._w[i], axis=(1, 2)))
+
+        def row(stack, v):
+            return None if stack is None else put(stack.at[idx].set(v))
+
+        for name in ("_w", "_thr", "_flip", "_gain"):
+            setattr(self, name, [row(t, v) for t, v in
+                                 zip(getattr(self, name), prep[name[1:]])])
         self._fc_w = tuple(
             put(w.at[idx].set(v)) for w, v in zip(self._fc_w, prep["fc_w"])
         )
@@ -520,7 +541,7 @@ class _BatchedModel:
         With ``model_idx`` the weights are the pooled (K, ...) stacks —
         one gather per tenant block inside the kernel, one per-row
         gather on the jnp path."""
-        st = self.plan.convs[i]
+        st = self.plan.stages[i]
         w = self._w[i]
         if st.in_bits > 1:
             # bit-serial first layer; offset folds out after accumulation.
@@ -576,6 +597,48 @@ class _BatchedModel:
         ge = raw.astype(jnp.float32) >= thr
         return jnp.where(flip, ~ge, ge).astype(jnp.int32)
 
+    def _join(self, i: int, ring: jax.Array, x: jax.Array | None,
+              s: jax.Array, model_idx: jax.Array | None = None
+              ) -> tuple[jax.Array, jax.Array]:
+        """A residual join: ``SA2(s + g * r)`` over the ``n`` frames of
+        ``s``, ``r`` the oldest ``n`` block inputs of the skip ring ++
+        ``x`` (this hop's block input), projected where the block has a
+        projection.  Returns the joined frames and the ring left over."""
+        st = self.plan.stages[i]
+        n = s.shape[1]
+        xs = ring if x is None else jnp.concatenate([ring, x], axis=1)
+        r = xs[:, :n]
+        if st.proj:
+            w = self._w[i]
+            if model_idx is not None:
+                wg, tb = self._block_gather(w, model_idx)
+                rg = r.reshape(-1, tb, *r.shape[1:])
+                r = jnp.einsum("gtnc,gco->gtno", rg, wg).reshape(
+                    r.shape[0], n, -1)
+            else:
+                r = jnp.einsum("bnc,co->bno", r, w)
+        g = (self._gain[i][model_idx][:, None, :] if model_idx is not None
+             else self._gain[i][None, None, :])
+        return self._sa(i, s + g * r, model_idx), xs[:, n:]
+
+    def _pool(self, i: int, pending: jax.Array, y: jax.Array, n_out: int
+              ) -> tuple[jax.Array, jax.Array]:
+        """A standalone pool stage: ``n_out`` OR-pooled frames of
+        ``pending ++ y`` and the frames left pending."""
+        return self._or_pool(pending, y, n_out, self.plan.stages[i].pool)
+
+    @staticmethod
+    def _or_pool(pending: jax.Array, y: jax.Array, n_out: int, pool: int
+                 ) -> tuple[jax.Array, jax.Array]:
+        """OR-pool ``pending ++ y`` into ``n_out`` frames at absolute
+        alignment; returns them and the frames left pending."""
+        frames = jnp.concatenate([pending, y], axis=1) \
+            if pending.shape[1] else y
+        used = n_out * pool
+        pooled = frames[:, :used].reshape(
+            frames.shape[0], n_out, pool, frames.shape[2]).max(axis=2)
+        return pooled, frames[:, used:]
+
     # -- the hop -------------------------------------------------------------
 
     def _step(self, audio, mask, tails, pendings, gap, model_idx=None,
@@ -586,7 +649,7 @@ class _BatchedModel:
         slot's tenant variant — constant per tenant block by placement,
         so the launch count stays K-independent."""
         plan = self.plan
-        stages = plan.convs
+        stages = plan.stages
         if self.backend == "megakernel":
             # the whole cascade — bit-serial layer 0, SA, pool phases,
             # tail/pending carry, GAP, mask merge, and (on emit) the ghost
@@ -613,26 +676,30 @@ class _BatchedModel:
             post = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
             return (*state, logits, post)
         cur = audio.reshape(audio.shape[0], plan.hop_samples, stages[0].cin)
-        new_tails, new_pendings = [], []
+        new_tails, new_pendings = list(tails), list(pendings)
+        ins = []  # frames entering each stage (a join reads its block's)
         for i, st in enumerate(stages):
+            ins.append(cur)
+            if isinstance(st, JoinStage):
+                with jax.named_scope(f"res{i}"):
+                    cur, new_tails[i] = self._join(
+                        i, tails[i], ins[st.src], cur, model_idx)
+                continue
+            if isinstance(st, PoolStage):
+                with jax.named_scope(f"pool{i}"):
+                    cur, new_pendings[i] = self._pool(
+                        i, pendings[i], cur, st.n_out)
+                continue
             with jax.named_scope(f"conv{i}"):
                 window = jnp.concatenate([tails[i], cur], axis=1)
-                raw = self._conv_raw(i, window, st.n_conv, model_idx)
-                new_tails.append(window[:, st.n_conv * st.stride :])
-                y = self._sa(i, raw, model_idx)
+                y = self._conv_raw(i, window, st.n_conv, model_idx)
+                new_tails[i] = window[:, st.n_conv * st.stride :]
+                if st.sa:
+                    y = self._sa(i, y, model_idx)
                 if st.pool > 1:
-                    frames = (
-                        jnp.concatenate([pendings[i], y], axis=1)
-                        if st.phase else y
-                    )
-                    used = st.n_out * st.pool
-                    pooled = frames[:, :used].reshape(
-                        frames.shape[0], st.n_out, st.pool, st.cout
-                    ).max(axis=2)
-                    new_pendings.append(frames[:, used:])
-                    cur = pooled
+                    cur, new_pendings[i] = self._or_pool(
+                        pendings[i], y, st.n_out, st.pool)
                 else:
-                    new_pendings.append(pendings[i])
                     cur = y
         # saturate at the 8-bit PWB counter ceiling inside the step: the
         # accumulation is monotone non-negative, so incremental clamping
@@ -643,7 +710,7 @@ class _BatchedModel:
 
         m3 = mask[:, None, None]
         new_tails = [
-            self._pin(jnp.where(m3, nt, t))
+            self._pin(jnp.where(m3, nt, t)) if t.shape[1] else t
             for nt, t in zip(new_tails, tails)
         ]
         new_pendings = [
@@ -677,19 +744,33 @@ class _BatchedModel:
                 tuple(tails), tuple(pendings), gap,
                 tuple(self._w), tuple(self._thr), tuple(self._flip),
                 self._fc_w, self._fc_thr, self._fc_flip, model_idx,
-                mesh=self.mesh, stages=self.plan.convs,
+                mesh=self.mesh, stages=self.plan.stages,
                 fc_raw=self._fc_raw, bb=self._bb(gap.shape[0]),
                 interpret=self.interpret,
             ))
             post = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
             return logits, post
-        stages = self.plan.convs
+        stages = self.plan.stages
         B = gap.shape[0]
-        cur = None  # frames flowing down from the layer above's flush
+        cur = None  # frames flowing down from the stage above's flush
+        ins = []    # the flush frames entering each stage (None: none)
         with jax.named_scope("ghost_flush"):
             for i, st in enumerate(stages):
+                ins.append(cur if st.flush_in else None)
+                if isinstance(st, JoinStage):
+                    # the ring's real frames meet the flushed s positions
+                    with jax.named_scope(f"res{i}"):
+                        cur, _ = self._join(i, tails[i], ins[st.src], cur,
+                                            model_idx)
+                    continue
+                if isinstance(st, PoolStage):
+                    with jax.named_scope(f"pool{i}"):
+                        y = cur if st.flush_in else jnp.zeros(
+                            (B, 0, st.channels), jnp.int32)
+                        cur, _ = self._pool(i, pendings[i], y, st.flush_out)
+                    continue
                 pieces = [tails[i]]
-                if cur is not None and st.flush_in:
+                if st.flush_in:
                     pieces.append(cur)
                 if st.pad:
                     pad_val = st.in_offset if st.in_bits > 1 else 0
@@ -700,15 +781,13 @@ class _BatchedModel:
                     )
                 if st.flush_conv > 0:
                     window = jnp.concatenate(pieces, axis=1)
-                    y = self._sa(i, self._conv_raw(i, window, st.flush_conv,
-                                                   model_idx), model_idx)
+                    y = self._conv_raw(i, window, st.flush_conv, model_idx)
+                    if st.sa:
+                        y = self._sa(i, y, model_idx)
                 else:
                     y = jnp.zeros((B, 0, st.cout), jnp.int32)
-                frames = jnp.concatenate([pendings[i], y], axis=1)
-                used = st.flush_out * st.pool  # drop-remainder (ref_maxpool1d)
-                cur = frames[:, :used].reshape(
-                    B, st.flush_out, st.pool, st.cout
-                ).max(axis=2)
+                # drop-remainder (ref_maxpool1d)
+                cur, _ = self._or_pool(pendings[i], y, st.flush_out, st.pool)
             gap_f = jnp.minimum(gap + cur.sum(axis=1, dtype=jnp.int32), 255)
         with jax.named_scope("classifier"):
             logits = self._pin(self._classifier(gap_f, model_idx))
@@ -761,9 +840,9 @@ class _BatchedModel:
         # first layer is a single plane-accumulating launch since PR 8),
         # plus — on emit — the ghost flush's conv launches and the fused
         # classifier tail
-        n = len(self.plan.convs)
+        n = len(self.plan.stages)
         if emit:
-            n += sum(1 for st in self.plan.convs if st.flush_conv > 0) + 1
+            n += sum(1 for st in self.plan.stages if st.flush_conv > 0) + 1
         return n
 
 
@@ -819,7 +898,12 @@ class StreamScheduler:
         # controllable fake clock and compare their traces structurally
         self._clock = clock
         self.plan = plan_stream(spec, hop_frames=hop_frames)
-        self.weights = {k: np.asarray(v) for k, v in weights.items()}
+        # a residual block's entry is a dict of its arrays
+        self.weights = {
+            k: ({kk: np.asarray(vv) for kk, vv in v.items()}
+                if isinstance(v, dict) else np.asarray(v))
+            for k, v in weights.items()
+        }
         self.thresholds = thresholds
         self.mesh = mesh
         if mesh is not None:
@@ -878,13 +962,16 @@ class StreamScheduler:
         cap0 = self._slots.capacity
         # batched state lives device-resident between hops; host copies are
         # made only on join/leave or fallback peeks — never the hot loop
+        # per stage: a conv's receptive-field tail or a join's skip ring,
+        # and the frames pending in a pool window (zero-length where the
+        # stage has none)
         self._tails = [
-            self._shard(jnp.zeros((cap0, st.tail, st.cin), jnp.int32))
-            for st in self.plan.convs
+            self._shard(jnp.zeros((cap0, st.tail, st.tail_ch), jnp.int32))
+            for st in self.plan.stages
         ]
         self._pendings = [
-            self._shard(jnp.zeros((cap0, st.phase, st.cout), jnp.int32))
-            for st in self.plan.convs
+            self._shard(jnp.zeros((cap0, st.phase, st.pend_ch), jnp.int32))
+            for st in self.plan.stages
         ]
         self._gap = self._shard(
             jnp.zeros((cap0, self.plan.gap_channels), jnp.int32)
@@ -989,7 +1076,7 @@ class StreamScheduler:
         self._gap = gap
 
     def slot_axes(self):
-        n = len(self.plan.convs)
+        n = len(self.plan.stages)
         return ((0,) * n, (0,) * n, 0)
 
     def shard(self, x, axis: int = 0):
@@ -1260,10 +1347,11 @@ class StreamScheduler:
                 steady = prime_batch(self.plan, w, t, samples[pos])
                 gslots = slots[pos]
                 jslots = jnp.asarray(gslots)
-                for i in range(len(self.plan.convs)):
-                    self._tails[i] = self._tails[i].at[jslots].set(
-                        jnp.asarray(steady["tails"][i])
-                    )
+                for i in range(len(self.plan.stages)):
+                    if self._tails[i].shape[1]:
+                        self._tails[i] = self._tails[i].at[jslots].set(
+                            jnp.asarray(steady["tails"][i])
+                        )
                     if self._pendings[i].shape[1]:
                         self._pendings[i] = self._pendings[i].at[jslots].set(
                             jnp.asarray(steady["pendings"][i])
@@ -1283,8 +1371,9 @@ class StreamScheduler:
         self.obs.events.emit("mass_join", n=len(sids))
 
     def _clear_slot(self, slot: int) -> None:
-        for i in range(len(self.plan.convs)):
-            self._tails[i] = self._tails[i].at[slot].set(0)
+        for i in range(len(self.plan.stages)):
+            if self._tails[i].shape[1]:
+                self._tails[i] = self._tails[i].at[slot].set(0)
             if self._pendings[i].shape[1]:
                 self._pendings[i] = self._pendings[i].at[slot].set(0)
         self._gap = self._gap.at[slot].set(0)
@@ -1572,10 +1661,10 @@ class StreamScheduler:
             args = (
                 z((cap, plan.hop_samples), jnp.int32),      # pack_hops dtype
                 z((cap,), bool),
-                tuple(z((cap, st.tail, st.cin), jnp.int32)
-                      for st in plan.convs),
-                tuple(z((cap, st.phase, st.cout), jnp.int32)
-                      for st in plan.convs),
+                tuple(z((cap, st.tail, st.tail_ch), jnp.int32)
+                      for st in plan.stages),
+                tuple(z((cap, st.phase, st.pend_ch), jnp.int32)
+                      for st in plan.stages),
                 z((cap, plan.gap_channels), jnp.int32),
             )
             if self._pool is not None:

@@ -25,7 +25,10 @@ exact fallback.
 Bit-exactness contract with core/executor.py (verified in test_stream.py):
   * layer-0 spatial padding uses the offset code (ref_bitserial_conv1d)
   * binary layers pad with zeros
-  * fused max-pool = OR over non-overlapping windows, remainder dropped
+  * max-pool (fused or standalone) = OR over non-overlapping windows,
+    remainder dropped
+  * a residual join adds the second conv's raw counts to ``g`` times the
+    shortcut before its SA; the flush joins every frame left in the ring
   * GAP counts saturate at 255 (8-bit PWB counters)
   * fc layers run on the saturated counts; final layer emits raw logits
 """
@@ -33,10 +36,19 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
+from numpy.lib.stride_tricks import sliding_window_view
 
-from repro.core.cnn_spec import CNN1DSpec, Conv1DSpec, FCSpec, GAPSpec
+from repro.core.cnn_spec import (
+    CNN1DSpec,
+    Conv1DSpec,
+    FCSpec,
+    GAPSpec,
+    PoolSpec,
+    ResBlock1DSpec,
+)
 # SlotPlacement and the host remap contract moved to the generic runtime
 # package (repro.runtime) when the slot-pool plane was extracted; they are
 # re-exported here because the streaming API grew up around this module
@@ -474,6 +486,14 @@ class RingArena:
 # ---------------------------------------------------------------------------
 # Stream plan: static per-hop geometry
 # ---------------------------------------------------------------------------
+#
+# A plan is a flat list of *stages*, each a streaming primitive with its own
+# per-slot state: a tail (input frames carried from hop to hop) and a
+# pending window (frames waiting for a whole pool window).  A conv layer is
+# one ConvStage (its max-pool fused), a standalone pool one PoolStage, and a
+# residual block three stages: its two convs (the second emitting raw
+# counts) and the JoinStage whose tail is the block's *skip ring* -- the
+# block inputs still waiting for the second conv to reach their position.
 
 @dataclasses.dataclass(frozen=True)
 class ConvStage:
@@ -505,6 +525,85 @@ class ConvStage:
     flush_in: int    # extra frames received from the layer above at flush
     flush_conv: int  # extra conv positions a flush emits (tail + right pad)
     flush_out: int   # extra pooled frames a flush emits (remainder dropped)
+    sa: bool = True  # SA-binarized output; False: raw counts (a block's
+    #                  second conv, whose counts meet the shortcut first)
+    wkey: str | None = None  # weights[layer_idx][wkey] (None: the entry)
+    tkey: str | None = None  # thresholds[layer_idx][tkey] (None: the entry)
+
+    @property
+    def tail_ch(self) -> int:
+        return self.cin
+
+    @property
+    def pend_ch(self) -> int:
+        return self.cout
+
+
+@dataclasses.dataclass(frozen=True)
+class PoolStage:
+    """A standalone max-pool (the paper's independent pooling write-back):
+    OR over non-overlapping windows at absolute alignment, remainder
+    dropped at the end of the stream, exactly as a fused pool."""
+
+    layer_idx: int
+    name: str
+    pool: int
+    channels: int
+    phase: int       # steady-state frames pending in the window
+    n_in: int
+    n_out: int
+    flush_in: int
+    flush_out: int
+
+    tail = 0  # no receptive field: the pending window is the whole state
+
+    @property
+    def tail_ch(self) -> int:
+        return self.channels
+
+    @property
+    def pend_ch(self) -> int:
+        return self.channels
+
+
+@dataclasses.dataclass(frozen=True)
+class JoinStage:
+    """A residual block's join, ``y = SA2(s + g * r)``: ``s`` the raw counts
+    of the block's second conv, ``r`` the block input ``x`` (identity) or
+    ``x @ Wp`` (projection), ``g`` the per-channel integer gain.  ``x``
+    waits in the skip ring (``tail`` frames: the two convs' lag) until
+    ``s`` reaches its position."""
+
+    layer_idx: int
+    name: str
+    src: int         # stage index of the block's first conv (input x)
+    cin: int
+    cout: int
+    proj: bool
+    tail: int        # skip ring depth (frames of x)
+    n_in: int        # s frames joined per hop (= x frames per hop)
+    flush_in: int    # s frames a flush brings (= tail + x frames it brings)
+
+    phase = 0
+
+    @property
+    def n_out(self) -> int:
+        return self.n_in
+
+    @property
+    def flush_out(self) -> int:
+        return self.flush_in
+
+    @property
+    def tail_ch(self) -> int:
+        return self.cin
+
+    @property
+    def pend_ch(self) -> int:
+        return self.cout
+
+
+Stage = ConvStage | PoolStage | JoinStage
 
 
 @dataclasses.dataclass(frozen=True)
@@ -519,45 +618,132 @@ class FCStage:
 
 @dataclasses.dataclass(frozen=True)
 class StreamPlan:
-    """Static schedule for one model: hop/prime sizes + per-layer geometry."""
+    """Static schedule for one model: hop/prime sizes + per-stage geometry."""
 
     spec: CNN1DSpec
     hop_samples: int
     prime_samples: int
-    convs: tuple[ConvStage, ...]
+    stages: tuple[Stage, ...]
     fcs: tuple[FCStage, ...]
     gap_channels: int
 
     @property
+    def convs(self) -> tuple[ConvStage, ...]:
+        return tuple(st for st in self.stages if isinstance(st, ConvStage))
+
+    @property
+    def plain(self) -> bool:
+        """Convs with fused pools only: the topology the Pallas kernels
+        and the hop megakernel take."""
+        return all(isinstance(st, ConvStage) and st.sa for st in self.stages)
+
+    @property
     def frames_per_hop(self) -> int:
-        return self.convs[-1].n_out
+        return self.stages[-1].n_out
 
     @property
     def samples_per_frame(self) -> int:
         return self.hop_samples // self.frames_per_hop
 
     def macs_per_hop(self) -> int:
-        """Logical MACs of one steady-state hop (conv cascade only)."""
-        return sum(c.n_conv * c.k * c.cin * c.cout for c in self.convs)
+        """Logical MACs of one steady-state hop (convs and projections)."""
+        macs = sum(c.n_conv * c.k * c.cin * c.cout for c in self.convs)
+        return macs + sum(j.n_in * j.cin * j.cout for j in self.stages
+                          if isinstance(j, JoinStage) and j.proj)
 
     def fc_macs(self) -> int:
         return sum(f.cin * f.cout for f in self.fcs)
 
 
-def _conv_layers(spec: CNN1DSpec) -> tuple[list[tuple[int, Conv1DSpec]],
-                                           int, list[tuple[int, FCSpec]]]:
-    """Split the spec into conv prefix / GAP / fc suffix (the streamable
-    topology); anything else is rejected."""
-    convs: list[tuple[int, Conv1DSpec]] = []
+def stage_weight(weights, st: Stage) -> np.ndarray | None:
+    """The stage's weights from a model's ``{layer: array}`` mapping (a
+    residual block's entry is ``{"w1", "w2"[, "wp"]}``); None for a pool
+    and an identity join."""
+    if isinstance(st, PoolStage) or (isinstance(st, JoinStage)
+                                     and not st.proj):
+        return None
+    w = weights[st.layer_idx]
+    key = "wp" if isinstance(st, JoinStage) else st.wkey
+    return np.asarray(w if key is None else w[key])
+
+
+def stage_threshold(thresholds, st: Stage):
+    """The stage's SA ``(thr, flip)`` (a block's entry is ``{"sa1",
+    "sa2", "gain"}``); None where the stage has no SA."""
+    if isinstance(st, PoolStage) or (isinstance(st, ConvStage)
+                                     and not st.sa):
+        return None
+    t = thresholds[st.layer_idx]
+    key = "sa2" if isinstance(st, JoinStage) else st.tkey
+    return t if key is None else t[key]
+
+
+def stage_gain(thresholds, st: JoinStage) -> np.ndarray:
+    """A join's per-channel integer shortcut gain ``g``."""
+    return np.asarray(thresholds[st.layer_idx]["gain"], np.int64)
+
+
+@dataclasses.dataclass(frozen=True)
+class _Prim:
+    """One streaming primitive of the spec, before its steady geometry."""
+
+    kind: str            # "conv" | "pool" | "join"
+    li: int
+    name: str
+    cin: int
+    cout: int
+    k: int = 1
+    stride: int = 1
+    pad: int = 0
+    pool: int = 1
+    in_bits: int = 1
+    in_offset: int = 0
+    sa: bool = True
+    wkey: str | None = None
+    tkey: str | None = None
+    src: int = -1        # join: index of the block's first conv
+    proj: bool = False
+
+
+def _primitives(spec: CNN1DSpec) -> tuple[list[_Prim], list[tuple[int, FCSpec]]]:
+    """Split the spec into the streamed prefix (convs, standalone pools,
+    residual blocks, as primitives) / GAP / fc suffix — the streamable
+    topology; anything else is rejected."""
+    prims: list[_Prim] = []
     fcs: list[tuple[int, FCSpec]] = []
     gap_at = None
+    c = spec.in_channels
     for li, lspec in enumerate(spec.layers):
+        if isinstance(lspec, (Conv1DSpec, PoolSpec, ResBlock1DSpec)) \
+                and gap_at is not None:
+            raise ValueError(f"{lspec.name} after GAP is not streamable")
         if isinstance(lspec, Conv1DSpec):
-            if gap_at is not None:
-                raise ValueError("conv after GAP is not streamable")
             if lspec.out_raw:
                 raise ValueError(f"{lspec.name}: raw-output conv mid-stream")
-            convs.append((li, lspec))
+            prims.append(_Prim(
+                "conv", li, lspec.name, lspec.cin, lspec.cout, lspec.k,
+                lspec.stride, lspec.pad, lspec.pool, lspec.in_bits,
+                lspec.in_offset))
+            c = lspec.cout
+        elif isinstance(lspec, PoolSpec):
+            if not prims:
+                raise ValueError(f"{lspec.name}: a pool cannot read the "
+                                 "audio")
+            prims.append(_Prim("pool", li, lspec.name, c, c,
+                               pool=lspec.pool))
+        elif isinstance(lspec, ResBlock1DSpec):
+            if not prims:
+                raise ValueError(f"{lspec.name}: a residual block cannot "
+                                 "read the audio")
+            L = lspec
+            src = len(prims)
+            prims.append(_Prim("conv", li, f"{L.name}.conv1", L.cin, L.cout,
+                               L.k, 1, L.pad, wkey="w1", tkey="sa1"))
+            prims.append(_Prim("conv", li, f"{L.name}.conv2", L.cout,
+                               L.cout, L.k, 1, L.pad, sa=False, wkey="w2"))
+            prims.append(_Prim("join", li, f"{L.name}.join", L.cin, L.cout,
+                               src=src, proj=L.proj))
+            c = L.cout
         elif isinstance(lspec, GAPSpec):
             if gap_at is not None:
                 raise ValueError("multiple GAP layers")
@@ -568,42 +754,83 @@ def _conv_layers(spec: CNN1DSpec) -> tuple[list[tuple[int, Conv1DSpec]],
             fcs.append((li, lspec))
         else:
             raise ValueError(f"layer {li} ({type(lspec).__name__}) not streamable")
-    if not convs or gap_at is None or not fcs:
-        raise ValueError("streamable spec needs convs -> GAP -> FCs")
-    return convs, gap_at, fcs
+    if not prims or prims[0].kind != "conv" or gap_at is None or not fcs:
+        raise ValueError("streamable spec needs a conv first, then convs, "
+                         "pools and residual blocks -> GAP -> FCs")
+    return prims, fcs
 
 
-def _simulate_counts(convs: list[tuple[int, Conv1DSpec]], pushes: list[int]
+def _simulate_counts(prims: list[_Prim], pushes: list[int]
                      ) -> tuple[list[int], list[int], list[list[int]]]:
     """Feed ``pushes`` chunks through the count-level model.
 
-    Returns (tail lengths, pool phases, per-push emissions per layer) after
-    all pushes; tails include the layer's left pad on the first push.
+    Returns (tail lengths, pool phases, per-push frames each stage takes
+    up) after all pushes: conv positions for a conv, frames in for a pool,
+    ``s`` frames joined for a join.  Conv tails include the layer's left
+    pad on the first push; a join's tail is its skip ring.
     """
-    fed = [0] * len(convs)       # frames of the *padded* stream received
-    emitted = [0] * len(convs)   # conv positions emitted so far
-    pooled = [0] * len(convs)    # pooled frames emitted so far
+    n = len(prims)
+    fed = [0] * n        # frames received (a conv's padded stream; a join's x)
+    emitted = [0] * n    # conv positions / s frames joined
+    pooled = [0] * n     # pooled frames emitted so far
     per_push: list[list[int]] = []
     for push in pushes:
         cur = push
+        ins = [0] * n
         outs = []
-        for i, (_, L) in enumerate(convs):
-            if fed[i] == 0 and cur > 0:
-                fed[i] += L.pad  # left pad arrives with the first real frame
-            fed[i] += cur
-            total = max(0, (fed[i] - L.k) // L.stride + 1) if fed[i] >= L.k else 0
-            new_conv = total - emitted[i]
-            emitted[i] = total
-            new_pool = (emitted[i] // L.pool) - pooled[i]
+        for i, P in enumerate(prims):
+            ins[i] = cur
+            if P.kind == "conv":
+                if fed[i] == 0 and cur > 0:
+                    fed[i] += P.pad  # left pad arrives with the first frame
+                fed[i] += cur
+                total = (fed[i] - P.k) // P.stride + 1 if fed[i] >= P.k else 0
+                outs.append(total - emitted[i])
+                emitted[i] = total
+                new_pool = emitted[i] // P.pool - pooled[i]
+            elif P.kind == "pool":
+                fed[i] += cur
+                outs.append(cur)
+                new_pool = fed[i] // P.pool - pooled[i]
+            else:  # join: cur (s frames) passes through, x frames queue
+                fed[i] += ins[P.src]
+                emitted[i] += cur
+                outs.append(cur)
+                continue
             pooled[i] += new_pool
             cur = new_pool
-            outs.append(new_conv)
         per_push.append(outs)
-    tails = [
-        fed[i] - emitted[i] * L.stride for i, (_, L) in enumerate(convs)
-    ]
-    phases = [emitted[i] % L.pool for i, (_, L) in enumerate(convs)]
+    tails, phases = [], []
+    for i, P in enumerate(prims):
+        if P.kind == "conv":
+            tails.append(fed[i] - emitted[i] * P.stride)
+            phases.append(emitted[i] % P.pool)
+        elif P.kind == "pool":
+            tails.append(0)
+            phases.append(fed[i] % P.pool)
+        else:
+            tails.append(fed[i] - emitted[i])
+            phases.append(0)
     return tails, phases, per_push
+
+
+def _default_prime(prims: list[_Prim], unit: int) -> int:
+    """Smallest stride-aligned prefix after which every conv has seen a
+    full receptive field (fed >= k), i.e. every tail is at steady size."""
+    s0 = prims[0].stride
+    for p in range(s0, 64 * unit + 1, s0):
+        f, ok = p, True
+        for P in prims:
+            if P.kind == "conv":
+                if P.pad + f < P.k:
+                    ok = False
+                    break
+                f = ((P.pad + f - P.k) // P.stride + 1) // P.pool
+            elif P.kind == "pool":
+                f //= P.pool
+        if ok:
+            return p
+    raise ValueError("could not find a priming prefix")
 
 
 def plan_stream(
@@ -613,41 +840,24 @@ def plan_stream(
 ) -> StreamPlan:
     """Derive the static streaming schedule for ``spec``.
 
-    ``hop_frames``: final-layer frames per scheduler step; the hop size in
-    samples is ``hop_frames * prod(stride*pool)``.  ``prime_samples`` is the
-    warm-up prefix a stream must deliver before it enters the steady-state
-    batched step; the default is the smallest stride-aligned prefix that
-    fills every layer's tail.
+    ``hop_frames``: final-stage frames per scheduler step; the hop size in
+    samples is ``hop_frames * prod(stride*pool)`` over convs and standalone
+    pools.  ``prime_samples`` is the warm-up prefix a stream must deliver
+    before it enters the steady-state batched step; the default is the
+    smallest stride-aligned prefix that fills every conv's tail.
     """
-    convs, _, fcs = _conv_layers(spec)
+    prims, fcs = _primitives(spec)
     unit = 1
-    for _, L in convs:
-        unit *= L.stride * L.pool
+    for P in prims:
+        unit *= P.stride * P.pool
     hop = hop_frames * unit
-
-    s0 = convs[0][1].stride
     if prime_samples is None:
-        # smallest stride-aligned prefix after which every layer has seen a
-        # full receptive field (fed >= k), i.e. every tail is at steady size
-        prime_samples = 0
-        for p in range(s0, 64 * unit + 1, s0):
-            f, ok = p, True
-            for _, L in convs:
-                f_padded = L.pad + f
-                if f_padded < L.k:
-                    ok = False
-                    break
-                f = ((f_padded - L.k) // L.stride + 1) // L.pool
-            if ok:
-                prime_samples = p
-                break
-        if prime_samples == 0:
-            raise ValueError("could not find a priming prefix")
+        prime_samples = _default_prime(prims, unit)
 
     # verify steady state: two extra hops give identical emissions + tails
-    tails, phases, per = _simulate_counts(convs, [prime_samples, hop, hop])
+    tails, phases, per = _simulate_counts(prims, [prime_samples, hop, hop])
     tails2, phases2, per2 = _simulate_counts(
-        convs, [prime_samples, hop, hop, hop]
+        prims, [prime_samples, hop, hop, hop]
     )
     if per[1] != per[2] or per2[2] != per2[3] or tails != tails2 or phases != phases2:
         raise ValueError(
@@ -658,34 +868,60 @@ def plan_stream(
     # steady state (mirrors StreamState._advance_once with flush=True)
     flush_geom = []
     f_in = 0
-    for i, (_, L) in enumerate(convs):
-        avail = tails[i] + f_in + L.pad  # tail ++ upstream flush ++ right pad
-        f_conv = (avail - L.k) // L.stride + 1 if avail >= L.k else 0
-        f_out = (phases[i] + f_conv) // L.pool
+    for i, P in enumerate(prims):
+        if P.kind == "conv":
+            avail = tails[i] + f_in + P.pad  # tail ++ upstream ++ right pad
+            f_conv = (avail - P.k) // P.stride + 1 if avail >= P.k else 0
+            f_out = (phases[i] + f_conv) // P.pool
+        elif P.kind == "pool":
+            f_conv, f_out = 0, (phases[i] + f_in) // P.pool
+        else:
+            if f_in != tails[i] + flush_geom[P.src][0]:
+                raise ValueError(f"{P.name}: the flush leaves the skip "
+                                 "ring unjoined")
+            f_conv, f_out = 0, f_in
         flush_geom.append((f_in, f_conv, f_out))
         f_in = f_out
 
-    stages = []
+    stages: list[Stage] = []
     n_in = hop
-    for i, (li, L) in enumerate(convs):
-        n_conv = per[1][i]
-        if n_conv % L.pool:
-            raise ValueError(
-                f"{L.name}: {n_conv} conv frames/hop not divisible by pool "
-                f"{L.pool}; raise hop_frames"
-            )
-        stages.append(
-            ConvStage(
-                layer_idx=li, name=L.name, k=L.k, stride=L.stride, pad=L.pad,
-                pool=L.pool, cin=L.cin, cout=L.cout, in_bits=L.in_bits,
-                in_offset=L.in_offset, tail=tails[i], phase=phases[i],
-                n_in=n_in, n_conv=n_conv, n_out=n_conv // L.pool,
-                flush_in=flush_geom[i][0], flush_conv=flush_geom[i][1],
-                flush_out=flush_geom[i][2],
-            )
-        )
-        assert n_conv * L.stride == n_in, (L.name, n_conv, n_in)
-        n_in = n_conv // L.pool
+    for i, P in enumerate(prims):
+        if P.kind == "conv":
+            n_conv = per[1][i]
+            if n_conv % P.pool:
+                raise ValueError(
+                    f"{P.name}: {n_conv} conv frames/hop not divisible by "
+                    f"pool {P.pool}; raise hop_frames"
+                )
+            assert n_conv * P.stride == n_in, (P.name, n_conv, n_in)
+            stages.append(ConvStage(
+                layer_idx=P.li, name=P.name, k=P.k, stride=P.stride,
+                pad=P.pad, pool=P.pool, cin=P.cin, cout=P.cout,
+                in_bits=P.in_bits, in_offset=P.in_offset, tail=tails[i],
+                phase=phases[i], n_in=n_in, n_conv=n_conv,
+                n_out=n_conv // P.pool, flush_in=flush_geom[i][0],
+                flush_conv=flush_geom[i][1], flush_out=flush_geom[i][2],
+                sa=P.sa, wkey=P.wkey, tkey=P.tkey,
+            ))
+        elif P.kind == "pool":
+            if n_in % P.pool:
+                raise ValueError(
+                    f"{P.name}: {n_in} frames/hop not divisible by pool "
+                    f"{P.pool}; raise hop_frames"
+                )
+            stages.append(PoolStage(
+                layer_idx=P.li, name=P.name, pool=P.pool, channels=P.cin,
+                phase=phases[i], n_in=n_in, n_out=n_in // P.pool,
+                flush_in=flush_geom[i][0], flush_out=flush_geom[i][2],
+            ))
+        else:
+            assert per[1][i] == n_in == stages[P.src].n_in, P.name
+            stages.append(JoinStage(
+                layer_idx=P.li, name=P.name, src=P.src, cin=P.cin,
+                cout=P.cout, proj=P.proj, tail=tails[i], n_in=n_in,
+                flush_in=flush_geom[i][0],
+            ))
+        n_in = stages[-1].n_out
 
     fc_stages = tuple(
         FCStage(li, F.name, F.cin, F.cout, F.in_bits, F.out_raw)
@@ -695,9 +931,9 @@ def plan_stream(
         spec=spec,
         hop_samples=hop,
         prime_samples=prime_samples,
-        convs=tuple(stages),
+        stages=tuple(stages),
         fcs=fc_stages,
-        gap_channels=convs[-1][1].cout,
+        gap_channels=stages[-1].pend_ch,
     )
 
 
@@ -727,13 +963,28 @@ def _conv_raw(window: np.ndarray, w: np.ndarray, stage: ConvStage,
     return np.einsum("knc,kco->no", taps, w.astype(np.int64))
 
 
+def _join(x: np.ndarray, s: np.ndarray, st: JoinStage, weights,
+          thresholds) -> np.ndarray:
+    """``SA2(s + g * r)`` over (..., n, C) frames; ``x`` the block inputs
+    at the positions of ``s``."""
+    r = x.astype(np.float64)
+    if st.proj:  # float64 BLAS: exact, the counts are small integers
+        r = r @ stage_weight(weights, st).astype(np.float64)
+    z = s + stage_gain(thresholds, st) * r
+    thr, flip = stage_threshold(thresholds, st)
+    ge = z >= thr
+    return np.where(flip, ~ge, ge).astype(np.uint8)
+
+
 class StreamState:
     """One stream's incremental inference state (bit-exact numpy path).
 
     Handles arbitrary chunk sizes: warm-up, steady hops, end-of-stream flush
     with right padding, and non-destructive mid-stream peeks.  The jitted
     batched scheduler path is the steady-state specialization of exactly
-    this code.
+    this code.  Per stage it keeps a tail ring (a conv's receptive-field
+    tail, a join's skip ring) and a pending ring (frames waiting for a
+    whole pool window).
     """
 
     def __init__(
@@ -752,20 +1003,24 @@ class StreamState:
         self._max_chunk = slack  # advance() splits larger inputs
         self.hists: list[FrameRing] = []
         self.pendings: list[FrameRing] = []
-        for st in plan.convs:
-            cap = st.tail + 2 * st.pad + st.k + max(slack, st.n_in) + 1
-            self.hists.append(FrameRing(cap, st.cin, np.int32))
-            self.pendings.append(
-                FrameRing(st.pool + st.k + st.pad + max(slack, st.n_conv) + 1,
-                          st.cout, np.int32)
-            )
-            slack = max(1, -(-slack // max(1, st.stride)))
-        self.started = [False] * len(plan.convs)
+        for st in plan.stages:
+            if isinstance(st, ConvStage):
+                hist = st.tail + 2 * st.pad + st.k + max(slack, st.n_in) + 1
+                pend = st.pool + st.k + st.pad + max(slack, st.n_conv) + 1
+            elif isinstance(st, PoolStage):
+                hist, pend = 1, st.pool + max(slack, st.n_in) + 1
+            else:
+                hist, pend = st.tail + max(slack, st.n_in) + 1, 1
+            self.hists.append(FrameRing(hist, st.tail_ch, np.int32))
+            self.pendings.append(FrameRing(pend, st.pend_ch, np.int32))
+            if isinstance(st, ConvStage):
+                slack = max(1, -(-slack // max(1, st.stride)))
+        self.started = [False] * len(plan.stages)
         # frames still to skip per stage: with k < stride the next window
         # can start past the frames that have arrived so far
-        self.skip = [0] * len(plan.convs)
+        self.skip = [0] * len(plan.stages)
         self.gap = np.zeros(plan.gap_channels, np.int64)
-        self.frames = 0          # final-conv pooled frames accumulated in GAP
+        self.frames = 0          # final-stage frames accumulated in GAP
         self.samples_seen = 0
         self.flushed = False
 
@@ -773,10 +1028,11 @@ class StreamState:
 
     def advance(self, samples: np.ndarray, flush: bool = False) -> np.ndarray:
         """Feed u8 samples (n,) or (n, Cin0); returns newly emitted
-        final-conv frames (m, C).  ``flush`` appends each layer's right pad
-        and drops incomplete pool windows (end-of-stream semantics)."""
+        final-stage frames (m, C).  ``flush`` appends each conv's right
+        pad, joins every frame left in a skip ring and drops incomplete
+        pool windows (end-of-stream semantics)."""
         samples = np.asarray(samples)
-        cur = samples.reshape(-1, self.plan.convs[0].cin)
+        cur = samples.reshape(-1, self.plan.stages[0].cin)
         if cur.shape[0] > self._max_chunk:
             # split oversized inputs so the fixed-capacity rings never
             # overflow (the pointers just wrap more often)
@@ -790,46 +1046,27 @@ class StreamState:
 
     def _advance_once(self, samples: np.ndarray, flush: bool) -> np.ndarray:
         assert not self.flushed, "stream already flushed"
-        cur = samples.reshape(-1, self.plan.convs[0].cin).astype(np.int32)
+        cur = samples.reshape(-1, self.plan.stages[0].cin).astype(np.int32)
         self.samples_seen += cur.shape[0]
-        for i, st in enumerate(self.plan.convs):
-            hist = self.hists[i]
-            w = self.weights[st.layer_idx]
-            wk = w.reshape(st.k, st.cin, st.cout)
-            if not self.started[i] and (cur.shape[0] > 0 or flush):
-                # left pad arrives with the first real frame (offset code
-                # for the multi-bit first layer, zeros for binary layers)
-                pad_val = st.in_offset if st.in_bits > 1 else 0
-                hist.push(np.full((st.pad, st.cin), pad_val, np.int32))
-                self.started[i] = True
-            if flush:
-                pad_val = st.in_offset if st.in_bits > 1 else 0
-                cur = np.concatenate(
-                    [cur, np.full((st.pad, st.cin), pad_val, np.int32)])
-            n_skip = min(self.skip[i], cur.shape[0])
-            self.skip[i] -= n_skip
-            hist.push(cur[n_skip:])
-            avail = len(hist)
-            n_conv = (avail - st.k) // st.stride + 1 if avail >= st.k else 0
-            if n_conv > 0:
-                window = hist.peek(avail)
-                raw = _conv_raw(window, wk, st, n_conv)
-                thr, flip = self.thresholds[st.layer_idx]
-                y = _threshold(raw, thr, flip)
-                n_drop = min(n_conv * st.stride, avail)
-                hist.drop(n_drop)
-                self.skip[i] = n_conv * st.stride - n_drop
+        ins: list[np.ndarray] = []  # frames entering each stage
+        for i, st in enumerate(self.plan.stages):
+            ins.append(cur)
+            if isinstance(st, JoinStage):
+                cur = self._advance_join(i, st, ins[st.src], cur, flush)
+                continue
+            if isinstance(st, ConvStage):
+                y = self._advance_conv(i, st, cur, flush)
             else:
-                y = np.zeros((0, st.cout), np.uint8)
+                y = cur
             # pool: OR over non-overlapping windows, absolute alignment
             pend = self.pendings[i]
             pend.push(y.astype(np.int32))
             n_pool = len(pend) // st.pool
             if n_pool > 0:
                 frames = pend.pop(n_pool * st.pool)
-                cur = frames.reshape(n_pool, st.pool, st.cout).max(axis=1)
+                cur = frames.reshape(n_pool, st.pool, st.pend_ch).max(axis=1)
             else:
-                cur = np.zeros((0, st.cout), np.int32)
+                cur = np.zeros((0, st.pend_ch), np.int32)
             if flush:
                 pend.drop(len(pend))  # drop-remainder (ref_maxpool1d)
         self.gap += cur.astype(np.int64).sum(axis=0)
@@ -837,6 +1074,50 @@ class StreamState:
         if flush:
             self.flushed = True
         return cur
+
+    def _advance_conv(self, i: int, st: ConvStage, cur: np.ndarray,
+                      flush: bool) -> np.ndarray:
+        """Push ``cur`` into the conv's tail; return the positions it
+        completes (SA bits, or raw counts for ``sa=False``)."""
+        hist = self.hists[i]
+        wk = stage_weight(self.weights, st).reshape(st.k, st.cin, st.cout)
+        if not self.started[i] and (cur.shape[0] > 0 or flush):
+            # left pad arrives with the first real frame (offset code
+            # for the multi-bit first layer, zeros for binary layers)
+            pad_val = st.in_offset if st.in_bits > 1 else 0
+            hist.push(np.full((st.pad, st.cin), pad_val, np.int32))
+            self.started[i] = True
+        if flush:
+            pad_val = st.in_offset if st.in_bits > 1 else 0
+            cur = np.concatenate(
+                [cur, np.full((st.pad, st.cin), pad_val, np.int32)])
+        n_skip = min(self.skip[i], cur.shape[0])
+        self.skip[i] -= n_skip
+        hist.push(cur[n_skip:])
+        avail = len(hist)
+        n_conv = (avail - st.k) // st.stride + 1 if avail >= st.k else 0
+        if n_conv <= 0:
+            return np.zeros((0, st.cout), np.int32)
+        raw = _conv_raw(hist.peek(avail), wk, st, n_conv)
+        n_drop = min(n_conv * st.stride, avail)
+        hist.drop(n_drop)
+        self.skip[i] = n_conv * st.stride - n_drop
+        if not st.sa:
+            return raw
+        thr, flip = stage_threshold(self.thresholds, st)
+        return _threshold(raw, thr, flip)
+
+    def _advance_join(self, i: int, st: JoinStage, x: np.ndarray,
+                      s: np.ndarray, flush: bool) -> np.ndarray:
+        """Queue the block inputs ``x`` in the skip ring and join the
+        oldest of them with the second conv's counts ``s``."""
+        ring = self.hists[i]
+        ring.push(x)
+        y = _join(ring.pop(s.shape[0]), s, st, self.weights, self.thresholds)
+        if flush and len(ring):
+            raise AssertionError(f"{st.name}: flush left {len(ring)} frames "
+                                 "in the skip ring")
+        return y
 
     # -- logits --------------------------------------------------------------
 
@@ -886,9 +1167,10 @@ class StreamState:
     # -- steady-state interchange with the batched scheduler -----------------
 
     def export_steady(self) -> dict[str, list[np.ndarray] | np.ndarray]:
-        """Tail/pending/gap arrays at the plan's steady-state shapes."""
+        """Tail/pending/gap arrays at the plan's steady-state shapes (a
+        join's tail is its skip ring; a pool has no tail)."""
         tails, pends = [], []
-        for i, st in enumerate(self.plan.convs):
+        for i, st in enumerate(self.plan.stages):
             h = self.hists[i]
             if len(h) != st.tail:
                 raise ValueError(
@@ -901,11 +1183,11 @@ class StreamState:
                 raise ValueError(
                     f"{st.name}: pool phase {len(p)} != steady {st.phase}"
                 )
-            pends.append(p.peek(st.phase))  # exactly (phase, cout)
+            pends.append(p.peek(st.phase))  # exactly (phase, channels)
         return {"tails": tails, "pendings": pends, "gap": self.gap.copy()}
 
     def import_steady(self, tails, pendings, gap, frames: int) -> None:
-        for i, st in enumerate(self.plan.convs):
+        for i, st in enumerate(self.plan.stages):
             self.hists[i].load(np.asarray(tails[i], np.int32))
             self.pendings[i].load(
                 np.asarray(pendings[i][: st.phase], np.int32)
@@ -920,6 +1202,31 @@ class StreamState:
 # Batched primer: warm up a mass join as ONE vectorized advance
 # ---------------------------------------------------------------------------
 
+PRIME_GROUP = 128      # streams one cascade of the batched primer holds
+PRIME_THREADS = 4      # cascades primed at once
+_IM2COL_VALUES = 1 << 23  # float64 values of one primer matmul's operand
+
+
+def _conv_batch(x: np.ndarray, w: np.ndarray, stride: int,
+                n_conv: int) -> np.ndarray:
+    """``n_conv`` positions of a conv over (B, L, Cin) integer frames:
+    (B, n_conv, Cout) raw counts held in floats, as BLAS matmuls over
+    blocks of rows.  Exact: float32 where no partial sum can reach 2**24,
+    float64 (2**53) otherwise."""
+    k, cin, cout = w.shape
+    exact32 = k * cin * int(np.abs(x).max(initial=0)) < (1 << 24)
+    dt = np.float32 if exact32 else np.float64
+    win = sliding_window_view(x.astype(dt), k, axis=1)[
+        :, : (n_conv - 1) * stride + 1 : stride]      # (B, n_conv, Cin, K)
+    rhs = w.transpose(1, 0, 2).reshape(cin * k, cout).astype(dt)
+    rows = max(1, _IM2COL_VALUES // (n_conv * cin * k))
+    out = np.empty((x.shape[0], n_conv, cout), dt)
+    for a in range(0, x.shape[0], rows):
+        lhs = win[a:a + rows].reshape(-1, cin * k)
+        out[a:a + rows] = (lhs @ rhs).reshape(-1, n_conv, cout)
+    return out
+
+
 def prime_batch(
     plan: StreamPlan,
     weights: dict[int, np.ndarray],
@@ -929,17 +1236,21 @@ def prime_batch(
     """Warm up B fresh streams with one batched numpy advance.
 
     ``samples`` is (B, prime_samples) u8 codes.  Returns the batched
-    steady-state interchange: ``tails[i]`` (B, tail_i, cin_i),
-    ``pendings[i]`` (B, phase_i, cout_i), ``gap`` (B, C) int64 and the
+    steady-state interchange: ``tails[i]`` (B, tail_i, tail_ch_i),
+    ``pendings[i]`` (B, phase_i, pend_ch_i), ``gap`` (B, C) int64 and the
     scalar ``frames`` every primed stream has emitted — row ``j`` equals
     ``StreamState().advance(samples[j]); export_steady()`` exactly.  The
-    warm-up is integer arithmetic end to end (int64 conv accumulation,
-    integer SA thresholds, OR-pooling), so adding the batch axis cannot
-    change any value; bit-exactness is pinned by tests/test_rebalance.py.
+    warm-up is integer arithmetic end to end (exact conv accumulation,
+    integer SA thresholds, OR-pooling, integer joins), so adding the batch
+    axis cannot change any value; bit-exactness is pinned by
+    tests/test_rebalance.py and tests/test_stream_resnet.py.
 
     This is what lets a B-stream mass join cost one vectorized cascade
     instead of B per-stream ``StreamState`` warm-ups (the last
-    per-stream-python ingest edge the PR 4 arena left behind).
+    per-stream python on the ingest side of the arena).  Streams
+    run ``PRIME_GROUP`` at a time, ``PRIME_THREADS`` groups at once, so a
+    deep network's long prime never holds every stream's intermediate
+    frames together.
     """
     samples = np.asarray(samples)
     if samples.ndim != 2 or samples.shape[1] != plan.prime_samples:
@@ -948,39 +1259,67 @@ def prime_batch(
             f"got {samples.shape}"
         )
     B = samples.shape[0]
-    cur = samples.reshape(B, -1, plan.convs[0].cin).astype(np.int32)
+    if B > PRIME_GROUP:
+        # numpy releases the GIL in the copies and matmuls that make up
+        # a group's cascade, so a few groups run side by side
+        with ThreadPoolExecutor(PRIME_THREADS) as ex:
+            parts = list(ex.map(
+                lambda a: prime_batch(plan, weights, thresholds,
+                                      samples[a:a + PRIME_GROUP]),
+                range(0, B, PRIME_GROUP)))
+        cat = lambda key, i: np.concatenate(  # noqa: E731
+            [p[key][i] for p in parts])
+        n = len(plan.stages)
+        return {"tails": [cat("tails", i) for i in range(n)],
+                "pendings": [cat("pendings", i) for i in range(n)],
+                "gap": np.concatenate([p["gap"] for p in parts]),
+                "frames": parts[0]["frames"]}
+    cur = samples.reshape(B, -1, plan.stages[0].cin).astype(np.int32)
     tails: list[np.ndarray] = []
     pendings: list[np.ndarray] = []
-    for st in plan.convs:
-        # left pad arrives with the first real frame, exactly like
-        # StreamState._advance_once on a fresh stream
-        pad_val = st.in_offset if st.in_bits > 1 else 0
-        window = np.concatenate(
-            [np.full((B, st.pad, st.cin), pad_val, np.int32), cur], axis=1
-        )
-        avail = window.shape[1]
-        n_conv = (avail - st.k) // st.stride + 1 if avail >= st.k else 0
-        if n_conv <= 0 or avail - n_conv * st.stride != st.tail:
-            raise ValueError(
-                f"{st.name}: priming prefix does not reach the steady "
-                f"tail (plan prime_samples mismatch?)"
+    ins: list[np.ndarray] = []
+    for st in plan.stages:
+        ins.append(cur)
+        if isinstance(st, JoinStage):
+            x = ins[st.src]
+            n = cur.shape[1]
+            if x.shape[1] - n != st.tail:
+                raise ValueError(f"{st.name}: skip ring {x.shape[1] - n} "
+                                 f"!= steady {st.tail} after priming")
+            tails.append(x[:, n:])
+            pendings.append(np.zeros((B, 0, st.cout), np.int32))
+            cur = _join(x[:, :n], cur, st, weights, thresholds).astype(
+                np.int32)
+            continue
+        if isinstance(st, PoolStage):
+            tails.append(np.zeros((B, 0, st.channels), np.int32))
+            y, n_conv = cur, cur.shape[1]
+        else:
+            # left pad arrives with the first real frame, exactly like
+            # StreamState._advance_once on a fresh stream
+            pad_val = st.in_offset if st.in_bits > 1 else 0
+            window = np.concatenate(
+                [np.full((B, st.pad, st.cin), pad_val, np.int32), cur],
+                axis=1
             )
-        w = weights[st.layer_idx].reshape(st.k, st.cin, st.cout)
-        x = window.astype(np.int64)
-        if st.in_bits > 1:
-            x = x - st.in_offset  # offset-binary input (pads carry the code)
-        taps = np.stack(
-            [
-                x[:, t : t + (n_conv - 1) * st.stride + 1 : st.stride]
-                for t in range(st.k)
-            ],
-            axis=1,
-        )  # (B, K, n_conv, Cin)
-        raw = np.einsum("bknc,kco->bno", taps, w.astype(np.int64))
-        thr, flip = thresholds[st.layer_idx]
-        ge = raw >= thr[None, None, :]
-        y = np.where(flip[None, None, :], ~ge, ge).astype(np.int32)
-        tails.append(window[:, n_conv * st.stride :])
+            avail = window.shape[1]
+            n_conv = (avail - st.k) // st.stride + 1 if avail >= st.k else 0
+            if n_conv <= 0 or avail - n_conv * st.stride != st.tail:
+                raise ValueError(
+                    f"{st.name}: priming prefix does not reach the steady "
+                    f"tail (plan prime_samples mismatch?)"
+                )
+            w = stage_weight(weights, st).reshape(st.k, st.cin, st.cout)
+            # offset-binary input: the pads carry the code, so read 0
+            x = window - st.in_offset if st.in_bits > 1 else window
+            raw = _conv_batch(x, w, st.stride, n_conv)
+            if st.sa:
+                thr, flip = stage_threshold(thresholds, st)
+                ge = raw >= thr[None, None, :]
+                y = np.where(flip[None, None, :], ~ge, ge).astype(np.int32)
+            else:
+                y = raw.astype(np.int32)
+            tails.append(window[:, n_conv * st.stride :])
         used = (n_conv // st.pool) * st.pool
         if n_conv - used != st.phase:
             raise ValueError(
@@ -989,7 +1328,7 @@ def prime_batch(
             )
         pendings.append(y[:, used:])
         cur = y[:, :used].reshape(
-            B, n_conv // st.pool, st.pool, st.cout
+            B, n_conv // st.pool, st.pool, st.pend_ch
         ).max(axis=2)
     gap = cur.astype(np.int64).sum(axis=1)
     return {"tails": tails, "pendings": pendings, "gap": gap,
